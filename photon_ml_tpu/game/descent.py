@@ -343,9 +343,8 @@ class CoordinateDescent:
                                 # 230-248): per-coordinate convergence histogram /
                                 # iteration stats. Gated: both the summary string
                                 # and the metrics recording FETCH device arrays (a
-                                # ~100ms+ pipeline stall per fetch on remote
-                                # links); with INFO disabled and no telemetry sink
-                                # the sweep stays fetch-free
+                                # pipeline stall per fetch); with INFO disabled
+                                # and no telemetry sink the sweep stays fetch-free
                                 if logger.isEnabledFor(logging.INFO):
                                     logger.info(
                                         "cd iter %d coordinate %s optimization "

@@ -12,6 +12,7 @@ sharded over the mesh, "single node" = the problem is one vmap lane.
 from __future__ import annotations
 
 import dataclasses
+import logging
 from functools import partial
 from typing import Optional, Tuple
 
@@ -39,6 +40,8 @@ from ..optimize.common import abs_tolerances
 
 Array = jax.Array
 
+logger = logging.getLogger("photon_ml_tpu")
+
 
 def _fusion_mode(batch: LabeledBatch):
     """Decide whether this batch takes the single-sweep Pallas kernels
@@ -62,6 +65,13 @@ def _fusion_mode(batch: LabeledBatch):
         return none
     n, d = x.shape
     if not pallas_glm.eligible(n, d, x.dtype):
+        if mode == "auto" and jax.default_backend() == "tpu":
+            # a default intercept turns a 1024-wide feature bag into d=1025
+            logger.info(
+                "dense %s batch n=%d d=%d is outside the fused-kernel gate "
+                "(d a multiple of %d, n >= %d): jnp two-pass path",
+                x.dtype, n, d, pallas_glm.LANE, pallas_glm.MIN_FUSED_ROWS,
+            )
         return none
     mesh = None
     sharding = getattr(x, "sharding", None)

@@ -8,7 +8,8 @@ program, with block-level deflate and row-window skipping, returning columnar
 arrays + interned feature keys ready for vectorized index-map lookup.
 
 The module self-builds with g++ on first use (cached next to the source,
-keyed by source mtime) and degrades cleanly: ``available()`` is False when
+keyed by a hash of the source's content, so a library built from any other
+decoder.cpp can never load) and degrades cleanly: ``available()`` is False when
 the toolchain or zlib is missing, and every caller falls back to the pure-
 Python codec (io/avro.py).
 """
@@ -16,6 +17,7 @@ Python codec (io/avro.py).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import logging
 import os
@@ -29,7 +31,15 @@ logger = logging.getLogger("photon_ml_tpu")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "decoder.cpp")
-_LIB_PATH = os.path.join(_DIR, "_photon_native.so")
+
+
+def lib_path(src: str = _SRC) -> str:
+    """Library path for the decoder source at ``src``: the content hash is
+    part of the file name."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_photon_native.{digest}.so")
+
 
 # opcodes — must match decoder.cpp
 OP_NULL, OP_BOOL, OP_INT, OP_LONG, OP_FLOAT, OP_DOUBLE = 0, 1, 2, 3, 4, 5
@@ -46,28 +56,27 @@ _lib_error: Optional[str] = None
 
 
 def _build() -> Optional[ctypes.CDLL]:
-    """Compile decoder.cpp -> _photon_native.so (mtime-cached)."""
+    """Compile decoder.cpp -> _photon_native.<content hash>.so (built once
+    per source content)."""
     global _lib, _lib_error
     with _build_lock:
         if _lib is not None or _lib_error is not None:
             return _lib
         try:
-            if (
-                not os.path.exists(_LIB_PATH)
-                or os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
-            ):
+            path = lib_path()
+            if not os.path.exists(path):
                 # per-pid temp name: concurrent first-use builds (multi-process
                 # CLI) must not interleave g++ output into one file before the
                 # atomic rename
-                tmp = f"{_LIB_PATH}.tmp.{os.getpid()}"
+                tmp = f"{path}.tmp.{os.getpid()}"
                 cmd = [
                     "g++", "-O3", "-Wall", "-shared", "-fPIC",
                     _SRC, "-o", tmp, "-lz",
                 ]
                 subprocess.run(cmd, check=True, capture_output=True, text=True)
-                os.replace(tmp, _LIB_PATH)
-                logger.info("built native decoder: %s", _LIB_PATH)
-            lib = ctypes.CDLL(_LIB_PATH)
+                os.replace(tmp, path)
+                logger.info("built native decoder: %s", path)
+            lib = ctypes.CDLL(path)
         except (OSError, subprocess.CalledProcessError) as e:
             detail = getattr(e, "stderr", "") or str(e)
             _lib_error = f"native decoder unavailable: {detail[:500]}"

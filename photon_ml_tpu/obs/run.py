@@ -139,12 +139,8 @@ def record_solver_metrics(solver: str, result) -> None:
         return
     import jax
 
-    try:
-        tracer_cls = jax.core.Tracer
-    except AttributeError:  # pragma: no cover - newer jax moved it
-        from jax._src.core import Tracer as tracer_cls
     if any(
-        isinstance(x, tracer_cls)
+        isinstance(x, jax.core.Tracer)
         for x in (result.iterations, result.reason, result.gradient)
     ):
         return
@@ -154,9 +150,13 @@ def record_solver_metrics(solver: str, result) -> None:
     from ..optimize.common import ConvergenceReason
     from .tracing import add_device_fetch_bytes
 
-    iters = np.asarray(result.iterations)
-    reasons = np.asarray(result.reason)
-    grad = np.asarray(result.gradient, dtype=np.float64)
+    # explicit fetch: host-level solves run inside the CD sweep's transfer
+    # guard, which rejects a bare np.asarray on a device array
+    iters, reasons, grad = map(
+        np.asarray,
+        jax.device_get((result.iterations, result.reason, result.gradient)),
+    )
+    grad = grad.astype(np.float64)
     add_device_fetch_bytes(
         f"solver.{solver}", iters.nbytes + reasons.nbytes + grad.nbytes
     )

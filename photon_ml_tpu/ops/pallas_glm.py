@@ -78,6 +78,8 @@ _MIN_TILE_ROWS = 128
 # VMEM ceiling on the feature dim: the (tile, d) X block at the MINIMUM tile
 # of 128 rows must fit the dtype budget (f32 additionally pays the
 # Precision.HIGHEST multi-pass scratch — a 4MB f32 tile OOMs scoped VMEM).
+# Both ceilings compile and run on a v5e under libtpu 0.0.34 (chip_smoke.py's
+# kernels phase checks every edge this gate admits).
 MAX_FUSED_DIM_F32 = 4096
 MAX_FUSED_DIM_BF16 = 8192
 # Below this many rows the dispatch overhead beats the saved HBM sweep.
@@ -333,10 +335,7 @@ def _shard_psum_call(mesh, inner, rep_mask, n_out, args):
     (pallas_call has no GSPMD partitioning rule, so collective placement is
     explicit). ``rep_mask[i]`` marks argument i replicated; non-replicated
     args are row-sharded (arg 0 is the 2-D X, the rest are [n] vectors)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pre-0.6 jax ships it under experimental only
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS  # lazy: parallel imports ops

@@ -162,8 +162,7 @@ def as_partial(fn):
     """Wrap a callable as a jax.tree_util.Partial so it can flow through jit
     as a DYNAMIC argument: the jit cache keys on the underlying function
     identity + pytree structure, so fresh objective objects of the same
-    structure reuse compiled solvers instead of recompiling (essential: a
-    remote-compile environment pays tens of seconds per recompile)."""
+    structure reuse compiled solvers instead of recompiling."""
     if isinstance(fn, jax.tree_util.Partial):
         return fn
     return jax.tree_util.Partial(fn)

@@ -87,9 +87,8 @@ class RandomEffectOptimizationTracker:
     The aggregates are LAZY: constructing a tracker must not fetch device
     arrays — trackers are built inside the coordinate-descent hot loop every
     sweep, and a host fetch there stalls the device pipeline for a full
-    round trip (measured ~100-165 ms through the remote-harness link). The
-    [E]-sized fetches happen on first access, typically when logs are
-    enabled or the caller inspects the finished result."""
+    round trip. The [E]-sized fetches happen on first access, typically when
+    logs are enabled or the caller inspects the finished result."""
 
     result: SolverResult
     entity_mask: Optional[np.ndarray] = None

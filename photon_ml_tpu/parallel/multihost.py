@@ -41,17 +41,9 @@ def initialize(
     explicit args support the 'coordinator=HOST:PORT,process=I,n=P' CLI spec.
     """
     # must not touch the XLA backend before initialize (jax.process_count()
-    # would); is_initialized only reads coordination-service state. Older
-    # jax (< 0.5) has no is_initialized — fall back to the client handle.
-    _is_init = getattr(jax.distributed, "is_initialized", None)
-    if _is_init is not None:
-        if _is_init():
-            return
-    else:
-        from jax._src import distributed as _dist
-
-        if getattr(_dist.global_state, "client", None) is not None:
-            return
+    # would); is_initialized only reads coordination-service state
+    if jax.distributed.is_initialized():
+        return
     kwargs = {}
     if coordinator_address is not None:
         kwargs["coordinator_address"] = coordinator_address

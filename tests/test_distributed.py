@@ -655,16 +655,7 @@ import os
 import sys
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 4)
-except AttributeError:
-    pass  # jax 0.4.x: XLA_FLAGS in the env pins the 4 virtual devices
-try:
-    # cross-host collectives on the CPU backend need an explicit impl on
-    # jax versions that don't default it
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
+jax.config.update("jax_num_cpu_devices", 4)
 jax.config.update("jax_enable_x64", True)
 
 from photon_ml_tpu.cli import train
@@ -693,7 +684,7 @@ def _free_port():
 def _drill_round(tmp_path, data, index_dir, ckpt, out, metrics_prefix,
                  extra=(), env_by_proc=None, timeout=420):
     env_base = {**os.environ, "PYTHONPATH": REPO}
-    # 4 virtual CPU devices per process (jax 0.4.x spells this via XLA_FLAGS)
+    # 4 virtual CPU devices per process
     env_base["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env_base.pop("PHOTON_FAULTS", None)
     port = _free_port()

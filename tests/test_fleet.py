@@ -472,14 +472,7 @@ _FLEET_WORKER = """
 import sys
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 4)
-except AttributeError:
-    pass  # jax 0.4.x: XLA_FLAGS in the env pins the 4 virtual devices
-try:
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
+jax.config.update("jax_num_cpu_devices", 4)
 
 from photon_ml_tpu.cli import train
 
@@ -522,7 +515,7 @@ def test_two_process_fleet_merge_parity(tmp_path):
 
     port = _free_port()
     env = {**os.environ, "PYTHONPATH": REPO}
-    # 4 virtual CPU devices per process (jax 0.4.x spells this via XLA_FLAGS)
+    # 4 virtual CPU devices per process
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     procs = [
         subprocess.Popen(

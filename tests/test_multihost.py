@@ -437,16 +437,7 @@ _STREAM_WORKER = """
 import sys
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 4)
-except AttributeError:
-    pass  # jax 0.4.x: XLA_FLAGS in the env pins the 4 virtual devices
-try:
-    # cross-host collectives on the CPU backend need an explicit impl on
-    # jax versions that don't default it
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
+jax.config.update("jax_num_cpu_devices", 4)
 jax.config.update("jax_enable_x64", True)
 
 from photon_ml_tpu.cli import train
@@ -1064,8 +1055,6 @@ import numpy as np
 import jax
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
-# cross-host collectives on the CPU backend need an explicit implementation
-jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 from photon_ml_tpu.parallel import make_mesh, multihost
 
@@ -1134,7 +1123,7 @@ def test_two_process_passive_rows_padded_space(tmp_path):
     n_total = 21  # not divisible by chunk=4: host 1's padded ids shift by 1
     port = _free_port()
     env = {**os.environ, "PYTHONPATH": REPO}
-    # 4 virtual CPU devices per process (jax 0.4.x spells this via XLA_FLAGS)
+    # 4 virtual CPU devices per process
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     procs = [
         subprocess.Popen(

@@ -1,6 +1,6 @@
 """Vectorized random-effect dataset build: exact equality against a
 straightforward per-entity loop reference, plus a scale smoke test
-(VERDICT.md round-1 item 3: no per-entity Python loops, millions of entities
+(round-1 verdict item 3: no per-entity Python loops, millions of entities
 in seconds)."""
 
 import time

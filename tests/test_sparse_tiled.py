@@ -1,6 +1,6 @@
 """Huge-d sparse path: sorted-COO layout and (data x model)-tiled sharding.
 
-VERDICT.md round-1 item 1: an 8-device virtual-mesh test asserting that the
+Round-1 verdict item 1: an 8-device virtual-mesh test asserting that the
 model-axis-sharded fixed-effect solve is exactly the replicated solve, plus
 kernel-level parity of every layout against dense.
 """
