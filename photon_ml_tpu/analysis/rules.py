@@ -150,7 +150,7 @@ RULES: Dict[str, str] = {
     "R4": "swallowed exception (no re-raise, no obs counter)",
     "R5": "non-atomic file write in an atomic-write module",
     "R6": "NaN mishandling (== nan compare / uncounted isnan patch)",
-    "R7": "direct wall-clock timing in a timing-strict module (use obs.span/timed)",
+    "R7": "direct wall-clock timing in a timing-strict module (use obs.span)",
     "R8": "module-level jax import in a jax-free module",
     "R9": "cross-thread shared-state access with no common lock",
     "R10": "refusal ledger drift (code / README / test pins / refusals.json)",
@@ -964,7 +964,7 @@ def _run_r6(mod: _Module, hot: bool, add: AddFn) -> None:
 # through spans. A bare time.time()/time.perf_counter() pair in a hot-loop
 # module measures something the timeline cannot see — the measurement is
 # invisible to phase attribution, Chrome-trace export, and the JSONL stream.
-# Route the section through obs.span(...) / utils.timed(...) and read the
+# Route the section through obs.span(...) and read the
 # span's duration_s instead. Cross-thread timestamp plumbing that cannot be
 # a span (e.g. enqueue stamps handed to another thread) suppresses with a
 # per-site ignore[R7] comment.
@@ -985,7 +985,7 @@ def _run_r7(mod: _Module, add: AddFn) -> None:
                 "R7",
                 f"direct {canonical}() timing in a timing-strict module is "
                 "invisible to the timeline profiler: wrap the section in "
-                "obs.span(...)/timed(...) and read span.duration_s (suppress "
+                "obs.span(...) and read span.duration_s (suppress "
                 "cross-thread timestamp plumbing with # photon: ignore[R7])",
             )
 
@@ -1061,7 +1061,7 @@ def run_rules(
     """All rule passes over one parsed module. ``hot`` enables R1;
     ``dtype_strict`` enables R3's jnp.array-without-dtype subrule;
     ``atomic`` enables R5 (direct-write detection in persistence modules);
-    ``timing`` enables R7 (wall-clock timing outside obs.span/timed);
+    ``timing`` enables R7 (wall-clock timing outside obs.span);
     ``jax_free`` enables R8 (no module-level jax import)."""
     mod = _Module(tree)
     out: List[RawFinding] = []

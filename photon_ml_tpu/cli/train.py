@@ -365,8 +365,6 @@ def run(argv: Optional[List[str]] = None) -> Dict:
     )
     flight = None
     if telemetry_on:
-        from ..utils.compile_cache import install_compile_metrics_hook
-
         coordinator = multihost.is_coordinator()
         # every process streams its own telemetry so cli fleetz can merge
         # the fleet view; the coordinator keeps the bare filenames (all
@@ -397,7 +395,6 @@ def run(argv: Optional[List[str]] = None) -> Dict:
         for sink in metric_sinks:
             run_t.register_listener(sink)
         prev_run = obs.set_current_run(run_t)
-        install_compile_metrics_hook()
         if args.status_port is not None and coordinator:
             status_server = obs.IntrospectionServer(run_t, port=args.status_port)
             logger.info(

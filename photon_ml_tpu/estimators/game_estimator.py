@@ -35,6 +35,7 @@ from ..game.problem import GLMOptimizationConfig
 from ..io.data import RawDataset
 from ..models.game import GameModel
 from ..ops.normalization import NormalizationContext
+from .. import obs
 from .. import plan as execution_plan
 from ..utils.events import (
     EventEmitter,
@@ -42,7 +43,6 @@ from ..utils.events import (
     TrainingFinishEvent,
     TrainingStartEvent,
 )
-from ..utils.timed import timed
 
 logger = logging.getLogger("photon_ml_tpu")
 
@@ -185,124 +185,111 @@ class GameEstimator(EventEmitter):
         execution_plan.check_multiprocess_mesh(jax.process_count(), self.mesh)
         datasets = {}
         for cc in self.coordinate_configs:
-            with timed(f"prepare dataset {cc.name}"):
-                if cc.is_random_effect:
-                    if multiprocess:
-                        # entity planning across hosts + device-side shuffle
-                        # (game/data_mp.py; the reference's partitioner+
-                        # partitionBy pipeline)
-                        from ..game.data_mp import build_random_effect_dataset_global
-
-                        ds = build_random_effect_dataset_global(
-                            raw,
-                            cc.name,
-                            cc.feature_shard,
-                            cc.random_effect_type,
-                            mesh=self.mesh,
-                            active_cap=cc.active_cap,
-                            active_lower_bound=cc.active_lower_bound,
-                            dtype=self.dtype,
-                            pad_entities_to_multiple=self.entity_pad_multiple,
-                            features_to_samples_ratio=cc.features_to_samples_ratio,
-                            feature_dtype=cc.feature_dtype,
-                            hbm_budget_bytes=(
-                                cc.hbm_budget_mb * (1 << 20)
-                                if cc.hbm_budget_mb is not None
-                                else None
-                            ),
-                        )
-                        datasets[cc.name] = ds
-                        continue
-                    ds = build_random_effect_dataset(
-                        raw,
-                        cc.name,
-                        cc.feature_shard,
-                        cc.random_effect_type,
-                        active_cap=cc.active_cap,
-                        active_lower_bound=cc.active_lower_bound,
-                        dtype=self.dtype,
-                        pad_entities_to_multiple=self.entity_pad_multiple,
-                        features_to_samples_ratio=cc.features_to_samples_ratio,
-                        feature_dtype=cc.feature_dtype,
-                        hbm_budget_bytes=(
-                            cc.hbm_budget_mb * (1 << 20)
-                            if cc.hbm_budget_mb is not None
-                            else None
-                        ),
-                    )
-                    if self.mesh is not None and not ds.streamed:
-                        # streamed blocks are host-resident by design: they
-                        # stream through the chip in slices, so there is
-                        # nothing to place on the mesh
-                        from ..parallel.mesh import shard_entity_blocks
-
-                        ds = dataclasses.replace(
-                            ds, blocks=shard_entity_blocks(ds.blocks, self.mesh)
-                        )
-                    datasets[cc.name] = ds
-                else:
-                    ds = build_fixed_effect_dataset(
-                        raw,
-                        cc.name,
-                        cc.feature_shard,
-                        dtype=self.dtype,
-                        layout=cc.layout,
-                        mesh=self.mesh,
-                        feature_dtype=cc.feature_dtype,
-                        hbm_budget_bytes=(
-                            cc.hbm_budget_mb * (1 << 20)
-                            if cc.hbm_budget_mb is not None
-                            else None
-                        ),
-                    )
-                    if ds.streamed:
-                        datasets[cc.name] = ds
-                        continue
-                    if self.mesh is not None and cc.layout != "tiled":
-                        from ..parallel.mesh import shard_batch
-
-                        ds = dataclasses.replace(
-                            ds, batch=shard_batch(ds.batch, self.mesh)
-                        )
-                    if multiprocess:
-                        # multi-process sample space is the padded GLOBAL row
-                        # space: scores/residuals stay [N_global], no trimming
-                        ds = dataclasses.replace(ds, true_n_rows=ds.batch.n_rows)
-                    datasets[cc.name] = ds
+            with obs.span("fit.prepare_dataset", coordinate=cc.name) as sp:
+                datasets[cc.name] = self._prepare_dataset(raw, cc, multiprocess)
+            logger.debug("prepare dataset %s took %.3fs", cc.name, sp.duration_s)
         return datasets
+
+    def _prepare_dataset(self, raw: RawDataset, cc: CoordinateConfig, multiprocess: bool):
+        budget = cc.hbm_budget_mb * (1 << 20) if cc.hbm_budget_mb is not None else None
+        if cc.is_random_effect:
+            re_kwargs = dict(
+                active_cap=cc.active_cap,
+                active_lower_bound=cc.active_lower_bound,
+                dtype=self.dtype,
+                pad_entities_to_multiple=self.entity_pad_multiple,
+                features_to_samples_ratio=cc.features_to_samples_ratio,
+                feature_dtype=cc.feature_dtype,
+                hbm_budget_bytes=budget,
+            )
+            if multiprocess:
+                # entity planning across hosts + device-side shuffle
+                # (game/data_mp.py; the reference's partitioner+
+                # partitionBy pipeline)
+                from ..game.data_mp import build_random_effect_dataset_global
+
+                return build_random_effect_dataset_global(
+                    raw, cc.name, cc.feature_shard, cc.random_effect_type,
+                    mesh=self.mesh, **re_kwargs,
+                )
+            ds = build_random_effect_dataset(
+                raw, cc.name, cc.feature_shard, cc.random_effect_type, **re_kwargs
+            )
+            if self.mesh is not None and not ds.streamed:
+                # streamed blocks are host-resident by design: they
+                # stream through the chip in slices, so there is
+                # nothing to place on the mesh
+                from ..parallel.mesh import shard_entity_blocks
+
+                ds = dataclasses.replace(
+                    ds, blocks=shard_entity_blocks(ds.blocks, self.mesh)
+                )
+            return ds
+        ds = build_fixed_effect_dataset(
+            raw,
+            cc.name,
+            cc.feature_shard,
+            dtype=self.dtype,
+            layout=cc.layout,
+            mesh=self.mesh,
+            feature_dtype=cc.feature_dtype,
+            hbm_budget_bytes=budget,
+        )
+        if ds.streamed:
+            return ds
+        if self.mesh is not None and cc.layout != "tiled":
+            from ..parallel.mesh import shard_batch
+
+            ds = dataclasses.replace(ds, batch=shard_batch(ds.batch, self.mesh))
+        if multiprocess:
+            # multi-process sample space is the padded GLOBAL row
+            # space: scores/residuals stay [N_global], no trimming
+            ds = dataclasses.replace(ds, true_n_rows=ds.batch.n_rows)
+        return ds
 
     def _validation_context(
         self, val_raw: RawDataset
     ) -> Tuple[ValidationContext, Dict[str, object]]:
-        suite = build_suite(
-            self.evaluator_specs or ["RMSE"],
-            val_raw.labels,
-            val_raw.weights,
-            id_tags=val_raw.id_tags,
-        )
-        # per-coordinate validation scoring closures
-        from ..game.data import _rows_to_ell  # host helper
+        import jax
 
-        score_fns = {}
-        for cc in self.coordinate_configs:
-            rows, cols, vals = val_raw.shard_coo[cc.feature_shard]
-            if cc.is_random_effect:
-                idx, val = _rows_to_ell(rows, cols, vals, val_raw.n_rows)
-                ids = val_raw.id_tags[cc.random_effect_type]
-                idx_j = jnp.asarray(idx)
-                val_j = jnp.asarray(val, self.dtype)
+        with obs.span("fit.validation_context", rows=int(val_raw.n_rows)):
+            suite = build_suite(
+                self.evaluator_specs or ["RMSE"],
+                val_raw.labels,
+                val_raw.weights,
+                id_tags=val_raw.id_tags,
+            )
+            # per-coordinate validation scoring closures
+            from ..game.data import _rows_to_ell  # host helper
 
-                def fn(model, _ids=ids, _idx=idx_j, _val=val_j):
-                    erow = jnp.asarray(model.rows_for(_ids).astype(np.int32))
-                    return model.score_ell_rows(erow, _idx, _val)
+            score_fns = {}
+            for cc in self.coordinate_configs:
+                rows, cols, vals = val_raw.shard_coo[cc.feature_shard]
+                if cc.is_random_effect:
+                    idx, val = _rows_to_ell(rows, cols, vals, val_raw.n_rows)
+                    ids = val_raw.id_tags[cc.random_effect_type]
+                    idx_j = jnp.asarray(idx)
+                    val_j = jnp.asarray(val, self.dtype)
+                    uploaded = (idx_j, val_j)
 
-            else:
-                batch = val_raw.to_batch(cc.feature_shard, dtype=self.dtype)
+                    def fn(model, _ids=ids, _idx=idx_j, _val=val_j):
+                        erow = jnp.asarray(model.rows_for(_ids).astype(np.int32))
+                        return model.score_ell_rows(erow, _idx, _val)
 
-                def fn(model, _batch=batch):
-                    return _batch.features.matvec(model.model.coefficients.means)
+                else:
+                    batch = val_raw.to_batch(cc.feature_shard, dtype=self.dtype)
+                    uploaded = batch
 
-            score_fns[cc.name] = fn
+                    def fn(model, _batch=batch):
+                        return _batch.features.matvec(model.model.coefficients.means)
+
+                # host-known sizes of what this call put on the device: fit
+                # rebuilds the validation context on every call
+                obs.add_device_put_bytes(
+                    "fit.validation_context",
+                    sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(uploaded)),
+                )
+                score_fns[cc.name] = fn
         return (
             ValidationContext(suite=suite, score_fns=score_fns, offsets=val_raw.offsets),
             score_fns,
@@ -388,83 +375,93 @@ class GameEstimator(EventEmitter):
         datasets are built, so a background decode thread (the CLI's ingest
         overlap; the native Avro decoder releases the GIL) runs concurrently
         with dataset preparation and device uploads."""
-        if datasets is None:
-            datasets = self._prepare_datasets(raw)
-        if validation is not None:
-            if hasattr(validation, "result"):
-                validation = validation.result()
-            elif callable(validation):
-                validation = validation()
-        validation_ctx = None
-        if validation is not None:
-            # evaluator_specs default to RMSE inside _validation_context
-            validation_ctx, _ = self._validation_context(validation)
+        with obs.span("fit") as fit_span:
+            if datasets is None:
+                datasets = self._prepare_datasets(raw)
+            if validation is not None:
+                if hasattr(validation, "result"):
+                    validation = validation.result()
+                elif callable(validation):
+                    validation = validation()
+            validation_ctx = None
+            if validation is not None:
+                # evaluator_specs default to RMSE inside _validation_context
+                validation_ctx, _ = self._validation_context(validation)
 
-        # cartesian product of per-coordinate reg-weight grids
-        grids = [cc.grid() for cc in self.coordinate_configs]
-        names = [cc.name for cc in self.coordinate_configs]
-        if combos is None:
-            combos = [
-                dict(zip(names, combo)) for combo in itertools.product(*grids)
-            ]
-        n_iterations = (
-            self.n_cd_iterations if n_cd_iterations is None else n_cd_iterations
-        )
-        results: List[GameResult] = []
-        prev_models: Dict[str, object] = dict(
-            (initial_model.models if initial_model else {})
-        )
-        import time as _time
+            # cartesian product of per-coordinate reg-weight grids
+            grids = [cc.grid() for cc in self.coordinate_configs]
+            names = [cc.name for cc in self.coordinate_configs]
+            if combos is None:
+                combos = [
+                    dict(zip(names, combo)) for combo in itertools.product(*grids)
+                ]
+            n_iterations = (
+                self.n_cd_iterations if n_cd_iterations is None else n_cd_iterations
+            )
+            results: List[GameResult] = []
+            prev_models: Dict[str, object] = dict(
+                (initial_model.models if initial_model else {})
+            )
+            import time as _time
 
-        self.send_event(TrainingStartEvent(time=_time.time()))
-        for combo_index, reg_weights in enumerate(combos):
-            reg_weights = dict(reg_weights)
-            coords = self._make_coordinates(datasets, reg_weights, prev_models)
-            cd_ckpt = None
-            if checkpoint_fn is not None:
-                task = self.task
-                cd_ckpt = lambda it, models, _w=reg_weights: checkpoint_fn(
-                    _w, it, GameModel(models=models, task=task)
+            fit_span.attrs["n_combos"] = len(combos)
+            self.send_event(TrainingStartEvent(time=_time.time()))
+            for combo_index, reg_weights in enumerate(combos):
+                reg_weights = dict(reg_weights)
+                with obs.span(
+                    "fit.combo", index=combo_index, reg_weights=reg_weights
+                ) as combo_span:
+                    with obs.span("fit.make_coordinates", index=combo_index):
+                        coords = self._make_coordinates(
+                            datasets, reg_weights, prev_models
+                        )
+                    cd_ckpt = None
+                    if checkpoint_fn is not None:
+                        task = self.task
+                        cd_ckpt = lambda it, models, _w=reg_weights: checkpoint_fn(
+                            _w, it, GameModel(models=models, task=task)
+                        )
+                    cd_boundary = None
+                    if boundary_fn is not None:
+                        cd_boundary = lambda st, _w=reg_weights: boundary_fn(_w, st)
+                    cd = CoordinateDescent(
+                        coords, n_iterations=n_iterations,
+                        validation=validation_ctx, checkpoint_fn=cd_ckpt,
+                        validation_frequency=self.validation_frequency,
+                        boundary_fn=cd_boundary,
+                        # a snapshot describes one in-flight configuration — the
+                        # first combo of a resumed call; later combos start fresh
+                        resume_state=resume_state if combo_index == 0 else None,
+                        divergence_guard=self.divergence_guard,
+                        rejection_tolerance=self.rejection_tolerance,
+                        pipeline_depth=self.pipeline_depth,
+                    )
+                    out = cd.run(initial_models=prev_models)
+                    results.append(
+                        GameResult(
+                            model=out.model,
+                            config=reg_weights,
+                            evaluation=out.best_evaluation,
+                            trackers=out.trackers,
+                        )
+                    )
+                    self.send_event(
+                        OptimizationLogEvent(
+                            reg_weights=reg_weights,
+                            trackers=out.trackers,
+                            metrics=(
+                                None
+                                if out.best_evaluation is None
+                                else dict(out.best_evaluation.metrics)
+                            ),
+                        )
+                    )
+                    # warm start next config from this one (GameEstimator.scala:356-374)
+                    prev_models = dict(out.model.models)
+                logger.info(
+                    "train config %s took %.3fs", reg_weights, combo_span.duration_s
                 )
-            cd_boundary = None
-            if boundary_fn is not None:
-                cd_boundary = lambda st, _w=reg_weights: boundary_fn(_w, st)
-            cd = CoordinateDescent(
-                coords, n_iterations=n_iterations,
-                validation=validation_ctx, checkpoint_fn=cd_ckpt,
-                validation_frequency=self.validation_frequency,
-                boundary_fn=cd_boundary,
-                # a snapshot describes one in-flight configuration — the
-                # first combo of a resumed call; later combos start fresh
-                resume_state=resume_state if combo_index == 0 else None,
-                divergence_guard=self.divergence_guard,
-                rejection_tolerance=self.rejection_tolerance,
-                pipeline_depth=self.pipeline_depth,
-            )
-            with timed(f"train config {reg_weights}", logging.INFO):
-                out = cd.run(initial_models=prev_models)
-            results.append(
-                GameResult(
-                    model=out.model,
-                    config=reg_weights,
-                    evaluation=out.best_evaluation,
-                    trackers=out.trackers,
-                )
-            )
-            self.send_event(
-                OptimizationLogEvent(
-                    reg_weights=reg_weights,
-                    trackers=out.trackers,
-                    metrics=(
-                        None
-                        if out.best_evaluation is None
-                        else dict(out.best_evaluation.metrics)
-                    ),
-                )
-            )
-            # warm start next config from this one (GameEstimator.scala:356-374)
-            prev_models = dict(out.model.models)
-        self.send_event(TrainingFinishEvent(time=_time.time()))
+            self.send_event(TrainingFinishEvent(time=_time.time()))
         return results
 
     def fit_lanes(

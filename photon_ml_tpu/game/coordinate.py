@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..analysis.runtime import logged_fetch
 from ..models.coefficients import Coefficients
 from ..models.game import FixedEffectModel, RandomEffectModel
@@ -311,6 +312,12 @@ class FixedEffectCoordinate(Coordinate):
                     local, ds.mesh, PartitionSpec(DATA_AXIS)
                 )
             return scores
+        with obs.span("fe.score") as sp:
+            scores = self._score_resident(model)
+            sp.sync(scores)
+        return scores
+
+    def _score_resident(self, model: FixedEffectModel) -> Array:
         feats = self.dataset.batch.features
         # compute in the dataset's dtype: a warm-start model loaded under an
         # x64 config is f64 and must not promote the f32 score/residual stream
@@ -377,10 +384,14 @@ class RandomEffectCoordinate(Coordinate):
         dtype = blocks.labels.dtype
 
         if residual_scores is not None:
-            res_blocks = jnp.take(
-                residual_scores, jnp.maximum(blocks.active_rows, 0), axis=0
-            ) * (blocks.active_rows >= 0)
-            offsets = blocks.offsets + res_blocks.astype(dtype)
+            # the residual exchange: every block slot gathers its row's
+            # residual (the other coordinates' summed scores)
+            with obs.span("re.exchange", entities=E, slots=E * K) as sp:
+                res_blocks = jnp.take(
+                    residual_scores, jnp.maximum(blocks.active_rows, 0), axis=0
+                ) * (blocks.active_rows >= 0)
+                offsets = blocks.offsets + res_blocks.astype(dtype)
+                sp.sync(offsets)
         else:
             offsets = blocks.offsets
         if faults.active():
@@ -433,22 +444,31 @@ class RandomEffectCoordinate(Coordinate):
 
         solver_kwargs = self._solver_kwargs()
         train_fn = self._train_fn()
+        # Size-bucketed solves: entities are sorted by descending row count,
+        # so each (K, S)-rounded bucket is a contiguous block-row segment;
+        # solving per bucket avoids every small entity paying the padding of
+        # the largest (RandomEffectDatasetPartitioner's size-awareness,
+        # re-purposed for vmap lane economy). No buckets: one whole-block
+        # solve, on the arrays as they stand (a full-range slice would copy).
         segments = _size_buckets(self.dataset, align=_entity_shard_align(blocks))
-        if segments is None:
-            results = train_fn(
-                blocks.features, blocks.labels, offsets, blocks.weights,
-                w0, prior_mean, prior_prec, **solver_kwargs,
-            )
-        else:
-            # Size-bucketed solves: entities are sorted by descending row
-            # count, so each (K, S)-rounded bucket is a contiguous block-row
-            # segment; solving per bucket avoids every small entity paying
-            # the padding of the largest (RandomEffectDatasetPartitioner's
-            # size-awareness, re-purposed for vmap lane economy).
-            parts = []
-            for start, end, kb, sb in segments:
-                parts.append(
-                    train_fn(
+        counts = self.dataset.entity_counts
+        real_slots = padded_slots = 0
+        parts = []
+        for start, end, kb, sb in segments or [(0, E, K, S)]:
+            slots = (end - start) * kb
+            shape = dict(k=kb, s=sb, entities=end - start, slots=slots)
+            if counts is not None:
+                shape["real_rows"] = int(counts[start:end].sum())
+                real_slots += shape["real_rows"]
+                padded_slots += slots - shape["real_rows"]
+            with obs.span("re.bucket", **shape) as sp:
+                if segments is None:
+                    part = train_fn(
+                        blocks.features, blocks.labels, offsets, blocks.weights,
+                        w0, prior_mean, prior_prec, **solver_kwargs,
+                    )
+                else:
+                    part = train_fn(
                         blocks.features[start:end, :kb, :sb],
                         blocks.labels[start:end, :kb],
                         offsets[start:end, :kb],
@@ -458,35 +478,73 @@ class RandomEffectCoordinate(Coordinate):
                         prior_prec[start:end, :sb],
                         **solver_kwargs,
                     )
-                )
-            results = _concat_results(parts, S)
-        if jax.process_count() > 1:
-            # entity-sharded outputs span processes; replicate so every host
-            # can read the model (saving, validation scoring, trackers) — the
-            # reference's collect-model-to-driver step
-            from ..parallel import multihost
+                sp.sync(part)
+            if obs.active() and not multiproc:
+                # (across processes a bucket's lanes are not all addressable
+                # from here; the trackers count them after the collect)
+                self._record_lane_iterations(part)
+            parts.append(part)
+        if counts is not None:
+            slot_counter = obs.current_run().registry.counter(
+                "photon_re_block_slots_total",
+                "entity-block row slots handed to the random-effect solver, "
+                "real rows against bucket padding",
+            )
+            slot_counter.labels(coordinate=self.coordinate_id, kind="real").inc(real_slots)
+            slot_counter.labels(coordinate=self.coordinate_id, kind="padded").inc(
+                padded_slots
+            )
+        with obs.span("re.collect") as sp:
+            results = parts[0] if segments is None else _concat_results(parts, S)
+            if multiproc:
+                # entity-sharded outputs span processes; replicate so every
+                # host can read the model (saving, validation scoring,
+                # trackers) — the reference's collect-model-to-driver step
+                from ..parallel import multihost
 
-            mesh = blocks.features.sharding.mesh
-            results = multihost.fully_replicate(results, mesh)
-            coef_indices = jnp.asarray(self.dataset.host_proj_cols)
-        else:
-            coef_indices = blocks.proj_cols
-        w_sub = results.coefficients  # [E, S]
-        valid = coef_indices >= 0
-        model = RandomEffectModel(
-            random_effect_type=self.dataset.random_effect_type,
-            feature_shard=self.dataset.feature_shard,
-            task=self.task,
-            entity_ids=self.dataset.entity_ids,
-            coef_indices=coef_indices,
-            coef_values=jnp.where(valid, w_sub, 0.0),
-        )
+                mesh = blocks.features.sharding.mesh
+                results = multihost.fully_replicate(results, mesh)
+                coef_indices = jnp.asarray(self.dataset.host_proj_cols)
+            else:
+                coef_indices = blocks.proj_cols
+            w_sub = results.coefficients  # [E, S]
+            valid = coef_indices >= 0
+            model = RandomEffectModel(
+                random_effect_type=self.dataset.random_effect_type,
+                feature_shard=self.dataset.feature_shard,
+                task=self.task,
+                entity_ids=self.dataset.entity_ids,
+                coef_indices=coef_indices,
+                coef_values=jnp.where(valid, w_sub, 0.0),
+            )
+            sp.sync(model.coef_values)
         # provenance mark (weakref: must not pin the dataset's device arrays
         # to the model's lifetime): this model's support layout IS this
         # dataset's block layout, so score() can take the cached-positions
         # fast path without fetching/comparing the [E, S] index arrays
         object.__setattr__(model, "_support_layout_of", weakref.ref(self.dataset))
         return model, results
+
+    def _record_lane_iterations(self, part: SolverResult) -> None:
+        """A lockstep bucket runs until its slowest lane stops: ``useful`` is
+        the iterations its lanes needed, ``issued`` what the bucket ran for
+        all of them. Called only with a sink attached, after the bucket's
+        fence: the fetch is the iterations array as it stands (a reduction
+        on the device would be one more program, in traced runs alone)."""
+        iters = np.asarray(logged_fetch("re.bucket_iterations", part.iterations))
+        if iters.size == 0:
+            return
+        counter = obs.current_run().registry.counter(
+            "photon_re_lane_iterations_total",
+            "random-effect solver iterations per bucket: useful (summed over "
+            "lanes) against issued (lanes x the bucket's slowest lane)",
+        )
+        counter.labels(coordinate=self.coordinate_id, kind="useful").inc(
+            int(iters.sum())
+        )
+        counter.labels(coordinate=self.coordinate_id, kind="issued").inc(
+            int(iters.size) * int(iters.max())
+        )
 
     def _solver_kwargs(self) -> dict:
         """Shared static solver arguments — ONE construction site so the
@@ -789,6 +847,12 @@ class RandomEffectCoordinate(Coordinate):
                     local, ds.mesh, PartitionSpec(DATA_AXIS)
                 )
             return scores
+        with obs.span("re.score") as sp:
+            scores = self._score_resident(model)
+            sp.sync(scores)
+        return scores
+
+    def _score_resident(self, model: RandomEffectModel) -> Array:
         row_entity = self.dataset.row_entity
         # The model's entity-row order may differ from this dataset's block
         # order (warm start from a loaded model, locked partial-retrain
@@ -951,6 +1015,7 @@ def _concat_results(parts, S: int) -> SolverResult:
         reason=jnp.concatenate([p.reason for p in parts]),
         loss_history=jnp.concatenate([p.loss_history for p in parts]),
         grad_norm_history=jnp.concatenate([p.grad_norm_history for p in parts]),
+        cg_iterations=jnp.concatenate([p.cg_iterations for p in parts]),
     )
 
 
@@ -1214,6 +1279,7 @@ def _train_blocks_packed(
         reason=res.reason,
         loss_history=res.loss_history.T,
         grad_norm_history=res.grad_norm_history.T,
+        cg_iterations=res.cg_iterations,
     )
 
 
@@ -1306,6 +1372,7 @@ def _train_blocks_packed_lanes(
         reason=res.reason,  # [E, L]
         loss_history=jnp.moveaxis(res.loss_history, 0, -1),  # [E, L, T]
         grad_norm_history=jnp.moveaxis(res.grad_norm_history, 0, -1),
+        cg_iterations=res.cg_iterations,
     )
 
 

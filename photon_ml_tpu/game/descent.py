@@ -29,7 +29,6 @@ from ..robust import faults
 from ..evaluation.suite import EvaluationResults, EvaluationSuite
 from ..models.game import GameModel
 from ..optimize.trackers import build_tracker, record_tracker_metrics
-from ..utils.timed import timed
 from . import pipeline
 from .coordinate import Coordinate, ModelCoordinate
 
@@ -223,42 +222,44 @@ class CoordinateDescent:
         start_it = 0
         start_idx = 0
         resume = self.resume_state
-        if resume is not None:
-            # restore the boundary state exactly: models come back verbatim,
-            # per-coordinate scores re-derive from them (deterministic XLA →
-            # bit-identical to what the dead process held), and the summed
-            # scores restore from the snapshot so the incremental arithmetic
-            # (summed - own + new) continues on the same values it would have
-            # had uninterrupted
-            models = dict(resume.models)
-            for name in self.order:
-                if name in models:
-                    scores[name] = coords[name].score(models[name])
-            summed = jnp.asarray(resume.summed_scores)
-            evaluations = list(resume.evaluations)
-            best_eval = resume.best_eval
-            best_models = dict(resume.best_models)
-            # older snapshots predate the divergence guard's regression
-            # ledger — resume with an empty one (first accepted update of
-            # each coordinate re-seeds it)
-            train_losses = dict(getattr(resume, "train_losses", None) or {})
-            start_it = int(resume.iteration)
-            start_idx = int(resume.coordinate_index) + 1
-            if start_idx >= len(self.order):
-                start_it += 1
-                start_idx = 0
-        else:
-            # initialize scores from warm-start models where available
-            for name in self.order:
-                if name in initial_models:
-                    models[name] = initial_models[name]
-                    scores[name] = coords[name].score(initial_models[name])
-            zero = jnp.zeros((n,), jnp.float32)
-            summed = sum(scores.values(), zero)
+        # entry to the first sweep: initial (or restored) scores and their sum
+        with obs.span("cd.init"):
+            if resume is not None:
+                # restore the boundary state exactly: models come back verbatim,
+                # per-coordinate scores re-derive from them (deterministic XLA →
+                # bit-identical to what the dead process held), and the summed
+                # scores restore from the snapshot so the incremental arithmetic
+                # (summed - own + new) continues on the same values it would have
+                # had uninterrupted
+                models = dict(resume.models)
+                for name in self.order:
+                    if name in models:
+                        scores[name] = coords[name].score(models[name])
+                summed = jnp.asarray(resume.summed_scores)
+                evaluations = list(resume.evaluations)
+                best_eval = resume.best_eval
+                best_models = dict(resume.best_models)
+                # older snapshots predate the divergence guard's regression
+                # ledger — resume with an empty one (first accepted update of
+                # each coordinate re-seeds it)
+                train_losses = dict(getattr(resume, "train_losses", None) or {})
+                start_it = int(resume.iteration)
+                start_idx = int(resume.coordinate_index) + 1
+                if start_idx >= len(self.order):
+                    start_it += 1
+                    start_idx = 0
+            else:
+                # initialize scores from warm-start models where available
+                for name in self.order:
+                    if name in initial_models:
+                        models[name] = initial_models[name]
+                        scores[name] = coords[name].score(initial_models[name])
+                zero = jnp.zeros((n,), jnp.float32)
+                summed = sum(scores.values(), zero)
 
-            evaluations = []
-            best_eval = None
-            best_models = dict(models)
+                evaluations = []
+                best_eval = None
+                best_models = dict(models)
 
         for it in range(start_it, self.n_iterations):
             first = start_idx if it == start_it else 0
@@ -328,14 +329,21 @@ class CoordinateDescent:
                             coordinate_index=idx,
                         )
                         with obs.span("cd.coordinate", iteration=it, coordinate=name):
-                            with timed(
-                                f"cd iter {it} coordinate {name}: train",
-                                phase="solve",
+                            with obs.span(
+                                "cd.train",
+                                iteration=it,
                                 coordinate=name,
-                            ):
+                                phase="solve",
+                            ) as train_span:
                                 model, solver_result = coordinate.train(
                                     residual, initial_model=models.get(name)
                                 )
+                            logger.debug(
+                                "cd iter %d coordinate %s: train took %.3fs",
+                                it,
+                                name,
+                                train_span.duration_s,
+                            )
                             tracker = build_tracker(coordinate, solver_result)
                             if tracker is not None:
                                 trackers[name] = tracker
@@ -354,16 +362,26 @@ class CoordinateDescent:
                                         tracker.to_summary_string(),
                                     )
                                 if obs.active():
-                                    record_tracker_metrics(
-                                        obs.current_run().registry, name, tracker
-                                    )
+                                    # exists only with a sink: the metrics'
+                                    # own cost (their fetches), made visible
+                                    with obs.span("cd.tracker", coordinate=name):
+                                        record_tracker_metrics(
+                                            obs.current_run().registry, name, tracker
+                                        )
 
-                            with timed(
-                                f"cd iter {it} coordinate {name}: score",
-                                phase="score",
+                            with obs.span(
+                                "cd.score",
+                                iteration=it,
                                 coordinate=name,
-                            ):
+                                phase="score",
+                            ) as score_span:
                                 new_scores = coordinate.score(model)
+                            logger.debug(
+                                "cd iter %d coordinate %s: score took %.3fs",
+                                it,
+                                name,
+                                score_span.duration_s,
+                            )
                             if faults.active():
                                 # fault site coordinate.scores: the schedule
                                 # decision is host-side (eager, never traced)
@@ -384,13 +402,15 @@ class CoordinateDescent:
                                 if self.pipeline_depth > 1
                                 else None
                             )
-                            accepted, train_loss = (
-                                self._guard(
-                                    name, new_scores, solver_result, train_losses
-                                )
-                                if self.divergence_guard
-                                else (True, None)
-                            )
+                            accepted, train_loss = True, None
+                            if self.divergence_guard:
+                                # the one blocking fetch of an update: where
+                                # the host waits for the device when no sink
+                                # is attached
+                                with obs.span("cd.guard", coordinate=name):
+                                    accepted, train_loss = self._guard(
+                                        name, new_scores, solver_result, train_losses
+                                    )
                             if accepted:
                                 models[name] = model
                                 # summedScores - oldScores + newScores (:441-446)

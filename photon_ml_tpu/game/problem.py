@@ -220,7 +220,14 @@ class GLMProblem:
 
         from ..ops.glm import hvp_fn, vg_fn
 
-        result = optimize(vg_fn(obj), w0, self.config.solver_config(), hvp=hvp_fn(obj))
+        solver_config = self.config.solver_config()
+        with obs.span(
+            "fe.solve",
+            optimizer=solver_config.normalized_type().value,
+            reg_weight=float(self.config.reg_weight),
+        ) as sp:
+            result = optimize(vg_fn(obj), w0, solver_config, hvp=hvp_fn(obj))
+            sp.sync(result)
 
         variances = compute_variances(obj, result.coefficients, self.config.variance_type)
 

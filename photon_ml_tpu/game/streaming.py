@@ -182,6 +182,7 @@ def solve_streamed(
     out_loss = np.zeros(E, sdt)
     out_it = np.zeros(E, np.int32)
     out_reason = np.zeros(E, np.int32)
+    out_cg = np.zeros(E, np.int32)
     T = solver_kwargs["max_iterations"] + 1
     out_lh = np.full((E, T), np.nan, sdt)
     out_gh = np.full((E, T), np.nan, sdt)
@@ -193,6 +194,7 @@ def solve_streamed(
         reason=out_reason,
         loss_history=out_lh,
         grad_norm_history=out_gh,
+        cg_iterations=out_cg,
     )
     if not slices:
         # every segment was empty (e.g. all entities filtered out): nothing
@@ -202,11 +204,12 @@ def solve_streamed(
     def collect(sl, res):
         s0, s1, _, sb = sl
         with obs.span("re_stream.collect", phase="collect", slice=s0) as cp:
-            coef, grad, loss, iters, reason, lh, gh = logged_fetch(
+            coef, grad, loss, iters, reason, lh, gh, cg = logged_fetch(
                 "streaming.collect",
                 (
                     res.coefficients, res.gradient, res.loss, res.iterations,
                     res.reason, res.loss_history, res.grad_norm_history,
+                    res.cg_iterations,
                 ),
             )
         intervals["collect"].append((cp.start_perf, cp.start_perf + cp.duration_s))
@@ -215,6 +218,7 @@ def solve_streamed(
         out_loss[s0:s1] = loss
         out_it[s0:s1] = iters
         out_reason[s0:s1] = reason
+        out_cg[s0:s1] = cg
         out_lh[s0:s1] = lh
         out_gh[s0:s1] = gh
 
@@ -318,6 +322,7 @@ def solve_streamed(
         reason=out_reason,
         loss_history=out_lh,
         grad_norm_history=out_gh,
+        cg_iterations=out_cg,
     )
 
 

@@ -51,6 +51,7 @@ from .tracing import (
     add_compile_seconds,
     add_device_fetch_bytes,
     add_device_put_bytes,
+    add_retrace_seconds,
     compile_seconds_total,
     current_span,
     get_process_index,
@@ -78,6 +79,7 @@ __all__ = [
     "add_compile_seconds",
     "add_device_fetch_bytes",
     "add_device_put_bytes",
+    "add_retrace_seconds",
     "build_run_summary",
     "collect_build_info",
     "compile_seconds_total",

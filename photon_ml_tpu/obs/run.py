@@ -61,6 +61,16 @@ class RunTelemetry(EventEmitter):
         self.registry = registry if registry is not None else MetricsRegistry()
         self.status = StatusBoard()
 
+    def register_listener(self, listener: EventListener) -> None:
+        super().register_listener(listener)
+        # a run that is listened to wants its spans to carry re-trace and
+        # compile attribution: the jax monitoring hook goes in with the
+        # first listener (idempotent, best effort — without a usable jax it
+        # says no and spans simply carry no such attributes)
+        from ..utils.compile_cache import install_compile_metrics_hook
+
+        install_compile_metrics_hook()
+
     def flush_metrics(self) -> List[dict]:
         snap = self.registry.snapshot()
         self.send_event(MetricsSnapshotEvent(metrics=snap))

@@ -1,16 +1,25 @@
 """Hierarchical span tracing with JAX-aware annotations.
 
 Spans nest through a contextvar (so the tree survives generators and is
-isolated per thread / async context), carry wall time, and pick up two kinds
-of annotation:
+isolated per thread / async context), carry wall time and the ``root_id``
+of their tree (what all spans of one ``GameEstimator.fit`` share), and pick
+up two kinds of annotation:
 
-- compile seconds, fed by the jax monitoring hook installed via
-  ``utils.compile_cache.install_compile_metrics_hook`` — a span whose body
-  triggered XLA compilation reports ``compile_s`` alongside its wall time,
-  separating compile from execute cost;
+- re-trace and compile accounting, fed by the jax monitoring hook
+  (``utils.compile_cache.install_compile_metrics_hook``, installed when a
+  run gets its first listener) by EXACT event name: a span whose body made
+  jax trace a function again reports ``retraces`` / ``retrace_s``, one
+  whose body reached the backend compiler (or its persistent cache) reports
+  ``compile_s`` — host cost apart from execute cost;
 - device-transfer byte counters (``add_device_fetch_bytes`` /
   ``add_device_put_bytes``), called at the known host<->device crossing
   points (tracker aggregation, streamed staging/collection).
+
+Device work is dispatched asynchronously, so a host span around a device
+phase times the enqueue. ``Span.sync(*arrays)`` fences the phase when a
+sink is attached: the span then covers the phase's host AND device time
+(``attrs["device"]``), which is why a traced run is a per-layer run and
+never an end-to-end timing. With no sink it does nothing.
 
 Span exit emits a ``SpanEvent`` through the current run's EventEmitter, so a
 raising sink cannot fail the traced code path; with no sinks the span is
@@ -33,10 +42,16 @@ from . import run as _run
 _ctx: contextvars.ContextVar = contextvars.ContextVar("photon_obs_span", default=None)
 _ids = itertools.count(1)
 
-# process-wide compile-time accumulator, fed by the jax monitoring hook;
-# spans snapshot it on entry to attribute compile seconds to themselves
+# The two jax monitoring events spans account for, by exact name (every
+# other "compile" event is trace/lowering detail or, for
+# /jax/compilation_cache/compile_time_saved_sec, time SAVED and not spent).
+JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# process-wide (retraces, retrace seconds, backend compile seconds), fed by
+# the jax monitoring hook; spans snapshot it on entry and take the delta
 _compile_lock = threading.Lock()
-_compile_seconds_total = 0.0
+_compile_totals = (0, 0.0, 0.0)
 
 # Lane identity for multi-process timelines. obs must stay importable without
 # jax, so the process index is pushed in from outside (cli.train stamps it
@@ -75,15 +90,30 @@ def get_replica_id() -> Optional[str]:
     return _replica_id
 
 
-def add_compile_seconds(seconds: float) -> None:
-    global _compile_seconds_total
+def add_retrace_seconds(seconds: float) -> None:
+    """One jaxpr trace: the host traced a function again."""
+    global _compile_totals
     with _compile_lock:
-        _compile_seconds_total += float(seconds)
+        n, trace_s, compile_s = _compile_totals
+        _compile_totals = (n + 1, trace_s + float(seconds), compile_s)
+
+
+def add_compile_seconds(seconds: float) -> None:
+    """One backend compile (jax times it around the persistent-cache lookup,
+    so a cache hit lands here too, as its retrieval time)."""
+    global _compile_totals
+    with _compile_lock:
+        n, trace_s, compile_s = _compile_totals
+        _compile_totals = (n, trace_s, compile_s + float(seconds))
 
 
 def compile_seconds_total() -> float:
+    return _compile_snapshot()[2]
+
+
+def _compile_snapshot():
     with _compile_lock:
-        return _compile_seconds_total
+        return _compile_totals
 
 
 @dataclasses.dataclass
@@ -101,6 +131,23 @@ class Span:
     # monotonic start (same clock as duration_s) — what the timeline
     # profiler aligns intervals on; start_unix is for humans and merging
     start_perf: float = 0.0
+    # span_id of the tree's root, inherited through the parent: what all
+    # spans of one fit (one request, ...) share. Also stamped into attrs on
+    # close, for consumers that keep only name, times and attrs.
+    root_id: str = ""
+
+    def sync(self, *arrays) -> None:
+        """Fence a device phase: with a sink attached, wait for ``arrays``
+        so the span's duration covers the device work it dispatched, and
+        mark it ``device``. A wait, not a transfer — legal under the sweep's
+        transfer guard. With no sink: nothing (the untraced program keeps
+        its async dispatch)."""
+        if not _run.current_run().has_listeners():
+            return
+        import jax
+
+        jax.block_until_ready(arrays)
+        self.attrs["device"] = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,6 +157,12 @@ class SpanEvent(Event):
 
 def current_span() -> Optional[Span]:
     return _ctx.get()
+
+
+def _root_id(parent: Optional[Span], span_id: str) -> str:
+    if parent is None:
+        return span_id
+    return parent.root_id or parent.span_id  # hand-built parents carry none
 
 
 @contextlib.contextmanager
@@ -122,27 +175,33 @@ def span(name: str, parent: Optional[Span] = None, **attrs):
     under the sweep it serves."""
     if parent is None:
         parent = _ctx.get()
+    span_id = f"s{next(_ids)}"
     s = Span(
         name=name,
-        span_id=f"s{next(_ids)}",
+        span_id=span_id,
         parent_id=parent.span_id if parent is not None else None,
         start_unix=time.time(),
         attrs=dict(attrs),
         thread_id=threading.get_ident(),
         thread_name=threading.current_thread().name,
         process_index=_process_index,
+        root_id=_root_id(parent, span_id),
     )
     token = _ctx.set(s)
-    compile0 = compile_seconds_total()
+    retraces0, retrace0, compile0 = _compile_snapshot()
     t0 = time.perf_counter()
     s.start_perf = t0
     try:
         yield s
     finally:
         s.duration_s = time.perf_counter() - t0
-        compile_delta = compile_seconds_total() - compile0
-        if compile_delta > 0:
-            s.attrs["compile_s"] = compile_delta
+        retraces1, retrace1, compile1 = _compile_snapshot()
+        if retraces1 > retraces0:
+            s.attrs["retraces"] = retraces1 - retraces0
+            s.attrs["retrace_s"] = retrace1 - retrace0
+        if compile1 > compile0:
+            s.attrs["compile_s"] = compile1 - compile0
+        s.attrs["root_id"] = s.root_id
         if _replica_id is not None:
             s.attrs.setdefault("replica", _replica_id)
         _ctx.reset(token)
@@ -170,9 +229,10 @@ def record_span(
     if not run.has_listeners():
         return None
     now_perf = time.perf_counter()
+    span_id = f"s{next(_ids)}"
     s = Span(
         name=name,
-        span_id=f"s{next(_ids)}",
+        span_id=span_id,
         parent_id=parent.span_id if parent is not None else None,
         start_unix=time.time() - (now_perf - start_perf),
         attrs=dict(attrs),
@@ -181,7 +241,9 @@ def record_span(
         thread_name=threading.current_thread().name,
         process_index=_process_index,
         start_perf=float(start_perf),
+        root_id=_root_id(parent, span_id),
     )
+    s.attrs["root_id"] = s.root_id
     if _replica_id is not None:
         s.attrs.setdefault("replica", _replica_id)
     run.send_event(SpanEvent(span=s))

@@ -100,6 +100,9 @@ class SolverResult:
     reason: Array  # i32 scalar, ConvergenceReason code
     loss_history: Array  # f[max_iter + 1], NaN-padded
     grad_norm_history: Array  # f[max_iter + 1], NaN-padded
+    # i32, shaped like ``iterations``: TRON's inner CG iterations summed
+    # over the solve (one Hessian-vector product each); 0 for L-BFGS/OWL-QN
+    cg_iterations: Array
 
     @property
     def converged(self) -> Array:

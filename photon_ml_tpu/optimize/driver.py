@@ -17,8 +17,10 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
+from .. import obs
 from .common import (
     HvpFn,
     OptimizerConfig,
@@ -39,7 +41,15 @@ def optimize(
     config: OptimizerConfig,
     hvp: Optional[HvpFn] = None,
 ) -> SolverResult:
-    loss_tol, grad_tol = abs_tolerances(value_and_grad, w0, config.tolerance)
+    if isinstance(w0, jax.core.Tracer):
+        # traced inside a jitted train function: nothing host-level to time
+        # (the rule obs.record_solver_metrics follows)
+        loss_tol, grad_tol = abs_tolerances(value_and_grad, w0, config.tolerance)
+    else:
+        # one more objective pass at zero coefficients, before every solve
+        with obs.span("fe.tolerances") as sp:
+            loss_tol, grad_tol = abs_tolerances(value_and_grad, w0, config.tolerance)
+            sp.sync(loss_tol, grad_tol)
     kind = config.normalized_type()
 
     if kind in (OptimizerType.LBFGS, OptimizerType.LBFGSB, OptimizerType.OWLQN):

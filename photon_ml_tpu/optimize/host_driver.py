@@ -255,6 +255,7 @@ def solve_lbfgs_host(
             reason=np.int32(reason),
             loss_history=lh,
             grad_norm_history=gh,
+            cg_iterations=np.int32(0),
         )
 
     if not _finite(f, g):
@@ -389,6 +390,7 @@ def solve_tron_host(
     gh = np.full(T, np.nan, dtype)
     lh[0] = f
     gh[0] = _norm(g)
+    cg_total = 0  # inner CG iterations over the whole solve
 
     def result(it: int, reason: int) -> SolverResult:
         return SolverResult(
@@ -399,6 +401,7 @@ def solve_tron_host(
             reason=np.int32(reason),
             loss_history=lh,
             grad_norm_history=gh,
+            cg_iterations=np.int32(cg_total),
         )
 
     if not _finite(f, g):
@@ -408,7 +411,8 @@ def solve_tron_host(
     it = 0
     failures = 0
     while True:
-        step, residual, _ = _truncated_cg(hvp, w, g, delta, max_cg_iterations)
+        step, residual, cg_it = _truncated_cg(hvp, w, g, delta, max_cg_iterations)
+        cg_total += cg_it
         w_try = w + step
         gs = float(np.dot(g, step))
         predicted = -0.5 * (gs - float(np.dot(step, residual)))

@@ -502,6 +502,7 @@ def _solve(
         reason=final.reason,
         loss_history=final.loss_history,
         grad_norm_history=final.grad_norm_history,
+        cg_iterations=jnp.zeros_like(final.it),
     )
 
 

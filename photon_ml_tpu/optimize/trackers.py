@@ -151,7 +151,8 @@ def build_tracker(coordinate, result: Optional[SolverResult]):
 
 def record_tracker_metrics(registry, coordinate_name: str, tracker) -> None:
     """Fold one coordinate update's tracker into the metrics registry:
-    ``photon_cd_iterations`` (StatCounter-compatible summary) and
+    ``photon_cd_iterations`` (StatCounter-compatible summary),
+    ``photon_cd_cg_iterations`` (fixed effects) and
     ``photon_cd_convergence_reason_total`` per coordinate. Forces the
     tracker's lazy aggregates, so callers in the CD hot loop must gate this
     on ``obs.active()``."""
@@ -180,10 +181,16 @@ def record_tracker_metrics(registry, coordinate_name: str, tracker) -> None:
             reasons.labels(coordinate=coordinate_name, reason=reason).inc(n)
     else:
         r = tracker.result
-        iters_v, reason_v, loss_v = logged_fetch(
-            "tracker_metrics", (r.iterations, r.reason, r.loss)
+        # the CG count rides in the one fetch this update already makes
+        iters_v, reason_v, loss_v, cg_v = logged_fetch(
+            "tracker_metrics", (r.iterations, r.reason, r.loss, r.cg_iterations)
         )
         iters.observe(int(iters_v))
+        registry.summary(
+            "photon_cd_cg_iterations",
+            "TRON inner CG iterations (Hessian-vector products) per "
+            "fixed-effect coordinate update; 0 for L-BFGS/OWL-QN",
+        ).labels(coordinate=coordinate_name).observe(int(cg_v))
         latest.set(int(iters_v))
         reasons.labels(
             coordinate=coordinate_name,

@@ -149,6 +149,7 @@ class _TronState(NamedTuple):
     reason: Array
     loss_history: Array
     grad_norm_history: Array
+    cg_it: Array  # inner CG iterations so far, per lane
 
 
 @partial(
@@ -198,13 +199,16 @@ def _solve(
         ).astype(jnp.int32),
         loss_history=hist.at[0].set(f0),
         grad_norm_history=hist.at[0].set(_norm(g0)),
+        cg_it=jnp.zeros(lanes, jnp.int32),
     )
 
     def cond(s: _TronState):
         return jnp.logical_not(jnp.all(s.done))
 
     def body(s: _TronState):
-        step, residual, _ = _truncated_cg(hvp, s.w, s.g, s.delta, max_cg_iterations)
+        step, residual, cg_it = _truncated_cg(
+            hvp, s.w, s.g, s.delta, max_cg_iterations
+        )
         w_try = s.w + step
         gs = _vdot(s.g, step)
         predicted = -0.5 * (gs - _vdot(step, residual))
@@ -294,6 +298,7 @@ def _solve(
             reason=jnp.where(keep, s.reason, reason).astype(jnp.int32),
             loss_history=lh,
             grad_norm_history=gh,
+            cg_it=jnp.where(keep, s.cg_it, s.cg_it + cg_it),
         )
 
     final = jax.lax.while_loop(cond, body, init)
@@ -305,6 +310,7 @@ def _solve(
         reason=final.reason,
         loss_history=final.loss_history,
         grad_norm_history=final.grad_norm_history,
+        cg_iterations=final.cg_it,
     )
 
 

@@ -45,10 +45,16 @@ _compile_hook_installed = False
 
 
 def install_compile_metrics_hook() -> bool:
-    """Best-effort: register a jax monitoring listener that feeds XLA
-    compile durations into the obs layer (span ``compile_s`` attribution
-    plus ``photon_jax_compile_*`` registry series). Idempotent; returns
-    True when the hook is (already) installed."""
+    """Best-effort: register a jax monitoring listener that feeds jax's
+    compile events into the obs layer. Spans are fed by EXACT event name:
+    one ``jaxpr_trace_duration`` is one re-trace (span ``retraces`` /
+    ``retrace_s``), ``backend_compile_duration`` alone is compile time
+    (span ``compile_s``); the ``photon_jax_compile_*`` registry series keep
+    every event whose name contains "compile", each under its own name, so
+    no sum ever mixes time spent with ``compile_time_saved_sec``.
+    ``RunTelemetry.register_listener`` calls this with a run's first
+    listener. Idempotent; returns True when the hook is (already)
+    installed."""
     global _compile_hook_installed
     if _compile_hook_installed:
         return True
@@ -66,7 +72,10 @@ def install_compile_metrics_hook() -> bool:
     def _on_duration(event: str, duration: float, **kwargs) -> None:
         if "compile" not in event:
             return
-        obs.add_compile_seconds(duration)
+        if event == obs.tracing.JAXPR_TRACE_EVENT:
+            obs.add_retrace_seconds(duration)
+        elif event == obs.tracing.BACKEND_COMPILE_EVENT:
+            obs.add_compile_seconds(duration)
         reg = obs.current_run().registry
         reg.counter(
             "photon_jax_compile_total", "XLA compile events by jax event name"

@@ -22,7 +22,6 @@ from photon_ml_tpu.optimize.trackers import StatCounter
 from photon_ml_tpu.testing import generate_mixed_effect_data
 from photon_ml_tpu.testing.generators import mixed_data_to_raw_dataset
 from photon_ml_tpu.utils.events import Event, EventListener
-from photon_ml_tpu.utils.timed import timed
 
 
 # ---------------------------------------------------------------- registry
@@ -194,17 +193,26 @@ def test_span_without_listeners_emits_nothing_and_is_cheap():
     assert obs.current_span() is None
 
 
-def test_timed_produces_span_and_log(caplog):
+def test_prepare_dataset_site_produces_span_and_log(caplog):
+    """The sites that used ``utils.timed`` are plain ``obs.span``s under
+    stable names now, and keep their "took %.3fs" log line."""
     run = obs.RunTelemetry()
     col = _Collector()
     run.register_listener(col)
-    with obs.use_run(run), caplog.at_level(logging.DEBUG, logger="photon_ml_tpu"):
-        with timed("work unit"):
-            pass
-    assert any(
-        isinstance(e, obs.SpanEvent) and e.span.name == "work unit" for e in col.events
+    data = mixed_data_to_raw_dataset(
+        generate_mixed_effect_data(n=120, d_fixed=3, re_specs={"userId": (4, 2)}, seed=3)
     )
-    assert any("work unit took" in r.getMessage() for r in caplog.records)
+    with obs.use_run(run), caplog.at_level(logging.DEBUG, logger="photon_ml_tpu"):
+        datasets = _small_estimator().prepare_datasets(data)
+    assert set(datasets) == {"global", "per-user"}
+    spans = [
+        e.span for e in col.events
+        if isinstance(e, obs.SpanEvent) and e.span.name == "fit.prepare_dataset"
+    ]
+    assert [s.attrs["coordinate"] for s in spans] == ["global", "per-user"]
+    assert any(
+        "prepare dataset per-user took" in r.getMessage() for r in caplog.records
+    )
 
 
 def test_device_transfer_counters_tagged_on_span():
@@ -342,23 +350,51 @@ def test_raising_sink_never_fails_training(game_fit_data, caplog):
 # ------------------------------------------------------------- compile hook
 
 
-def test_compile_hook_records_into_current_run():
-    from photon_ml_tpu.utils.compile_cache import install_compile_metrics_hook
-
-    if not install_compile_metrics_hook():
-        pytest.skip("jax monitoring hook unavailable in this jax build")
+def test_compile_hook_feeds_spans_by_exact_event_name():
+    """The hook goes in with a run's first listener. A span's ``retraces`` /
+    ``retrace_s`` count jaxpr traces, ``compile_s`` is backend compile
+    alone; time SAVED by the cache, trace and lowering detail never reach a
+    span's time. The per-event registry series keep every "compile" event
+    under its own name."""
     try:
         from jax._src import monitoring
     except ImportError:
         pytest.skip("jax._src.monitoring unavailable")
     run = obs.RunTelemetry()
+    col = _Collector()
+    run.register_listener(col)  # installs the hook
     before = obs.compile_seconds_total()
     with obs.use_run(run):
-        monitoring.record_event_duration_secs("/test/obs_backend_compile", 0.25)
-    assert obs.compile_seconds_total() == pytest.approx(before + 0.25)
-    snap = {m["name"]: m for m in run.registry.snapshot()}
-    assert snap["photon_jax_compile_total"]["value"] == 1
-    assert snap["photon_jax_compile_seconds"]["sum"] == pytest.approx(0.25)
+        with obs.span("traced"):
+            monitoring.record_event_duration_secs(obs.tracing.JAXPR_TRACE_EVENT, 0.5)
+            monitoring.record_event_duration_secs(obs.tracing.JAXPR_TRACE_EVENT, 0.25)
+            monitoring.record_event_duration_secs(obs.tracing.BACKEND_COMPILE_EVENT, 0.125)
+            monitoring.record_event_duration_secs(
+                "/jax/compilation_cache/compile_time_saved_sec", 64.0
+            )
+            monitoring.record_event_duration_secs(
+                "/jax/core/compile/jaxpr_to_mlir_module_duration", 32.0
+            )
+        with obs.span("quiet"):
+            pass
+    if obs.compile_seconds_total() == before:
+        pytest.skip("jax monitoring hook unavailable in this jax build")
+    assert obs.compile_seconds_total() == pytest.approx(before + 0.125)
+    spans = {e.span.name: e.span for e in col.events if isinstance(e, obs.SpanEvent)}
+    assert spans["traced"].attrs["retraces"] == 2
+    assert spans["traced"].attrs["retrace_s"] == pytest.approx(0.75)
+    assert spans["traced"].attrs["compile_s"] == pytest.approx(0.125)
+    assert not {"retraces", "retrace_s", "compile_s"} & set(spans["quiet"].attrs)
+    series = {
+        (m["name"], m["labels"]["event"]): m
+        for m in run.registry.snapshot()
+        if m["name"].startswith("photon_jax_compile_")
+    }
+    assert series[("photon_jax_compile_total", obs.tracing.JAXPR_TRACE_EVENT)]["value"] == 2
+    saved = series[
+        ("photon_jax_compile_seconds", "/jax/compilation_cache/compile_time_saved_sec")
+    ]
+    assert saved["sum"] == pytest.approx(64.0)
 
 
 # --------------------------------------------------- zero-fetch invariant

@@ -1,0 +1,293 @@
+"""One span tree per ``GameEstimator.fit``: stable names, one ``root_id`` per
+fit, the device-phase spans below ``cd.train`` / ``cd.score`` with their
+attributes, the counters recorded at the same boundaries, and the promise
+that with no listener attached none of it waits for, or fetches from, the
+device. A tiny fixed + per-user fit on the CPU; no number here is a timing."""
+
+import collections
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from photon_ml_tpu import obs
+from photon_ml_tpu.estimators import CoordinateConfig, GameEstimator
+from photon_ml_tpu.game.coordinate import _entity_shard_align, _size_buckets
+from photon_ml_tpu.game.problem import GLMOptimizationConfig
+from photon_ml_tpu.ops.regularization import RegularizationContext
+from photon_ml_tpu.optimize import OptimizerConfig, OptimizerType
+from photon_ml_tpu.testing import generate_mixed_effect_data
+from photon_ml_tpu.testing.generators import mixed_data_to_raw_dataset
+from photon_ml_tpu.utils.events import EventListener
+
+N_SWEEPS = 2
+# what a sink's presence adds to the fetch ledger, and nothing else may
+SINK_ONLY_FETCH_SITES = {
+    "re.bucket_iterations", "tracker_metrics", "tracker_aggregates", "solver.tron",
+}
+
+
+class _Spans(EventListener):
+    def __init__(self):
+        self.spans = []
+
+    def handle(self, event) -> None:
+        if isinstance(event, obs.SpanEvent):
+            self.spans.append(event.span)
+
+
+def _estimator():
+    def coordinate(name, shard, optimizer, **kw):
+        return CoordinateConfig(
+            name=name, feature_shard=shard, reg_weights=(1.0,),
+            config=GLMOptimizationConfig(
+                optimizer=OptimizerConfig(
+                    optimizer_type=optimizer, tolerance=1e-8, max_iterations=30
+                ),
+                regularization=RegularizationContext("L2"),
+            ),
+            **kw,
+        )
+
+    return GameEstimator(
+        task="logistic_regression",
+        coordinate_configs=[
+            coordinate("global", "global", OptimizerType.TRON),
+            coordinate("per-user", "userShard", OptimizerType.LBFGS, random_effect_type="userId"),
+        ],
+        n_cd_iterations=N_SWEEPS,
+        evaluator_specs=["AUC"],
+        dtype=jnp.float64,
+        validation_frequency="SWEEP",
+    )
+
+
+@pytest.fixture(scope="module")
+def data():
+    full = mixed_data_to_raw_dataset(
+        generate_mixed_effect_data(n=900, d_fixed=5, re_specs={"userId": (40, 4)}, seed=11)
+    )
+    train, val = full.subset(np.arange(700)), full.subset(np.arange(700, 900))
+    return train, val, _estimator().prepare_datasets(train)
+
+
+def _fit(data, run, n_fits=1):
+    train, val, datasets = data
+    with obs.use_run(run):
+        return [_estimator().fit(train, validation=val, datasets=datasets) for _ in range(n_fits)]
+
+
+@pytest.fixture(scope="module")
+def traced(data):
+    """Two fits with a collecting listener and the timeline recorder."""
+    run, spans, timeline = obs.RunTelemetry(), _Spans(), obs.TimelineRecorder()
+    run.register_listener(spans)
+    run.register_listener(timeline)
+    results = _fit(data, run, n_fits=2)
+    return results, spans.spans, run.registry.snapshot(), timeline
+
+
+def _by_root(spans):
+    trees = collections.defaultdict(list)
+    for s in spans:
+        trees[s.root_id].append(s)
+    return trees
+
+
+def _counter(snapshot, name, **labels):
+    return sum(
+        m["value"] for m in snapshot
+        if m["name"] == name and all(m["labels"].get(k) == v for k, v in labels.items())
+    )
+
+
+def test_tree_shape_and_stable_names(traced):
+    _, spans, _, _ = traced
+    by_id = {s.span_id: s for s in spans}
+    parents = collections.defaultdict(set)
+    for s in spans:
+        parents[s.name].add(by_id[s.parent_id].name if s.parent_id else None)
+    assert dict(parents) == {
+        "fit": {None},
+        "fit.validation_context": {"fit"},
+        "fit.combo": {"fit"},
+        "fit.make_coordinates": {"fit.combo"},
+        "cd.init": {"fit.combo"},
+        "cd.sweep": {"fit.combo"},
+        "cd.coordinate": {"cd.sweep"},
+        "cd.eval": {"cd.sweep"},
+        "evaluate.device": {"cd.eval"},
+        "cd.train": {"cd.coordinate"},
+        "cd.tracker": {"cd.coordinate"},
+        "cd.score": {"cd.coordinate"},
+        "cd.guard": {"cd.coordinate"},
+        "fe.solve": {"cd.train"},
+        "fe.tolerances": {"fe.solve"},
+        "fe.score": {"cd.score"},
+        "re.exchange": {"cd.train"},
+        "re.bucket": {"cd.train"},
+        "re.collect": {"cd.train"},
+        "re.score": {"cd.score"},
+    }
+    # everything variable is an attribute, never part of a name
+    fit = next(s for s in spans if s.name == "fit")
+    assert fit.attrs["n_combos"] == 1
+    combo = next(s for s in spans if s.name == "fit.combo")
+    assert combo.attrs["index"] == 0
+    assert combo.attrs["reg_weights"] == {"global": 1.0, "per-user": 1.0}
+    train = [s for s in spans if s.name == "cd.train" and s.root_id == fit.root_id]
+    assert [(s.attrs["iteration"], s.attrs["coordinate"], s.attrs["phase"]) for s in train] == [
+        (it, name, "solve") for it in range(N_SWEEPS) for name in ("global", "per-user")
+    ]
+    solve = next(s for s in spans if s.name == "fe.solve")
+    assert (solve.attrs["optimizer"], solve.attrs["reg_weight"]) == ("TRON", 1.0)
+    # the device phases were fenced (a sink is attached) and say so; the
+    # spans that only group them carry no such mark, and no new span below
+    # cd.train / cd.score carries a phase
+    fenced = {"fe.solve", "fe.tolerances", "fe.score", "re.exchange", "re.bucket", "re.collect", "re.score"}
+    for s in spans:
+        assert (s.attrs.get("device") is True) == (s.name in fenced), s.name
+        if s.name in fenced:
+            assert "phase" not in s.attrs
+    val_ctx = next(s for s in spans if s.name == "fit.validation_context")
+    assert val_ctx.attrs["rows"] == 200 and val_ctx.attrs["put_bytes"] > 0
+
+
+def test_one_root_id_per_fit(traced):
+    _, spans, _, _ = traced
+    trees = _by_root(spans)
+    assert len(trees) == 2
+    for root_id, tree in trees.items():
+        roots = [s for s in tree if s.parent_id is None]
+        assert [(s.name, s.span_id) for s in roots] == [("fit", root_id)]
+        assert all(s.attrs["root_id"] == root_id for s in tree)  # what sinks keep
+        assert sum(s.name == "cd.sweep" for s in tree) == N_SWEEPS
+    a, b = (sorted(s.name for s in tree) for tree in trees.values())
+    assert a == b
+
+
+def test_bucket_attributes_and_slot_counter_agree_with_the_dataset(traced, data):
+    _, spans, snapshot, _ = traced
+    dataset = data[2]["per-user"]
+    n_entities = dataset.blocks.features.shape[0]
+    real_rows = int(dataset.entity_counts.sum())
+    segments = _size_buckets(dataset, align=_entity_shard_align(dataset.blocks))
+    assert segments is not None and len(segments) > 1
+    buckets = [s for s in spans if s.name == "re.bucket"]
+    n_trains = 2 * N_SWEEPS
+    assert len(buckets) == n_trains * len(segments)
+    one_train = buckets[: len(segments)]
+    assert [(s.attrs["k"], s.attrs["s"], s.attrs["entities"]) for s in one_train] == [
+        (kb, sb, end - start) for start, end, kb, sb in segments
+    ]
+    assert sum(s.attrs["entities"] for s in one_train) == n_entities
+    assert sum(s.attrs["real_rows"] for s in one_train) == real_rows
+    padded_slots = sum((end - start) * kb for start, end, kb, _ in segments)
+    assert sum(s.attrs["slots"] for s in one_train) == padded_slots
+    real = _counter(snapshot, "photon_re_block_slots_total", coordinate="per-user", kind="real")
+    padded = _counter(snapshot, "photon_re_block_slots_total", coordinate="per-user", kind="padded")
+    assert real == n_trains * real_rows
+    assert real + padded == n_trains * padded_slots
+    exchange = next(s for s in spans if s.name == "re.exchange")
+    k = dataset.blocks.features.shape[1]
+    assert (exchange.attrs["entities"], exchange.attrs["slots"]) == (n_entities, n_entities * k)
+
+
+def test_lane_iterations_are_useful_over_issued(traced):
+    results, _, snapshot, _ = traced
+    useful = _counter(snapshot, "photon_re_lane_iterations_total", coordinate="per-user", kind="useful")
+    issued = _counter(snapshot, "photon_re_lane_iterations_total", coordinate="per-user", kind="issued")
+    assert 0 < useful <= issued
+    # the last update's share of it is what that update's tracker holds
+    last = np.asarray(jax.device_get(results[-1][0].trackers["per-user"].result.iterations))
+    assert useful >= last.sum()
+
+
+def test_cg_count_equals_the_hessian_vector_products_made(data, monkeypatch):
+    """``photon_cd_cg_iterations`` against a count taken where the work is:
+    every CG iteration makes exactly one Hessian-vector product."""
+    from photon_ml_tpu.ops import glm
+
+    calls = []
+
+    def counting_hvp(inner, w, v):
+        jax.debug.callback(lambda: calls.append(1))
+        return inner(w, v)
+
+    real = glm.hvp_fn
+    monkeypatch.setattr(
+        glm, "hvp_fn", lambda objective: jax.tree_util.Partial(counting_hvp, real(objective))
+    )
+    run = obs.RunTelemetry()
+    run.register_listener(_Spans())
+    results = _fit(data, run)[0]
+    jax.effects_barrier()
+    (summary,) = [m for m in run.registry.snapshot() if m["name"] == "photon_cd_cg_iterations"]
+    assert summary["labels"] == {"coordinate": "global"}
+    assert summary["stat"]["count"] == N_SWEEPS
+    assert summary["sum"] == len(calls) > 0
+    # L-BFGS has no inner CG: the per-user result carries zeros
+    per_user = results[0].trackers["per-user"].result
+    assert not np.asarray(jax.device_get(per_user.cg_iterations)).any()
+    assert per_user.cg_iterations.shape == per_user.iterations.shape
+
+
+def test_no_listener_no_fence_no_fetch_and_the_same_model(traced, data, monkeypatch, caplog):
+    # the INFO optimization summary fetches on its own account (tracker_summary,
+    # tracker_aggregates), and an earlier test of the process may have left INFO on
+    caplog.set_level(logging.WARNING, logger="photon_ml_tpu")
+    fences = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: (fences.append(1), real(x))[1])
+    quiet = obs.RunTelemetry()  # a registry, no listener
+    untraced = _fit(data, quiet)[0]
+    assert fences == []
+    sites = {
+        m["labels"]["site"] for m in quiet.registry.snapshot()
+        if m["name"] == "photon_device_fetch_bytes_total"
+    }
+    assert not sites & SINK_ONLY_FETCH_SITES, sites
+    assert not [m for m in quiet.registry.snapshot() if m["name"].startswith("photon_re_lane_")]
+    # with a listener the spans do fence, and the sink-only sites are exactly
+    # what the ledger gains
+    run = obs.RunTelemetry()
+    run.register_listener(_Spans())
+    _fit(data, run)
+    assert fences
+    traced_sites = {
+        m["labels"]["site"] for m in run.registry.snapshot()
+        if m["name"] == "photon_device_fetch_bytes_total"
+    }
+    assert traced_sites - sites == SINK_ONLY_FETCH_SITES
+    # tracing changes no arithmetic: bit-identical models
+    for a, b in zip(_coefficients(untraced[0].model), _coefficients(traced[0][0][0].model)):
+        np.testing.assert_array_equal(a, b)
+
+
+def _coefficients(game_model):
+    fixed, per_user = game_model.models["global"], game_model.models["per-user"]
+    return [
+        np.asarray(jax.device_get(x))
+        for x in (fixed.model.coefficients.means, per_user.coef_values, per_user.coef_indices)
+    ]
+
+
+def test_phase_attribution_reads_what_it_read(traced):
+    """The timeline counts outermost phase spans only: cd.train / cd.score /
+    cd.eval carry the phases the ``timed`` sections carried, and the new
+    spans below them carry none."""
+    _, _, _, timeline = traced
+    report = timeline.phase_attribution()
+    assert report["n_sweeps"] == 2 * N_SWEEPS
+    for sweep in report["sweeps"]:
+        assert set(sweep["phases"]) == {"solve", "score", "eval"}
+        assert sweep["nested_phases"] == {}
+        assert {c: set(p) for c, p in sweep["coordinates"].items()} == {
+            "global": {"solve", "score"}, "per-user": {"solve", "score", "eval"},
+        }
+        assert sweep["critical_path_seconds"] + sweep["other_seconds"] == pytest.approx(
+            sweep["wall_seconds"]
+        )
+        assert sweep["overlap_factor"] == pytest.approx(0.0, abs=1e-9)
