@@ -22,6 +22,7 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import obs
 from ..analysis.runtime import logged_fetch
@@ -444,21 +445,31 @@ class RandomEffectCoordinate(Coordinate):
 
         solver_kwargs = self._solver_kwargs()
         train_fn = self._train_fn()
-        # Size-bucketed solves: entities are sorted by descending row count,
-        # so each (K, S)-rounded bucket is a contiguous block-row segment;
-        # solving per bucket avoids every small entity paying the padding of
-        # the largest (RandomEffectDatasetPartitioner's size-awareness,
-        # re-purposed for vmap lane economy). No buckets: one whole-block
-        # solve, on the arrays as they stand (a full-range slice would copy).
-        segments = _size_buckets(self.dataset, align=_entity_shard_align(blocks))
+        # Size-bucketed solves: each of the dataset's chunks is sorted by
+        # descending row count and dealt the same size profile, so a (K, S)-
+        # rounded bucket is the SAME local row range of every chunk; solving
+        # per bucket avoids every small entity paying the padding of the
+        # largest (RandomEffectDatasetPartitioner's size-awareness, re-purposed
+        # for vmap lane economy), and under a mesh every chip holds an equal
+        # share of every bucket. No buckets: one whole-block solve, on the
+        # arrays as they stand (a full-range slice would copy).
+        segments = _size_buckets(self.dataset)
+        chunks = self.dataset.entity_chunks
+        sharded = _chunk_axis(blocks.features, chunks)
         counts = self.dataset.entity_counts
+        if counts is not None:
+            chunk_counts = np.asarray(counts).reshape(chunks, -1)
         real_slots = padded_slots = 0
         parts = []
-        for start, end, kb, sb in segments or [(0, E, K, S)]:
-            slots = (end - start) * kb
-            shape = dict(k=kb, s=sb, entities=end - start, slots=slots)
+        for start, end, kb, sb in segments or [(0, E // chunks, K, S)]:
+            entities = chunks * (end - start)
+            slots = entities * kb
+            shape = dict(k=kb, s=sb, entities=entities, slots=slots, chunks=chunks)
             if counts is not None:
-                shape["real_rows"] = int(counts[start:end].sum())
+                chunk_real = chunk_counts[:, start:end].sum(axis=1)
+                shape["real_rows"] = int(chunk_real.sum())
+                # the chips' balance: real_rows over chunks * this
+                shape["max_chunk_real_rows"] = int(chunk_real.max())
                 real_slots += shape["real_rows"]
                 padded_slots += slots - shape["real_rows"]
             with obs.span("re.bucket", **shape) as sp:
@@ -469,13 +480,11 @@ class RandomEffectCoordinate(Coordinate):
                     )
                 else:
                     part = train_fn(
-                        blocks.features[start:end, :kb, :sb],
-                        blocks.labels[start:end, :kb],
-                        offsets[start:end, :kb],
-                        blocks.weights[start:end, :kb],
-                        w0[start:end, :sb],
-                        prior_mean[start:end, :sb],
-                        prior_prec[start:end, :sb],
+                        *_bucket_operands(
+                            (blocks.features, blocks.labels, offsets, blocks.weights),
+                            (w0, prior_mean, prior_prec),
+                            chunks, sharded, start, end, kb, sb,
+                        ),
                         **solver_kwargs,
                     )
                 sp.sync(part)
@@ -495,7 +504,11 @@ class RandomEffectCoordinate(Coordinate):
                 padded_slots
             )
         with obs.span("re.collect") as sp:
-            results = parts[0] if segments is None else _concat_results(parts, S)
+            results = (
+                parts[0]
+                if segments is None
+                else _concat_results(parts, S, chunks, sharded)
+            )
             if multiproc:
                 # entity-sharded outputs span processes; replicate so every
                 # host can read the model (saving, validation scoring,
@@ -718,7 +731,7 @@ class RandomEffectCoordinate(Coordinate):
                 )
 
         solver_kwargs = self._solver_kwargs()
-        segments = _size_buckets(ds, entity_range=shard) or [(0, E, K, S)]
+        segments = _contiguous_segments(ds, entity_range=shard) or [(0, E, K, S)]
         results = solve_streamed(
             blocks,
             segments,
@@ -924,48 +937,45 @@ def _size_buckets(
     dataset: RandomEffectDataset,
     min_dim: int = 8,
     align: int = 1,
-    entity_range: Optional[Tuple[int, int]] = None,
 ):
-    """Contiguous entity segments with power-of-2-rounded (K, S) block shapes.
+    """Chunk-local entity segments with power-of-2-rounded (K, S) block shapes.
 
     Returns [(start, end, K_b, S_b)], or None when per-entity stats are
-    unavailable or bucketing cannot shrink anything. Rounding to powers of two
-    (floored at ``min_dim``) bounds the number of distinct compiled solver
-    shapes at O(log^2) while removing the bulk of the padding FLOPs.
+    unavailable or bucketing cannot shrink anything. ``start``/``end`` are
+    rows of ONE chunk: the dataset's block rows are ``entity_chunks`` equal
+    chunks, each size-sorted descending and dealt the same size profile
+    (``_entity_plan``), and a bucket is rows [start, end) of EVERY chunk. One
+    set of bounds serves all chunks: the row count at a local position is
+    taken as the largest over the chunks, so an entity a chunk reaches one
+    position early fits the larger K of the bucket before it. With one chunk
+    the segments are plain block-row ranges. Rounding to powers of two (floored
+    at ``min_dim``) bounds the number of distinct compiled solver shapes at
+    O(log^2) while removing the bulk of the padding FLOPs.
 
     Fully vectorized (no per-entity Python work — this runs on every train()
-    call, potentially over millions of entities). ``align`` snaps segment
-    boundaries up to multiples of the per-device entity-chunk size so bucket
-    slices of mesh-sharded blocks never split a device shard (counts are
-    non-increasing, so the merged head of the next run still fits the larger
-    preceding block shape).
+    call, potentially over millions of entities). ``align`` (the per-device
+    entity chunk) is accepted for the benchmark's re_pad_share reader, which
+    passes it, and changes nothing: a bucket takes the same rows of every
+    chunk, so no slice splits a device shard.
     """
+    del align
     counts = dataset.entity_counts
     svec = dataset.entity_subspace_dims
     if counts is None or svec is None or len(counts) == 0:
         return None
-    if entity_range is not None:
-        # streamed + sharded: stats are GLOBAL but the blocks hold only this
-        # host's [lo, hi) range — bucket the local slice (counts are globally
-        # non-increasing, so the slice stays sorted)
-        lo, hi = entity_range
-        counts = counts[lo:hi]
-        svec = svec[lo:hi]
-        if len(counts) == 0:
-            return None
-    E, K, S = dataset.blocks.features.shape
+    _, K, S = dataset.blocks.features.shape
+    chunks = dataset.entity_chunks
+    # the stats cover ALL block rows (streamed + sharded blocks hold one
+    # host's range of them); per local position, the largest over the chunks
+    counts = np.asarray(counts, dtype=np.int64).reshape(chunks, -1).max(axis=0)
+    sv = np.asarray(svec, dtype=np.int64).reshape(chunks, -1).max(axis=0)
+    chunk_rows = len(counts)
 
-    kb_of = np.minimum(
-        np.maximum(_pow2_ceil(np.asarray(counts[:E], dtype=np.int64)), min_dim), K
-    )
+    kb_of = np.minimum(np.maximum(_pow2_ceil(counts), min_dim), K)
     bounds = np.flatnonzero(np.diff(kb_of)) + 1  # starts of new equal-K runs
-    if align > 1:
-        bounds = np.unique(-(-bounds // align) * align)
-    bounds = bounds[(bounds > 0) & (bounds < E)]
     starts = np.concatenate([[0], bounds])
-    ends = np.concatenate([bounds, [E]])
+    ends = np.concatenate([bounds, [chunk_rows]])
 
-    sv = np.asarray(svec[:E], dtype=np.int64)
     sb_of = np.minimum(
         np.maximum(_pow2_ceil(np.maximum.reduceat(sv, starts)), min_dim), S
     )
@@ -983,9 +993,35 @@ def _size_buckets(
     return segments
 
 
+def _contiguous_segments(
+    dataset: RandomEffectDataset,
+    entity_range: Optional[Tuple[int, int]] = None,
+):
+    """``_size_buckets`` as contiguous block-row segments, for the streamed
+    solve (game/streaming.py walks host blocks slice by slice): every chunk's
+    copy of every bucket, in block-row order — ``entity_chunks`` times as many
+    segments of the same shapes. ``entity_range`` (streamed + sharded: the
+    blocks hold only this host's [lo, hi) of the block rows) keeps the parts
+    inside the range, relative to ``lo``."""
+    local = _size_buckets(dataset)
+    if local is None:
+        return None
+    chunk_rows = dataset.num_entities // dataset.entity_chunks
+    lo, hi = entity_range if entity_range is not None else (0, dataset.num_entities)
+    segments = []
+    for c in range(dataset.entity_chunks):
+        for start, end, kb, sb in local:
+            s = max(c * chunk_rows + start, lo)
+            e = min(c * chunk_rows + end, hi)
+            if s < e:
+                segments.append((s - lo, e - lo, kb, sb))
+    return segments or None
+
+
 def _entity_shard_align(blocks) -> int:
-    """Per-device chunk size of mesh-sharded entity blocks (1 = unsharded):
-    the boundary multiple that keeps bucket slices shard-aligned."""
+    """Per-device chunk size of mesh-sharded entity blocks (1 = unsharded).
+    Read by the benchmark's re_pad_share reader alone: the solve's buckets
+    are chunk-local (``_size_buckets``)."""
     try:
         sh = blocks.features.sharding
         if len(sh.device_set) > 1:
@@ -998,25 +1034,119 @@ def _entity_shard_align(blocks) -> int:
     return 1
 
 
-def _concat_results(parts, S: int) -> SolverResult:
-    """Stitch per-bucket vmapped SolverResults back into entity order,
-    zero-padding coefficients/gradients to the global subspace dim."""
+def _chunk_rows(a, chunks: int, start: int, end: int, *dims: int):
+    """Rows [start, end) of every one of the ``chunks`` equal chunks of
+    ``a``'s leading axis, chunk-major, the trailing axes cut to ``dims``.
+    One chunk: the plain slice. (Slices joined, not a reshape sliced: behind a
+    reshape the TPU compiler re-lays the whole array out before it cuts.)"""
+    cut = tuple(slice(None, d) for d in dims)
+    rows = a.shape[0] // chunks
+    parts = [
+        a[(slice(c * rows + start, c * rows + end),) + cut] for c in range(chunks)
+    ]
+    if chunks == 1:
+        return parts[0]
+    return (np if isinstance(a, np.ndarray) else jnp).concatenate(parts)
 
-    def pad_cols(a):
-        if a.shape[-1] == S:
-            return a
-        return jnp.pad(a, ((0, 0), (0, S - a.shape[-1])))
 
-    return SolverResult(
-        coefficients=jnp.concatenate([pad_cols(p.coefficients) for p in parts]),
-        loss=jnp.concatenate([p.loss for p in parts]),
-        gradient=jnp.concatenate([pad_cols(p.gradient) for p in parts]),
-        iterations=jnp.concatenate([p.iterations for p in parts]),
-        reason=jnp.concatenate([p.reason for p in parts]),
-        loss_history=jnp.concatenate([p.loss_history for p in parts]),
-        grad_norm_history=jnp.concatenate([p.grad_norm_history for p in parts]),
-        cg_iterations=jnp.concatenate([p.cg_iterations for p in parts]),
+def _chunk_axis(a, chunks: int):
+    """(mesh, axis name) when ``a``'s leading axis is sharded over one mesh
+    axis that deals whole chunks to every device (what ``shard_entity_blocks``
+    places), else None."""
+    sharding = getattr(a, "sharding", None)
+    if not isinstance(sharding, NamedSharding) or len(sharding.device_set) == 1:
+        return None
+    axis, *rest = tuple(sharding.spec) or (None,)
+    if not isinstance(axis, str) or any(r is not None for r in rest):
+        return None
+    return (sharding.mesh, axis) if chunks % sharding.mesh.shape[axis] == 0 else None
+
+
+def _per_device(fn, chunks: int, sharded):
+    """``fn(chunks_here, arrays)`` over the whole arrays, or under ``sharded`` =
+    (mesh, axis) per device over that device's own chunks of them (shard_map:
+    no row leaves its chip, and the per-device program is the one-chip one)."""
+    if sharded is None:
+        return partial(fn, chunks)
+    mesh, axis = sharded
+    spec = PartitionSpec(axis)
+    return jax.shard_map(
+        partial(fn, chunks // mesh.shape[axis]), mesh=mesh, in_specs=spec, out_specs=spec
     )
+
+
+@partial(jax.jit, static_argnames=("chunks", "start", "end", "dims", "sharded"))
+def _chunk_rows_of(arrays, *, chunks, start, end, dims, sharded):
+    """``_chunk_rows`` of several device arrays as ONE program."""
+
+    def cut(chunks_here, arrays):
+        return tuple(
+            _chunk_rows(a, chunks_here, start, end, *d) for a, d in zip(arrays, dims)
+        )
+
+    return _per_device(cut, chunks, sharded)(arrays)
+
+
+def _bucket_operands(block_arrays, state_arrays, chunks, sharded, start, end, kb, sb):
+    """A bucket's solver operands: rows [start, end) of every chunk, cut to
+    the bucket's (K_b, S_b). ``block_arrays`` are the [E, K(, S)] features,
+    labels, offsets and weights, ``state_arrays`` the [E, S] w0 and priors
+    (host numpy on the CPU backend and across processes: cut on the host);
+    ``sharded`` is the blocks' ``_chunk_axis``."""
+    dims = ((kb, sb), (kb,), (kb,), (kb,)) + ((sb,),) * len(state_arrays)
+    arrays = tuple(block_arrays) + tuple(state_arrays)
+    if chunks == 1:
+        # one sorted run: plain eager slices (a chunked cut is one program)
+        return tuple(_chunk_rows(a, 1, start, end, *d) for a, d in zip(arrays, dims))
+    on_host = [isinstance(a, np.ndarray) for a in arrays]
+    cut = iter(
+        _chunk_rows_of(
+            tuple(a for a, h in zip(arrays, on_host) if not h),
+            chunks=chunks, start=start, end=end,
+            dims=tuple(d for d, h in zip(dims, on_host) if not h), sharded=sharded,
+        )
+    )
+    return tuple(
+        _chunk_rows(a, chunks, start, end, *d) if h else next(cut)
+        for a, d, h in zip(arrays, dims, on_host)
+    )
+
+
+def _concat_results(parts, S: int, chunks: int = 1, sharded=None) -> SolverResult:
+    """Stitch per-bucket SolverResults back into block-row order, zero-padding
+    coefficients/gradients to the global subspace dim. A part holds its
+    bucket's rows of every chunk, chunk-major (``_chunk_rows``); several
+    chunks are put back in ONE program, per device under ``sharded``."""
+    if chunks == 1:
+        return _stitch_results(S, 1, parts)
+    return _stitch_chunked_results(parts, S=S, chunks=chunks, sharded=sharded)
+
+
+def _stitch_results(S: int, chunks: int, parts) -> SolverResult:
+    def field(name):
+        columns = [getattr(p, name) for p in parts]
+        if name in ("coefficients", "gradient"):
+            columns = [
+                a if a.shape[-1] == S else jnp.pad(a, ((0, 0), (0, S - a.shape[-1])))
+                for a in columns
+            ]
+        if chunks == 1:
+            return jnp.concatenate(columns)
+        # chunk by chunk, every bucket's rows of it
+        return jnp.concatenate(
+            [
+                a[i * (a.shape[0] // chunks) : (i + 1) * (a.shape[0] // chunks)]
+                for i in range(chunks)
+                for a in columns
+            ]
+        )
+
+    return SolverResult(**{f.name: field(f.name) for f in dataclasses.fields(SolverResult)})
+
+
+@partial(jax.jit, static_argnames=("S", "chunks", "sharded"))
+def _stitch_chunked_results(parts, *, S: int, chunks: int, sharded) -> SolverResult:
+    return _per_device(partial(_stitch_results, S), chunks, sharded)(parts)
 
 
 def _concat_results_np(parts) -> SolverResult:

@@ -20,7 +20,10 @@ Per-entity local solves then become one vmapped masked solver call — the
 MXU-friendly replacement for the reference's per-entity sequential L-BFGS
 fan-out (RandomEffectCoordinate.scala:273-329). Entity order doubles as the
 sharding axis: shard dim 0 over the mesh and each device owns a contiguous
-entity range (the bin-packing partitioner's role, P5).
+range of block rows. Built for ``m`` devices (``pad_entities_to_multiple``),
+the size-sorted entities are DEALT over ``m`` chunks of block rows, so each
+device's range is size-sorted in itself and carries the same load (the
+bin-packing partitioner's role, P5; ``_entity_plan``).
 
 Active/passive split parity: entities with more than ``active_cap`` samples
 train on a deterministic hash-priority reservoir of ``active_cap`` rows with
@@ -160,12 +163,16 @@ class RandomEffectDataset:
     ell_idx: Array  # i32[n, F]
     ell_val: Array  # f[n, F]
     passive_rows: np.ndarray  # i64[*] rows not in any active block (info only)
-    # host-side per-entity stats (entities are size-sorted descending), used
-    # to bucket the vmapped solver by block size so small entities don't pay
-    # the padding of the largest (the TPU analogue of the reference's
-    # size-aware partitioning, RandomEffectDatasetPartitioner.scala:117-180)
+    # host-side per-entity stats, used to bucket the solver by block size so
+    # small entities don't pay the padding of the largest. The block rows are
+    # ``entity_chunks`` equal chunks, each size-sorted descending in itself
+    # and dealt the same size profile (_entity_plan): every size bucket has
+    # an equal share in every chunk, and so on every chip the chunks shard
+    # over (the TPU analogue of the reference's size-aware partitioning,
+    # RandomEffectDatasetPartitioner.scala:117-180)
     entity_counts: Optional[np.ndarray] = None  # i64[E] active rows per entity
     entity_subspace_dims: Optional[np.ndarray] = None  # i64[E] real S per entity
+    entity_chunks: int = 1  # size-sorted chunks the block rows hold (1: one sorted run)
     # multi-process: host copy of blocks.proj_cols (the device array is
     # entity-sharded across processes, so not host-addressable); model
     # projection / warm-start layout checks read this instead
@@ -191,15 +198,20 @@ class RandomEffectDataset:
 @dataclasses.dataclass(frozen=True)
 class _EntityPlan:
     """The deterministic entity layout every process must agree on: which
-    entities train, their block order (size-sorted descending, stable), the
-    padded block count, the per-entity active cap, and weight rescales.
+    entities train, their block order, the padded block count, the per-entity
+    active cap, and weight rescales. The order is the stable descending size
+    sort, dealt over ``chunks`` equal chunks of block rows (sorted entity j to
+    chunk j mod chunks), so each chunk is size-sorted in itself and carries
+    the same load; one chunk is the plain sort. The ``E - E_real`` pad rows
+    are the tail of the block rows in either case.
     Computed from the (possibly cross-process-merged) per-entity counts alone,
     so identical inputs give identical plans on every host."""
 
-    kept_entities: np.ndarray  # i64[E_real] indices into uniq, size-sorted
+    kept_entities: np.ndarray  # i64[E_real] indices into uniq, in block-row order
     old_to_block: np.ndarray  # i64[len(uniq)] -> block row or -1
     E_real: int
     E: int  # padded block count
+    chunks: int  # size-sorted chunks of E // chunks block rows each
     cap: int
     K: int  # block row capacity
     weight_scale: np.ndarray  # f8[E] count/cap rescale for capped entities
@@ -213,7 +225,7 @@ def _entity_plan(
 ) -> _EntityPlan:
     kept_mask = counts >= active_lower_bound
     kept_entities = np.nonzero(kept_mask)[0]
-    # order entities by descending size: natural bin-packing order for sharding
+    # order entities by descending size: the order the deal below bin-packs
     kept_entities = kept_entities[np.argsort(-counts[kept_entities], kind="stable")]
     E_real = len(kept_entities)
     E = max(
@@ -221,6 +233,11 @@ def _entity_plan(
         * pad_entities_to_multiple,
         pad_entities_to_multiple,
     )
+    chunks = pad_entities_to_multiple
+    if chunks > 1:
+        dealt = np.empty_like(kept_entities)
+        dealt[_deal_block_rows(E_real, E // chunks, chunks)] = kept_entities
+        kept_entities = dealt
     old_to_block = np.full(len(counts), -1, dtype=np.int64)
     old_to_block[kept_entities] = np.arange(E_real)
     cap = active_cap if active_cap is not None else int(counts.max() if len(counts) else 1)
@@ -234,10 +251,31 @@ def _entity_plan(
         old_to_block=old_to_block,
         E_real=E_real,
         E=E,
+        chunks=chunks,
         cap=cap,
         K=K,
         weight_scale=weight_scale,
     )
+
+
+def _deal_block_rows(n: int, chunk_rows: int, chunks: int) -> np.ndarray:
+    """Block row of each of ``n`` size-sorted entities when they are dealt
+    over ``chunks`` chunks of ``chunk_rows`` block rows, laid out chunk-major:
+    sorted entity j goes to chunk j mod chunks, position j div chunks (the
+    reference's partitioner balances load per partition the same way,
+    RandomEffectDatasetPartitioner.scala:117-180). Every chunk is then
+    size-sorted in itself, and the chunks' loads differ by at most one entity
+    a round. The rows are a permutation of [0, n): the pad rows stay the tail
+    of the last chunk(s), so a chunk that the tail has filled sits out the
+    later rounds."""
+    capacity = np.clip(n - np.arange(chunks) * chunk_rows, 0, chunk_rows)
+    # chunks still open at each position: capacities are non-increasing, so
+    # the open chunks of a round are the first ``open_at[p]``
+    open_at = np.searchsorted(-capacity, -np.arange(chunk_rows), side="left")
+    round_start = np.concatenate([[0], np.cumsum(open_at)])
+    j = np.arange(n)
+    position = np.searchsorted(round_start, j, side="right") - 1
+    return (j - round_start[position]) * chunk_rows + position
 
 
 def _hash64(a: np.ndarray, seed: int) -> np.ndarray:
@@ -793,6 +831,7 @@ def build_random_effect_dataset(
         passive_rows=passive,
         entity_counts=np.sum(active_rows_np >= 0, axis=1).astype(np.int64),
         entity_subspace_dims=per_entity_s.astype(np.int64),
+        entity_chunks=plan.chunks,
         streamed=streamed,
         hbm_budget_bytes=hbm_budget_bytes if streamed else None,
     )

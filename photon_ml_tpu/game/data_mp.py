@@ -12,7 +12,8 @@ read its own row range), so the build splits into
 1. **Planning metadata exchange** (host, small): each process allgathers its
    local (entity id, count) table (`multihost.allgather_object`); every
    process merges them identically and derives the same `_EntityPlan`
-   (size-sorted entity order, block capacity K, weight rescales) — the
+   (size-sorted entity order dealt over the mesh's chunks, block capacity K,
+   weight rescales) — the
    analogue of the reference's driver-side partitioner state.
 2. **Device-side shuffle** (bulk, zero host networking): per-row planning
    columns (entity index, splitmix64 reservoir priority) and the row data
@@ -375,6 +376,7 @@ def build_random_effect_dataset_global(
         passive_rows=_derive_passive_rows(mesh, ent_local, n_local, active_rows),
         entity_counts=entity_counts,
         entity_subspace_dims=sizes_host,
+        entity_chunks=plan.chunks,
         host_proj_cols=host_pc,
         streamed=streamed,
         hbm_budget_bytes=hbm_budget_bytes if streamed else None,

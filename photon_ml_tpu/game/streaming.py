@@ -17,7 +17,8 @@ HOST memory (numpy) and pipelines fixed-size entity slices through the chip:
   solve is dispatched, so device residency stays bounded by ~2 slices of
   data + solver state regardless of total model size.
 
-Slices respect the size-bucket segmentation (``_size_buckets``), so each
+Slices respect the size-bucket segmentation (``_contiguous_segments``: every
+chunk's copy of every ``_size_buckets`` bucket, as block-row ranges), so each
 solve call keeps the bucket's (K, S)-rounded shapes and the packed solver's
 lane economy. Scoring streams the per-entity coefficient table through the
 chip the same way (the model itself is bigger than the budget by
@@ -97,7 +98,7 @@ def entities_per_slice(
 
 def solve_streamed(
     blocks_np,  # EntityBlocks holding HOST numpy arrays
-    segments,  # [(start, end, K_b, S_b)] from _size_buckets (or one segment)
+    segments,  # [(start, end, K_b, S_b)] from _contiguous_segments (or one segment)
     residual_scores: Optional[Array],  # device f[n] or None
     w0_np: np.ndarray,  # [E, S] host
     prior_mean_np: np.ndarray,

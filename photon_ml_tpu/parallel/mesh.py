@@ -137,13 +137,18 @@ def shard_coefficients(w: jnp.ndarray, mesh: Mesh) -> jnp.ndarray:
 
 
 def shard_entity_blocks(blocks, mesh: Mesh):
-    """Shard EntityBlocks on the entity dim over the data axis (P5)."""
+    """Shard EntityBlocks on the entity dim over the data axis (P5): each
+    device takes a contiguous range of block rows. A dataset built with
+    ``pad_entities_to_multiple`` = the axis size holds one size-sorted chunk
+    of equal load per device (game/data.py ``_entity_plan``)."""
     n_data = mesh.shape[DATA_AXIS]
     E = blocks.features.shape[0]
     if E % n_data != 0:
         raise ValueError(
             f"entity count {E} must divide the data axis ({n_data}); "
-            f"build the dataset with pad_entities_to_multiple={n_data}"
+            f"build the dataset with pad_entities_to_multiple={n_data}, which "
+            f"also deals the entities over the axis so that every device "
+            f"holds the same load"
         )
 
     def put(a):

@@ -14,7 +14,7 @@ import pytest
 
 from photon_ml_tpu import obs
 from photon_ml_tpu.estimators import CoordinateConfig, GameEstimator
-from photon_ml_tpu.game.coordinate import _entity_shard_align, _size_buckets
+from photon_ml_tpu.game.coordinate import _size_buckets
 from photon_ml_tpu.game.problem import GLMOptimizationConfig
 from photon_ml_tpu.ops.regularization import RegularizationContext
 from photon_ml_tpu.optimize import OptimizerConfig, OptimizerType
@@ -168,31 +168,90 @@ def test_one_root_id_per_fit(traced):
     assert a == b
 
 
-def test_bucket_attributes_and_slot_counter_agree_with_the_dataset(traced, data):
-    _, spans, snapshot, _ = traced
-    dataset = data[2]["per-user"]
+def _assert_buckets_agree_with_the_dataset(dataset, buckets, snapshot, n_trains):
+    """``re.bucket`` spans and photon_re_block_slots_total against the
+    dataset's own segmentation: a bucket is rows [start, end) of every chunk,
+    and every slot handed to the solver (chunks * n_b * K_b) is counted."""
+    chunks = dataset.entity_chunks
     n_entities = dataset.blocks.features.shape[0]
     real_rows = int(dataset.entity_counts.sum())
-    segments = _size_buckets(dataset, align=_entity_shard_align(dataset.blocks))
+    segments = _size_buckets(dataset)
     assert segments is not None and len(segments) > 1
-    buckets = [s for s in spans if s.name == "re.bucket"]
-    n_trains = 2 * N_SWEEPS
     assert len(buckets) == n_trains * len(segments)
     one_train = buckets[: len(segments)]
     assert [(s.attrs["k"], s.attrs["s"], s.attrs["entities"]) for s in one_train] == [
-        (kb, sb, end - start) for start, end, kb, sb in segments
+        (kb, sb, chunks * (end - start)) for start, end, kb, sb in segments
     ]
+    assert all(s.attrs["chunks"] == chunks for s in one_train)
     assert sum(s.attrs["entities"] for s in one_train) == n_entities
     assert sum(s.attrs["real_rows"] for s in one_train) == real_rows
-    padded_slots = sum((end - start) * kb for start, end, kb, _ in segments)
+    by_chunk = dataset.entity_counts.reshape(chunks, -1)
+    assert [s.attrs["max_chunk_real_rows"] for s in one_train] == [
+        by_chunk[:, start:end].sum(axis=1).max() for start, end, _, _ in segments
+    ]
+    padded_slots = sum(chunks * (end - start) * kb for start, end, kb, _ in segments)
     assert sum(s.attrs["slots"] for s in one_train) == padded_slots
-    real = _counter(snapshot, "photon_re_block_slots_total", coordinate="per-user", kind="real")
-    padded = _counter(snapshot, "photon_re_block_slots_total", coordinate="per-user", kind="padded")
+    label = dict(coordinate=dataset.coordinate_id)
+    real = _counter(snapshot, "photon_re_block_slots_total", kind="real", **label)
+    padded = _counter(snapshot, "photon_re_block_slots_total", kind="padded", **label)
     assert real == n_trains * real_rows
     assert real + padded == n_trains * padded_slots
+
+
+def test_bucket_attributes_and_slot_counter_agree_with_the_dataset(traced, data):
+    _, spans, snapshot, _ = traced
+    dataset = data[2]["per-user"]
+    assert dataset.entity_chunks == 1
+    buckets = [s for s in spans if s.name == "re.bucket"]
+    _assert_buckets_agree_with_the_dataset(dataset, buckets, snapshot, 2 * N_SWEEPS)
+    # one chunk holds every real row of a bucket
+    assert all(s.attrs["max_chunk_real_rows"] == s.attrs["real_rows"] for s in buckets)
+    n_entities = dataset.blocks.features.shape[0]
     exchange = next(s for s in spans if s.name == "re.exchange")
     k = dataset.blocks.features.shape[1]
     assert (exchange.attrs["entities"], exchange.attrs["slots"]) == (n_entities, n_entities * k)
+
+
+@pytest.mark.parametrize("chunks", [4, 8])
+def test_bucket_spans_of_a_dealt_dataset_give_the_chips_balance(chunks):
+    """Under a mesh the dataset holds one size-sorted chunk a chip: a bucket's
+    span says how many chunks it draws on and the largest chunk's real rows,
+    so that sum(real_rows) / sum(chunks * max_chunk_real_rows) is the balance."""
+    from photon_ml_tpu.game import RandomEffectCoordinate, build_random_effect_dataset
+    from photon_ml_tpu.parallel import data_parallel_mesh, shard_entity_blocks
+    import dataclasses
+
+    raw = mixed_data_to_raw_dataset(
+        generate_mixed_effect_data(
+            n=4000, d_fixed=4, re_specs={"userId": (203, 4)}, seed=3, entity_skew=1.2
+        )
+    )
+    dataset = build_random_effect_dataset(
+        raw, "per-user", "userShard", "userId", active_cap=32,
+        pad_entities_to_multiple=chunks, dtype=jnp.float64,
+    )
+    dataset = dataclasses.replace(
+        dataset, blocks=shard_entity_blocks(dataset.blocks, data_parallel_mesh(chunks))
+    )
+    assert dataset.entity_chunks == chunks
+    config = GLMOptimizationConfig(
+        optimizer=OptimizerConfig(tolerance=1e-6, max_iterations=10),
+        regularization=RegularizationContext("L2"),
+        reg_weight=1.0,
+    )
+    run, spans = obs.RunTelemetry(), _Spans()
+    run.register_listener(spans)
+    with obs.use_run(run):
+        RandomEffectCoordinate(
+            dataset=dataset, task="logistic_regression", config=config
+        ).train(None)
+    buckets = [s for s in spans.spans if s.name == "re.bucket"]
+    _assert_buckets_agree_with_the_dataset(dataset, buckets, run.registry.snapshot(), 1)
+    balance = 100.0 * sum(s.attrs["real_rows"] for s in buckets) / sum(
+        s.attrs["chunks"] * s.attrs["max_chunk_real_rows"] for s in buckets
+    )
+    # an even deal reads 100; 200 users over 4 or 8 chunks: one user a bucket off
+    assert 85.0 < balance <= 100.0
 
 
 def test_lane_iterations_are_useful_over_issued(traced):

@@ -1,0 +1,91 @@
+"""Programs of the mesh path compiled for a described v5e 2x2 host (no chip is
+attached and nothing runs): what the TPU compiler does with them, which the
+CPU backend's tests cannot show. Keep every such compile in THIS file, behind
+the ``topo`` fixture: one process at a time may load the TPU's library."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+# the four-chip cell's blocks (benchmark/configs/glmix-user-4chip-mesh.json)
+E, K, S, CHIPS = 104_856, 256, 32, 4
+COLLECTIVE = re.compile(r"all-gather|all-to-all|collective-permute|all-reduce")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    return Mesh(np.array(topo.devices).reshape(CHIPS), ("data",))
+
+
+def _sharded(mesh, shape, dtype=jnp.float32):
+    spec = PartitionSpec("data", *([None] * (len(shape) - 1)))
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+
+@pytest.mark.parametrize("chunks", [4, 8])
+@pytest.mark.parametrize("bucket", [(0, 1500, 256, 32), (6000, 13107, 8, 8)])
+def test_bucket_operands_are_cut_on_their_own_chip(mesh, chunks, bucket):
+    """A bucket's operands from the dealt, P(data)-sharded blocks: no
+    collective, the operands sharded as the blocks are, and no temporary the
+    size of a chip's whole feature block (a reshape sliced, where this joins
+    slices, had the compiler re-lay all 859 MB out before it cut 3 MB)."""
+    from photon_ml_tpu.game.coordinate import _chunk_rows_of
+
+    start, end, kb, sb = bucket
+    arrays = (
+        _sharded(mesh, (E, K, S)), _sharded(mesh, (E, K)), _sharded(mesh, (E, K)),
+        _sharded(mesh, (E, K)), _sharded(mesh, (E, S)),
+    )
+    dims = ((kb, sb), (kb,), (kb,), (kb,), (sb,))
+    compiled = _chunk_rows_of.lower(
+        arrays, chunks=chunks, start=start, end=end, dims=dims, sharded=(mesh, "data")
+    ).compile()
+    assert not COLLECTIVE.findall(compiled.as_text())
+    for out, a in zip(compiled.output_shardings, arrays):
+        assert out.is_equivalent_to(a.sharding, len(a.shape))
+    features, labels, *_ = jax.tree_util.tree_leaves(compiled.out_info)
+    rows = chunks * (end - start)
+    assert features.shape == (rows, kb, sb) and labels.shape == (rows, kb)
+    chip_features_bytes = E // CHIPS * K * S * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < chip_features_bytes // 8
+
+
+def test_bucket_results_go_back_on_their_own_chip(mesh):
+    from photon_ml_tpu.game.coordinate import _stitch_chunked_results
+    from photon_ml_tpu.optimize import SolverResult
+
+    segments = [(0, 1500, 32), (1500, 8000, 32), (8000, 18000, 16), (18000, 26214, 8)]
+    parts = []
+    for start, end, sb in segments:
+        n = CHIPS * (end - start)
+        lane = _sharded(mesh, (n,), jnp.int32)
+        parts.append(
+            SolverResult(
+                coefficients=_sharded(mesh, (n, sb)), loss=_sharded(mesh, (n,)),
+                gradient=_sharded(mesh, (n, sb)), iterations=lane, reason=lane,
+                loss_history=_sharded(mesh, (n, 31)),
+                grad_norm_history=_sharded(mesh, (n, 31)), cg_iterations=lane,
+            )
+        )
+    compiled = _stitch_chunked_results.lower(
+        parts, S=S, chunks=CHIPS, sharded=(mesh, "data")
+    ).compile()
+    assert not COLLECTIVE.findall(compiled.as_text())
+    out = compiled.out_info
+    assert out.coefficients.shape == (E, S) and out.iterations.shape == (E,)
+    want = NamedSharding(mesh, PartitionSpec("data"))
+    assert compiled.output_shardings.coefficients.is_equivalent_to(want, 2)
