@@ -18,6 +18,14 @@ solves). Quality metrics come from ONE scoring pass after the timed
 training wall. ``--verbose`` restores per-update eval + INFO logging for
 debugging convergence.
 
+The MEASURED shape of this example is the benchmark configuration
+``benchmark/configs/glmix-user-item-1chip.json`` (cell
+``glmix-user-item-1chip.fit-3coord``: the same three coordinates and update
+sequence at d 1024, d_re 32, 1.31M rows on one TPU v5e chip; numbers in
+PERF.md). The wall this script prints is the wall of whatever backend it ran
+on (``backend`` in its result line) with one-time builds and compiles inside
+it: a smoke observation, never a device metric.
+
 Run:  python examples/glmix_movielens.py [--out out-glmix] [--n 100000]
 """
 
@@ -27,6 +35,8 @@ import os
 import sys
 import tempfile
 import time
+
+import jax
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -149,10 +159,9 @@ def main():
         "users": args.users,
         "items": args.items,
         "cd_sweeps": args.cd_iterations,
+        # of this run on this backend, builds and compiles included
+        "backend": jax.default_backend(),
         "wall_clock_s": round(wall, 2),
-        "examples_per_sec_per_sweep": round(
-            n_train * args.cd_iterations / wall, 1
-        ),
     }
     print(json.dumps(result))
     # random effects must add signal over the fixed effect alone

@@ -117,7 +117,9 @@ class FixedEffectCoordinate(Coordinate):
             prior=self.prior_model.model.coefficients if self.prior_model else None,
         )
         glm, result = problem.run(
-            batch, initial_model=initial_model.model if initial_model else None
+            batch,
+            initial_model=initial_model.model if initial_model else None,
+            coordinate=self.coordinate_id,
         )
         if jax.process_count() > 1:
             # tiled solves leave coefficients model-axis-sharded across
@@ -313,7 +315,7 @@ class FixedEffectCoordinate(Coordinate):
                     local, ds.mesh, PartitionSpec(DATA_AXIS)
                 )
             return scores
-        with obs.span("fe.score") as sp:
+        with obs.span("fe.score", coordinate=self.coordinate_id) as sp:
             scores = self._score_resident(model)
             sp.sync(scores)
         return scores
@@ -387,7 +389,9 @@ class RandomEffectCoordinate(Coordinate):
         if residual_scores is not None:
             # the residual exchange: every block slot gathers its row's
             # residual (the other coordinates' summed scores)
-            with obs.span("re.exchange", entities=E, slots=E * K) as sp:
+            with obs.span(
+                "re.exchange", coordinate=self.coordinate_id, entities=E, slots=E * K
+            ) as sp:
                 res_blocks = jnp.take(
                     residual_scores, jnp.maximum(blocks.active_rows, 0), axis=0
                 ) * (blocks.active_rows >= 0)
@@ -464,7 +468,10 @@ class RandomEffectCoordinate(Coordinate):
         for start, end, kb, sb in segments or [(0, E // chunks, K, S)]:
             entities = chunks * (end - start)
             slots = entities * kb
-            shape = dict(k=kb, s=sb, entities=entities, slots=slots, chunks=chunks)
+            shape = dict(
+                coordinate=self.coordinate_id,
+                k=kb, s=sb, entities=entities, slots=slots, chunks=chunks,
+            )
             if counts is not None:
                 chunk_real = chunk_counts[:, start:end].sum(axis=1)
                 shape["real_rows"] = int(chunk_real.sum())
@@ -503,7 +510,21 @@ class RandomEffectCoordinate(Coordinate):
             slot_counter.labels(coordinate=self.coordinate_id, kind="padded").inc(
                 padded_slots
             )
-        with obs.span("re.collect") as sp:
+            # host-known from the dataset: the rows this coordinate trains on
+            # (the buckets' real slots) against the rows over the active cap,
+            # which it only scores
+            row_counter = obs.current_run().registry.counter(
+                "photon_re_rows_total",
+                "rows of a random-effect coordinate per train call: active "
+                "(in an entity block) against passive (scored, never trained)",
+            )
+            row_counter.labels(coordinate=self.coordinate_id, kind="active").inc(
+                real_slots
+            )
+            row_counter.labels(coordinate=self.coordinate_id, kind="passive").inc(
+                len(self.dataset.passive_rows)
+            )
+        with obs.span("re.collect", coordinate=self.coordinate_id) as sp:
             results = (
                 parts[0]
                 if segments is None
@@ -860,7 +881,7 @@ class RandomEffectCoordinate(Coordinate):
                     local, ds.mesh, PartitionSpec(DATA_AXIS)
                 )
             return scores
-        with obs.span("re.score") as sp:
+        with obs.span("re.score", coordinate=self.coordinate_id) as sp:
             scores = self._score_resident(model)
             sp.sync(scores)
         return scores

@@ -181,8 +181,10 @@ class GLMProblem:
         self,
         batch: LabeledBatch,
         initial_model: Optional[GeneralizedLinearModel] = None,
+        coordinate: Optional[str] = None,
     ) -> Tuple[GeneralizedLinearModel, SolverResult]:
         """Train; returns (model in ORIGINAL space, solver result).
+        ``coordinate`` names the caller's coordinate on the ``fe.solve`` span.
 
         Normalization semantics parity (Optimizer.scala:161-185 +
         GeneralizedLinearOptimizationProblem): warm-start coefficients are
@@ -223,6 +225,7 @@ class GLMProblem:
         solver_config = self.config.solver_config()
         with obs.span(
             "fe.solve",
+            coordinate=coordinate,
             optimizer=solver_config.normalized_type().value,
             reg_weight=float(self.config.reg_weight),
         ) as sp:

@@ -47,7 +47,10 @@ def optimize(
         loss_tol, grad_tol = abs_tolerances(value_and_grad, w0, config.tolerance)
     else:
         # one more objective pass at zero coefficients, before every solve
-        with obs.span("fe.tolerances") as sp:
+        # (the enclosing fe.solve span says whose solve this is)
+        solve_span = obs.current_span()
+        coordinate = solve_span.attrs.get("coordinate") if solve_span else None
+        with obs.span("fe.tolerances", coordinate=coordinate) as sp:
             loss_tol, grad_tol = abs_tolerances(value_and_grad, w0, config.tolerance)
             sp.sync(loss_tol, grad_tol)
     kind = config.normalized_type()
