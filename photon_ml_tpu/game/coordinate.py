@@ -386,26 +386,46 @@ class RandomEffectCoordinate(Coordinate):
         # (feature_dtype), labels/weights/offsets carry the solve precision
         dtype = blocks.labels.dtype
 
+        # Size-bucketed solves: each of the dataset's chunks is sorted by
+        # descending row count and dealt the same size profile, so a (K, S)-
+        # rounded bucket is the SAME local row range of every chunk; solving
+        # per bucket avoids every small entity paying the padding of the
+        # largest (RandomEffectDatasetPartitioner's size-awareness, re-purposed
+        # for vmap lane economy), and under a mesh every chip holds an equal
+        # share of every bucket. No buckets: one whole-block solve, on the
+        # arrays as they stand (a full-range slice would copy).
+        segments = _size_buckets(self.dataset)
+        chunks = self.dataset.entity_chunks
+        sharded = _chunk_axis(blocks.features, chunks)
+        buckets = tuple(segments or [(0, E // chunks, K, S)])
+        exchange = partial(
+            _bucket_offsets, blocks.active_rows, blocks.offsets,
+            segments=buckets, chunks=chunks, sharded=sharded,
+        )
         if residual_scores is not None:
-            # the residual exchange: every block slot gathers its row's
-            # residual (the other coordinates' summed scores)
+            # the residual exchange: every slot a bucket will solve gathers
+            # its row's residual (the other coordinates' summed scores);
+            # block_slots is the [E, K] plane the buckets are cut from
             with obs.span(
-                "re.exchange", coordinate=self.coordinate_id, entities=E, slots=E * K
+                "re.exchange", coordinate=self.coordinate_id, entities=E,
+                slots=sum(chunks * (end - start) * kb for start, end, kb, _ in buckets),
+                block_slots=E * K,
             ) as sp:
-                res_blocks = jnp.take(
-                    residual_scores, jnp.maximum(blocks.active_rows, 0), axis=0
-                ) * (blocks.active_rows >= 0)
-                offsets = blocks.offsets + res_blocks.astype(dtype)
-                sp.sync(offsets)
+                bucket_offsets = exchange(residual_scores)
+                sp.sync(bucket_offsets)
         else:
-            offsets = blocks.offsets
+            bucket_offsets = exchange(None)
         if faults.active():
             # same fault site as the fixed-effect path; flat index 0 of the
-            # [E, K] offsets is entity 0's first row, so the corruption
-            # deterministically poisons exactly one entity lane. (The
-            # streamed path carries no injection site — its offsets never
-            # materialize whole.)
-            offsets = faults.corrupt("solver.value_and_grad", offsets)
+            # FIRST bucket's offsets is entity 0's first row, so the
+            # corruption deterministically poisons exactly one entity lane
+            # (handed every bucket's array it would poison one lane in each).
+            # (The streamed path carries no injection site — its offsets
+            # never materialize whole.)
+            bucket_offsets = (
+                faults.corrupt("solver.value_and_grad", bucket_offsets[0]),
+                *bucket_offsets[1:],
+            )
 
         # w0/priors: multi-process passes host numpy (every process holds the
         # full array; jit treats numpy inputs as replicated contributions).
@@ -449,23 +469,12 @@ class RandomEffectCoordinate(Coordinate):
 
         solver_kwargs = self._solver_kwargs()
         train_fn = self._train_fn()
-        # Size-bucketed solves: each of the dataset's chunks is sorted by
-        # descending row count and dealt the same size profile, so a (K, S)-
-        # rounded bucket is the SAME local row range of every chunk; solving
-        # per bucket avoids every small entity paying the padding of the
-        # largest (RandomEffectDatasetPartitioner's size-awareness, re-purposed
-        # for vmap lane economy), and under a mesh every chip holds an equal
-        # share of every bucket. No buckets: one whole-block solve, on the
-        # arrays as they stand (a full-range slice would copy).
-        segments = _size_buckets(self.dataset)
-        chunks = self.dataset.entity_chunks
-        sharded = _chunk_axis(blocks.features, chunks)
         counts = self.dataset.entity_counts
         if counts is not None:
             chunk_counts = np.asarray(counts).reshape(chunks, -1)
         real_slots = padded_slots = 0
         parts = []
-        for start, end, kb, sb in segments or [(0, E // chunks, K, S)]:
+        for (start, end, kb, sb), offsets in zip(buckets, bucket_offsets):
             entities = chunks * (end - start)
             slots = entities * kb
             shape = dict(
@@ -488,8 +497,8 @@ class RandomEffectCoordinate(Coordinate):
                 else:
                     part = train_fn(
                         *_bucket_operands(
-                            (blocks.features, blocks.labels, offsets, blocks.weights),
-                            (w0, prior_mean, prior_prec),
+                            (blocks.features, blocks.labels, blocks.weights),
+                            offsets, (w0, prior_mean, prior_prec),
                             chunks, sharded, start, end, kb, sb,
                         ),
                         **solver_kwargs,
@@ -1083,16 +1092,19 @@ def _chunk_axis(a, chunks: int):
     return (sharding.mesh, axis) if chunks % sharding.mesh.shape[axis] == 0 else None
 
 
-def _per_device(fn, chunks: int, sharded):
-    """``fn(chunks_here, arrays)`` over the whole arrays, or under ``sharded`` =
-    (mesh, axis) per device over that device's own chunks of them (shard_map:
-    no row leaves its chip, and the per-device program is the one-chip one)."""
+def _per_device(fn, chunks: int, sharded, n_whole: int = 0):
+    """``fn(chunks_here, arrays, *whole)`` over the whole arrays, or under
+    ``sharded`` = (mesh, axis) per device over that device's own chunks of
+    ``arrays`` (shard_map: no row leaves its chip, and the per-device program is
+    the one-chip one); the ``n_whole`` operands after them reach every device
+    entire."""
     if sharded is None:
         return partial(fn, chunks)
     mesh, axis = sharded
     spec = PartitionSpec(axis)
     return jax.shard_map(
-        partial(fn, chunks // mesh.shape[axis]), mesh=mesh, in_specs=spec, out_specs=spec
+        partial(fn, chunks // mesh.shape[axis]), mesh=mesh,
+        in_specs=(spec,) + (PartitionSpec(),) * n_whole, out_specs=spec,
     )
 
 
@@ -1108,29 +1120,72 @@ def _chunk_rows_of(arrays, *, chunks, start, end, dims, sharded):
     return _per_device(cut, chunks, sharded)(arrays)
 
 
-def _bucket_operands(block_arrays, state_arrays, chunks, sharded, start, end, kb, sb):
+@partial(jax.jit, static_argnames=("segments", "chunks", "sharded"))
+def _bucket_offsets(active_rows, offsets, residual_scores, *, segments, chunks, sharded):
+    """The residual exchange as ONE program: every bucket's solver offsets,
+    one ``[chunks * (end - start), K_b]`` array a segment, rows as
+    ``_chunk_rows`` orders them. Only the slots a bucket solves are gathered:
+    ``active_rows`` and ``offsets`` ([E, K]) are cut to the bucket BEFORE the
+    residual is gathered at those rows, padding slots (-1) masked, and added.
+    Under ``sharded`` every chip gathers for its own chunks from the whole [N]
+    residual (all-gathered once on entry when it is row-sharded).
+    ``residual_scores`` None: the blocks' own offsets, cut."""
+
+    def gather(chunks_here, blocks, *residual):
+        active_rows, offsets = blocks
+        chunk_rows = offsets.shape[0] // chunks_here
+
+        def piece(c, start, end, kb):
+            cut = (slice(c * chunk_rows + start, c * chunk_rows + end), slice(None, kb))
+            if not residual:
+                return offsets[cut]
+            rows = active_rows[cut]
+            res = jnp.take(residual[0], jnp.maximum(rows, 0), axis=0) * (rows >= 0)
+            return offsets[cut] + res.astype(offsets.dtype)
+
+        # slices gathered chunk by chunk and joined LAST: joined first, the
+        # TPU compiler re-lays a narrow bucket's index array out row-major
+        # (lane-padded 128 / K_b times) to flatten it for the gather
+        return tuple(
+            jnp.concatenate([piece(c, start, end, kb) for c in range(chunks_here)])
+            for start, end, kb, _ in segments
+        )
+
+    whole = () if residual_scores is None else (residual_scores,)
+    return _per_device(gather, chunks, sharded, len(whole))(
+        (active_rows, offsets), *whole
+    )
+
+
+def _bucket_operands(
+    block_arrays, offsets, state_arrays, chunks, sharded, start, end, kb, sb
+):
     """A bucket's solver operands: rows [start, end) of every chunk, cut to
     the bucket's (K_b, S_b). ``block_arrays`` are the [E, K(, S)] features,
-    labels, offsets and weights, ``state_arrays`` the [E, S] w0 and priors
-    (host numpy on the CPU backend and across processes: cut on the host);
-    ``sharded`` is the blocks' ``_chunk_axis``."""
-    dims = ((kb, sb), (kb,), (kb,), (kb,)) + ((sb,),) * len(state_arrays)
+    labels and weights, ``offsets`` the bucket's own from the exchange
+    (``_bucket_offsets``: cut already), ``state_arrays`` the [E, S] w0 and
+    priors (host numpy on the CPU backend and across processes: cut on the
+    host); ``sharded`` is the blocks' ``_chunk_axis``."""
+    dims = ((kb, sb), (kb,), (kb,)) + ((sb,),) * len(state_arrays)
     arrays = tuple(block_arrays) + tuple(state_arrays)
     if chunks == 1:
         # one sorted run: plain eager slices (a chunked cut is one program)
-        return tuple(_chunk_rows(a, 1, start, end, *d) for a, d in zip(arrays, dims))
-    on_host = [isinstance(a, np.ndarray) for a in arrays]
-    cut = iter(
-        _chunk_rows_of(
-            tuple(a for a, h in zip(arrays, on_host) if not h),
-            chunks=chunks, start=start, end=end,
-            dims=tuple(d for d, h in zip(dims, on_host) if not h), sharded=sharded,
+        cut = (_chunk_rows(a, 1, start, end, *d) for a, d in zip(arrays, dims))
+    else:
+        on_host = [isinstance(a, np.ndarray) for a in arrays]
+        on_device = iter(
+            _chunk_rows_of(
+                tuple(a for a, h in zip(arrays, on_host) if not h),
+                chunks=chunks, start=start, end=end,
+                dims=tuple(d for d, h in zip(dims, on_host) if not h), sharded=sharded,
+            )
         )
-    )
-    return tuple(
-        _chunk_rows(a, chunks, start, end, *d) if h else next(cut)
-        for a, d, h in zip(arrays, dims, on_host)
-    )
+        cut = (
+            _chunk_rows(a, chunks, start, end, *d) if h else next(on_device)
+            for a, d, h in zip(arrays, dims, on_host)
+        )
+    features, labels, weights, *state = cut
+    return (features, labels, offsets, weights, *state)
 
 
 def _concat_results(parts, S: int, chunks: int = 1, sharded=None) -> SolverResult:

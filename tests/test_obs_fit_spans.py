@@ -206,10 +206,17 @@ def test_bucket_attributes_and_slot_counter_agree_with_the_dataset(traced, data)
     _assert_buckets_agree_with_the_dataset(dataset, buckets, snapshot, 2 * N_SWEEPS)
     # one chunk holds every real row of a bucket
     assert all(s.attrs["max_chunk_real_rows"] == s.attrs["real_rows"] for s in buckets)
-    n_entities = dataset.blocks.features.shape[0]
-    exchange = next(s for s in spans if s.name == "re.exchange")
-    k = dataset.blocks.features.shape[1]
-    assert (exchange.attrs["entities"], exchange.attrs["slots"]) == (n_entities, n_entities * k)
+    # the exchange gathers the slots its train call's buckets solve, and says
+    # beside them how many the [E, K] plane they are cut from holds
+    n_entities, k, _ = dataset.blocks.features.shape
+    exchanges = [s for s in spans if s.name == "re.exchange"]
+    assert len(exchanges) == 2 * N_SWEEPS
+    for exchange in exchanges:
+        train = exchange.parent_id
+        solved = sum(s.attrs["slots"] for s in buckets if s.parent_id == train)
+        assert 0 < solved < n_entities * k
+        assert exchange.attrs["entities"] == n_entities
+        assert (exchange.attrs["slots"], exchange.attrs["block_slots"]) == (solved, n_entities * k)
 
 
 @pytest.mark.parametrize("chunks", [4, 8])

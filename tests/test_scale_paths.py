@@ -451,10 +451,12 @@ def test_bucket_operands_keep_every_row_on_its_chip():
     w0 = np.arange(E * S, dtype=np.float64).reshape(E, S)
     parts = []
     for start, end, kb, sb in _size_buckets(ds):
-        feats, labels, _, _, w0_b = _bucket_operands(
-            (blocks.features, blocks.labels, blocks.offsets, blocks.weights),
-            (w0,), m, sharded, start, end, kb, sb,
+        ready = object()  # the bucket's offsets come cut, from the exchange
+        feats, labels, offsets, _, w0_b = _bucket_operands(
+            (blocks.features, blocks.labels, blocks.weights),
+            ready, (w0,), m, sharded, start, end, kb, sb,
         )
+        assert offsets is ready
         n_b = end - start
         assert feats.shape == (m * n_b, kb, sb) and labels.shape == (m * n_b, kb)
         assert isinstance(w0_b, np.ndarray) and w0_b.shape == (m * n_b, sb)

@@ -89,3 +89,48 @@ def test_bucket_results_go_back_on_their_own_chip(mesh):
     assert out.coefficients.shape == (E, S) and out.iterations.shape == (E,)
     want = NamedSharding(mesh, PartitionSpec("data"))
     assert compiled.output_shardings.coefficients.is_equivalent_to(want, 2)
+
+
+# the four-chip cell's buckets, rows of ONE chunk: _size_buckets of its quotas
+# (benchmark.data.user_quotas(N_ROWS, E, 1.1), capped at K, size-sorted, dealt)
+N_ROWS = 2_621_440
+SEGMENTS = {
+    4: ((0, 335, 256, 32), (335, 629, 128, 32), (629, 1182, 64, 32),
+        (1182, 2219, 32, 32), (2219, 4167, 16, 32), (4167, 26214, 8, 32)),
+    8: ((0, 168, 256, 32), (168, 315, 128, 32), (315, 591, 64, 32),
+        (591, 1110, 32, 32), (1110, 2084, 16, 32), (2084, 13107, 8, 32)),
+}
+
+
+@pytest.mark.parametrize("chunks", [4, 8])
+def test_the_exchange_gathers_a_buckets_slots_on_its_own_chip(mesh, chunks):
+    """The residual exchange over the dealt, P(data)-sharded blocks and the
+    row-sharded residual: the only thing that crosses chips is the [N]
+    residual, all-gathered once; every bucket's offsets come out sharded as
+    the blocks are; and nothing of a chip's [E/4, K] plane is materialised
+    (6% of its slots are gathered)."""
+    from photon_ml_tpu.game.coordinate import _bucket_offsets
+
+    segments = SEGMENTS[chunks]
+    assert all(end * chunks <= E for _, end, _, _ in segments)
+    active_rows, offsets = _sharded(mesh, (E, K), jnp.int32), _sharded(mesh, (E, K))
+    residual = _sharded(mesh, (N_ROWS,))
+    compiled = _bucket_offsets.lower(
+        active_rows, offsets, residual,
+        segments=segments, chunks=chunks, sharded=(mesh, "data"),
+    ).compile()
+    crossing = [
+        line.strip() for line in compiled.as_text().splitlines()
+        if re.search(r"= \S+ (%s)(-start|-done)?\(" % COLLECTIVE.pattern, line)
+    ]
+    assert crossing and all(
+        re.search(rf"= f32\[{N_ROWS}\]\S* all-gather", line) for line in crossing
+    ), crossing
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [o.shape for o in outs] == [
+        (chunks * (end - start), kb) for start, end, kb, _ in segments
+    ]
+    for sharding in compiled.output_shardings:
+        assert sharding.is_equivalent_to(offsets.sharding, 2)
+    chip_plane_bytes = E // CHIPS * K * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < chip_plane_bytes // 8 + N_ROWS * 4
