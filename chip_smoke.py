@@ -101,7 +101,7 @@ def phase_device() -> dict:
         "count": len(devices),
     }
 
-    from photon_ml_tpu.analysis.runtime import transfer_guard
+    from photon_ml_tpu.utils.transfer import transfer_guard
 
     # the CD sweep's transfer guard is a no-op on the CPU backend; here an
     # implicit fetch inside it must raise

@@ -30,11 +30,14 @@ that discipline, in two halves:
   / ``# photon: lock-order[LockA < LockB]`` / ``# photon:
   static-arg[name]``; grandfather findings in a checked-in baseline.
 
-- **runtime**: :func:`transfer_guard`, a context manager the CD sweep and
-  bench enter, which makes JAX hard-error on any *implicit* device->host
+- **runtime**: :func:`transfer_guard`, a context manager the CD sweep
+  enters, which makes JAX hard-error on any *implicit* device->host
   fetch. Legitimate fetches go through :func:`logged_fetch` (explicit
   ``jax.device_get`` + an obs byte counter), so "zero unlogged fetches in
   the hot loop" is enforced by the runtime, not just asserted by a test.
+  That half lives in ``photon_ml_tpu/utils/transfer.py`` (the measured path
+  imports it from there and never imports this package); the four names
+  are re-exported here.
 """
 
 from .config import LintConfig, find_repo_root, load_config
@@ -50,7 +53,7 @@ from .engine import (
 )
 from .project import analyze_project
 from .rules import RULES, explain_rule
-from .runtime import allow_transfers, guard_level, logged_fetch, transfer_guard
+from ..utils.transfer import allow_transfers, guard_level, logged_fetch, transfer_guard
 
 __all__ = [
     "Finding",

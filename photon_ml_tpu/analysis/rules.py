@@ -6,7 +6,7 @@ R1 implicit-device-transfer: ``float()`` / ``int()`` / ``bool()`` /
    blocks the Python thread on a device->host round trip — measured at
    ~100 ms+ through a remote-accelerator link — and none of them announce
    themselves. The fix is to keep the value on device, or to fetch
-   explicitly through ``analysis.runtime.logged_fetch`` (counted by obs and
+   explicitly through ``utils.transfer.logged_fetch`` (counted by obs and
    permitted by the runtime transfer guard).
 
 R2 recompile-hazard: inside a ``@jax.jit`` function, a Python ``if`` /
@@ -427,7 +427,7 @@ def _run_r1(mod: _Module, add: AddFn) -> None:
                     node.col_offset,
                     "R1",
                     ".item() forces a device->host sync; fetch explicitly "
-                    "via analysis.runtime.logged_fetch or keep on device",
+                    "via utils.transfer.logged_fetch or keep on device",
                 )
                 continue
             if not node.args:
@@ -441,7 +441,7 @@ def _run_r1(mod: _Module, add: AddFn) -> None:
                         "R1",
                         f"{d}() on a jax value blocks on an implicit "
                         "device->host transfer; use "
-                        "analysis.runtime.logged_fetch or keep on device",
+                        "utils.transfer.logged_fetch or keep on device",
                     )
             elif d in ("numpy.asarray", "numpy.array"):
                 if _expr_is_jaxy(first, tainted, aliases):
@@ -451,7 +451,7 @@ def _run_r1(mod: _Module, add: AddFn) -> None:
                         "R1",
                         f"{d.replace('numpy', 'np')}() on a jax value is an "
                         "implicit device->host fetch; use jax.device_get via "
-                        "analysis.runtime.logged_fetch so the transfer is "
+                        "utils.transfer.logged_fetch so the transfer is "
                         "explicit and counted",
                     )
 

@@ -3,14 +3,13 @@
 Rebuilds the training report (report.json + self-contained report.html) from
 a directory of run artifacts — run_summary.json, metrics.jsonl,
 training-summary.json, saved models, feature-index metadata, boundary
-checkpoint MANIFESTs, bench --progress-out JSONL, flight-recorder
-postmortems (flight-<kind>-<seq>.json). No jax, no accelerator
+checkpoint MANIFESTs, flight-recorder postmortems
+(flight-<kind>-<seq>.json). No jax, no accelerator
 stack: the whole path is jax-free (lint rule R8), so this runs on a dev box
 against artifacts rsynced off a training host.
 
 Usage:
-  python -m photon_ml_tpu.cli.report ARTIFACTS_DIR [--out DIR]
-      [--bench-baseline OLD.json --bench-candidate NEW.json] [--top-k N]
+  python -m photon_ml_tpu.cli.report ARTIFACTS_DIR [--out DIR] [--top-k N]
 
 ``cli train --report-out`` emits the same report at end of run through the
 same discover/build code path, which is what makes the rebuild identical.
@@ -19,7 +18,6 @@ same discover/build code path, which is what makes the rebuild identical.
 from __future__ import annotations
 
 import argparse
-import json
 import logging
 import sys
 from typing import List, Optional
@@ -49,18 +47,6 @@ def build_parser() -> argparse.ArgumentParser:
         default=20,
         help="features per coordinate in the top-|weight| table",
     )
-    p.add_argument(
-        "--bench-baseline",
-        default=None,
-        help="BENCH json record to diff --bench-candidate against "
-        "(per-series deltas land in the report's bench section)",
-    )
-    p.add_argument(
-        "--bench-candidate",
-        default=None,
-        help="BENCH json record measured by this run (requires "
-        "--bench-baseline)",
-    )
     p.add_argument("--log-level", default="INFO")
     return p
 
@@ -70,10 +56,6 @@ def run(argv: Optional[List[str]] = None) -> dict:
 
     args = build_parser().parse_args(argv)
     setup_logging(args.log_level, None)
-    if bool(args.bench_baseline) != bool(args.bench_candidate):
-        raise SystemExit(
-            "--bench-baseline and --bench-candidate must be given together"
-        )
 
     inputs = report_mod.discover(args.artifacts_dir)
     if (
@@ -87,12 +69,6 @@ def run(argv: Optional[List[str]] = None) -> dict:
             "model directory)"
         )
     doc = report_mod.build_report(inputs, top_k=args.top_k)
-    if args.bench_baseline:
-        with open(args.bench_baseline, encoding="utf-8") as f:
-            old = json.load(f)
-        with open(args.bench_candidate, encoding="utf-8") as f:
-            new = json.load(f)
-        doc["bench"]["diff"] = report_mod.bench_diff(old, new)
 
     out_dir = args.out or os.path.join(args.artifacts_dir, "report")
     paths = report_mod.write_report(doc, out_dir)

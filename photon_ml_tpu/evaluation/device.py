@@ -114,7 +114,7 @@ def build_device_evaluator(evaluators, labels: np.ndarray, weights):
     )
 
     def evaluate(scores) -> Dict[str, float]:
-        from ..analysis.runtime import logged_fetch
+        from ..utils.transfer import logged_fetch
 
         vals = logged_fetch(
             "evaluation.device_metrics",

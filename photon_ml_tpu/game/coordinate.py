@@ -14,7 +14,6 @@ descent loop owns residual composition (CoordinateDataScores semantics, P7).
 from __future__ import annotations
 
 import dataclasses
-import os
 import weakref
 from functools import partial
 from typing import Optional, Tuple, Union
@@ -25,12 +24,10 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import obs
-from ..analysis.runtime import logged_fetch
+from ..utils.transfer import logged_fetch
 from ..models.coefficients import Coefficients
 from ..models.game import FixedEffectModel, RandomEffectModel
 from ..models.glm import GeneralizedLinearModel, model_for_task
-from ..ops.features import FeatureMatrix, LabeledBatch
-from ..ops.glm import GLMObjective
 from ..ops.losses import get_loss
 from ..ops.normalization import NormalizationContext
 from ..optimize import OptimizerType, SolverResult, solve_lbfgs, solve_tron
@@ -468,7 +465,6 @@ class RandomEffectCoordinate(Coordinate):
                 prior_prec = to_host(1.0 / jnp.maximum(var, 1e-12))
 
         solver_kwargs = self._solver_kwargs()
-        train_fn = self._train_fn()
         counts = self.dataset.entity_counts
         if counts is not None:
             chunk_counts = np.asarray(counts).reshape(chunks, -1)
@@ -490,12 +486,12 @@ class RandomEffectCoordinate(Coordinate):
                 padded_slots += slots - shape["real_rows"]
             with obs.span("re.bucket", **shape) as sp:
                 if segments is None:
-                    part = train_fn(
+                    part = _train_blocks_packed(
                         blocks.features, blocks.labels, offsets, blocks.weights,
                         w0, prior_mean, prior_prec, **solver_kwargs,
                     )
                 else:
-                    part = train_fn(
+                    part = _train_blocks_packed(
                         *_bucket_operands(
                             (blocks.features, blocks.labels, blocks.weights),
                             offsets, (w0, prior_mean, prior_prec),
@@ -605,10 +601,6 @@ class RandomEffectCoordinate(Coordinate):
             max_cg_iterations=solver_cfg.max_cg_iterations,
             max_improvement_failures=solver_cfg.max_improvement_failures,
         )
-
-    @staticmethod
-    def _train_fn():
-        return _train_blocks if _re_solver_mode() == "vmapped" else _train_blocks_packed
 
     def train_lanes(
         self,
@@ -770,7 +762,7 @@ class RandomEffectCoordinate(Coordinate):
             prior_mean,
             prior_prec,
             ds.hbm_budget_bytes,
-            self._train_fn(),
+            _train_blocks_packed,
             solver_kwargs,
         )
         if shard is not None:
@@ -941,19 +933,6 @@ class RandomEffectCoordinate(Coordinate):
                 model, coef_values=jnp.asarray(model.coef_values, ds_dtype)
             )
         return model.score_ell_rows(row_entity, self.dataset.ell_idx, self.dataset.ell_val)
-
-
-def _re_solver_mode() -> str:
-    """Random-effect solver selection: 'packed' (default, entity-minor
-    lane-packed lockstep solves) or 'vmapped' (the entity-leading vmapped
-    path, bit-exact across bucket shapes — the parity/debug escape hatch).
-    Unknown values raise instead of silently picking a default."""
-    mode = os.environ.get("PHOTON_RE_SOLVER", "packed").strip().lower()
-    if mode not in ("packed", "vmapped"):
-        raise ValueError(
-            f"PHOTON_RE_SOLVER={mode!r}: expected 'packed' or 'vmapped'"
-        )
-    return mode
 
 
 def _pow2_ceil(x: np.ndarray) -> np.ndarray:
@@ -1321,80 +1300,6 @@ def _initial_subspace_coefficients(
         "max_improvement_failures",
     ),
 )
-def _train_blocks(
-    features: Array,  # [E, K, S]
-    labels: Array,
-    offsets: Array,
-    weights: Array,
-    w0: Array,  # [E, S]
-    prior_mean: Array,  # [E, S]; zeros = plain L2
-    prior_prec: Array,  # [E, S]; ones = plain L2
-    *,
-    task: str,
-    l2: float,
-    l1: float,
-    optimizer_type: str,
-    tolerance: float,
-    max_iterations: int,
-    num_corrections: int,
-    max_cg_iterations: int,
-    max_improvement_failures: int,
-) -> SolverResult:
-    """One vmapped masked solve over all entity blocks."""
-    loss = get_loss(task)
-    S = features.shape[-1]
-
-    def solve_one(feat, y, off, wt, w0_e, pm_e, pp_e):
-        batch = LabeledBatch(
-            features=FeatureMatrix(dim=S, dense=feat),
-            labels=y,
-            offsets=off,
-            weights=wt,
-        )
-        obj = GLMObjective(
-            loss=loss, batch=batch, l2=l2, prior_mean=pm_e, prior_precision=pp_e
-        )
-        loss_tol, grad_tol = abs_tolerances(obj.value_and_grad, w0_e, tolerance)
-        if optimizer_type == "TRON":
-            return solve_tron(
-                obj.value_and_grad,
-                obj.hessian_vector,
-                w0_e,
-                loss_tol,
-                grad_tol,
-                max_iterations=max_iterations,
-                max_cg_iterations=max_cg_iterations,
-                max_improvement_failures=max_improvement_failures,
-            )
-        return solve_lbfgs(
-            obj.value_and_grad,
-            w0_e,
-            loss_tol,
-            grad_tol,
-            max_iterations=max_iterations,
-            num_corrections=num_corrections,
-            l1_weight=l1,
-        )
-
-    return jax.vmap(solve_one)(
-        features, labels, offsets, weights, w0, prior_mean, prior_prec
-    )
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "task",
-        "l2",
-        "l1",
-        "optimizer_type",
-        "tolerance",
-        "max_iterations",
-        "num_corrections",
-        "max_cg_iterations",
-        "max_improvement_failures",
-    ),
-)
 def _train_blocks_packed(
     features: Array,  # [E, K, S]
     labels: Array,
@@ -1416,9 +1321,10 @@ def _train_blocks_packed(
 ) -> SolverResult:
     """Entity-minor lockstep solve over all entity blocks.
 
-    Same contract as :func:`_train_blocks`, but instead of vmapping with the
-    entity axis leading ([E, K, S] puts S in the TPU's 128-wide lane dimension
-    — at S=32 that wastes 3/4 of every vector op), the data is transposed so
+    The tests' reference (``testing/reference_solver.py``) solves the same
+    contract by vmapping with the entity axis leading; [E, K, S] puts S in the
+    TPU's 128-wide lane dimension, and at S=32 that wastes 3/4 of every
+    vector op. Here the data is transposed so
     the ENTITY axis is minor: features [K, S, E], coefficients [S, E]. Every
     solver op is then elementwise over a fully packed lane dimension whatever
     S is, and the per-entity reductions are axis-0 sums. This is the

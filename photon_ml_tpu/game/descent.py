@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from ..analysis.runtime import allow_transfers, logged_fetch, transfer_guard
+from ..utils.transfer import allow_transfers, logged_fetch, transfer_guard
 from ..robust import distributed as robust_dist
 from ..robust import faults
 from ..evaluation.suite import EvaluationResults, EvaluationSuite

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from ..analysis.runtime import logged_fetch
+from ..utils.transfer import logged_fetch
 from ..utils.futures import PrefetchQueue
 from . import pipeline
 from ..ops.features import FeatureMatrix, LabeledBatch

@@ -57,7 +57,7 @@ from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple, Uni
 import numpy as np
 
 from .. import obs
-from ..analysis.runtime import logged_fetch
+from ..utils.transfer import logged_fetch
 from ..evaluation import build_suite
 from ..models.game import GameModel, RandomEffectModel
 from ..robust import faults

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from ..analysis.runtime import logged_fetch
+from ..utils.transfer import logged_fetch
 from ..models.coefficients import Coefficients
 from ..models.game import FixedEffectModel, GameModel, RandomEffectModel
 from ..models.glm import model_for_task

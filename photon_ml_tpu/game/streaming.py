@@ -10,9 +10,8 @@ HOST memory (numpy) and pipelines fixed-size entity slices through the chip:
 - the slice size is chosen from an explicit HBM budget (bytes), halved for
   double buffering;
 - slice i+1's ``jax.device_put`` is dispatched BEFORE slice i's solve is
-  awaited, so the H2D transfer overlaps compute (measured in
-  ``bench.py --config billion``: at on-host PCIe the transfer hides entirely
-  under the solve);
+  awaited, so the H2D transfer can overlap compute (whether it hides under
+  the solve on a real host link has no chip number yet: ROADMAP.md S4);
 - per-slice results are fetched to host numpy as soon as the NEXT slice's
   solve is dispatched, so device residency stays bounded by ~2 slices of
   data + solver state regardless of total model size.
@@ -44,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from ..analysis.runtime import logged_fetch
+from ..utils.transfer import logged_fetch
 from ..optimize import SolverResult
 from ..utils.futures import PrefetchQueue
 from . import pipeline
@@ -104,7 +103,7 @@ def solve_streamed(
     prior_mean_np: np.ndarray,
     prior_prec_np: np.ndarray,
     budget_bytes: int,
-    train_fn,  # _train_blocks or _train_blocks_packed
+    train_fn,  # coordinate._train_blocks_packed
     solver_kwargs: dict,
     pipeline_depth: Optional[int] = None,  # None -> pipeline.active_depth()
 ) -> SolverResult:

@@ -5,10 +5,10 @@ The reference's photon-client renders per-model HTML training reports
 (Diagnostics + model summaries) next to every fit; this module is that
 subsystem for the TPU reproduction. Inputs are EXISTING artifacts only —
 run_summary.json, metrics.jsonl, training-summary.json, saved model dirs,
-partitioned feature-index metadata, boundary-checkpoint manifests, and
-bench --progress-out JSONL — so the same report rebuilds bit-identically
-after the fact: ``cli train --report-out`` and ``cli report <artifacts-dir>``
-both run :func:`discover` + :func:`build_report` over the same files.
+partitioned feature-index metadata and boundary-checkpoint manifests — so
+the same report rebuilds bit-identically after the fact: ``cli train
+--report-out`` and ``cli report <artifacts-dir>`` both run :func:`discover`
++ :func:`build_report` over the same files.
 
 jax-free by design (lint rule R8): model avro files are read through
 ``io.avro`` directly (coefficients serialize as (name, term, value) triples,
@@ -30,12 +30,15 @@ import numpy as np
 from ..robust.atomic import atomic_write, atomic_write_json
 from . import diagnostics
 from .memory import memory_block
+from .tracing import BACKEND_COMPILE_EVENT
 
 # v2: added the top-level "plan" key (the resolved execution plan from
 # run_summary.json; None for runs that predate the planner)
 # v3: added the top-level "flight" key (flight-recorder postmortem index;
 # empty for runs with no anomaly dumps)
-REPORT_SCHEMA_VERSION = 3
+# v4: dropped the top-level "bench" key (the script that wrote its inputs is
+# gone; the benchmark's record is PERF_LEDGER.jsonl)
+REPORT_SCHEMA_VERSION = 4
 REPORT_JSON = "report.json"
 REPORT_HTML = "report.html"
 
@@ -60,7 +63,6 @@ class ReportInputs:
     # feature shard -> total feature count (from _index-<shard>-meta.json)
     feature_counts: Dict[str, int] = dataclasses.field(default_factory=dict)
     checkpoint_manifests: List[dict] = dataclasses.field(default_factory=list)
-    bench_progress: List[dict] = dataclasses.field(default_factory=list)
     # flight-recorder postmortems (flight-<kind>-<seq>.json), root-relative
     # "path" attached so the report links back to the full dump
     flight_dumps: List[dict] = dataclasses.field(default_factory=list)
@@ -80,27 +82,6 @@ def load_metric_snapshots(path: str) -> List[List[dict]]:
             return list(diagnostics.iter_metric_snapshots(f))
     except OSError:
         return []
-
-
-def _load_bench_progress(path: str) -> List[dict]:
-    """bench_diff rows of a --progress-out JSONL file (other row types in
-    the same file are the driver's own and are skipped)."""
-    rows: List[dict] = []
-    try:
-        with open(path, encoding="utf-8") as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    row = json.loads(line)
-                except ValueError:
-                    continue
-                if isinstance(row, dict) and row.get("type") == "bench_diff":
-                    rows.append(row)
-    except OSError:
-        pass
-    return rows
 
 
 def discover(root: str) -> ReportInputs:
@@ -135,10 +116,6 @@ def discover(root: str) -> ReportInputs:
                 if doc and "trigger" in doc:
                     doc["path"] = os.path.relpath(path, root)
                     inputs.flight_dumps.append(doc)
-            elif fname.endswith(".jsonl") and fname != _METRICS_JSONL:
-                rows = _load_bench_progress(path)
-                if rows:
-                    inputs.bench_progress.extend(rows)
     basenames = [os.path.basename(p.rstrip("/")) for p in model_paths]
     for path, base in zip(model_paths, basenames):
         name = base
@@ -299,15 +276,21 @@ def model_diagnostics(
 
 
 def _compile_seconds(snapshot: Sequence[dict]) -> Optional[float]:
-    """Total XLA compile seconds: sum of the photon_jax_compile_seconds
-    summary family across jax event names."""
-    total = 0.0
-    seen = False
-    for m in snapshot:
-        if m.get("name") == "photon_jax_compile_seconds" and "sum" in m:
-            total += float(m["sum"])
-            seen = True
-    return total if seen else None
+    """Total XLA compile seconds: the ``backend_compile_duration`` series of
+    the photon_jax_compile_seconds family, the one event the hook of
+    ``utils/compile_cache.py`` counts as compile time (a span's
+    ``compile_s``). The family's other series are not compilations:
+    ``jaxpr_trace_duration`` is a re-trace (a span's ``retrace_s``),
+    ``jaxpr_to_mlir_module_duration`` is lowering, and
+    ``compile_time_saved_sec`` is time the persistent cache SAVED."""
+    sums = [
+        float(m["sum"])
+        for m in snapshot
+        if m.get("name") == "photon_jax_compile_seconds"
+        and "sum" in m
+        and m.get("labels", {}).get("event") == BACKEND_COMPILE_EVENT
+    ]
+    return sum(sums) if sums else None
 
 
 def _streaming_utilization(snapshot: Sequence[dict]) -> Dict[str, dict]:
@@ -414,7 +397,6 @@ def build_report(inputs: ReportInputs, top_k: int = 20) -> dict:
             }
             for m in inputs.checkpoint_manifests
         ],
-        "bench": {"progress": inputs.bench_progress},
         "flight": [
             {
                 "trigger": (d.get("trigger") or {}).get("kind"),
@@ -429,25 +411,6 @@ def build_report(inputs: ReportInputs, top_k: int = 20) -> dict:
         ],
     }
     return report
-
-
-def bench_diff(old: dict, new: dict) -> Dict[str, dict]:
-    """Per-series deltas between two BENCH json records (the report-side
-    subset of ``bench.py --diff``: shared numeric quadrant keys only)."""
-    out: Dict[str, dict] = {}
-    oq, nq = old.get("quadrants") or {}, new.get("quadrants") or {}
-    for side in sorted(set(oq) & set(nq)):
-        os_, ns_ = oq[side] or {}, nq[side] or {}
-        for key in sorted(set(os_) & set(ns_)):
-            o_v, n_v = os_[key], ns_[key]
-            if isinstance(o_v, (int, float)) and isinstance(n_v, (int, float)):
-                delta = (float(n_v) - float(o_v)) / float(o_v) if o_v else 0.0
-                out[f"quadrants.{side}.{key}"] = {
-                    "old": float(o_v),
-                    "new": float(n_v),
-                    "delta_pct": 100.0 * delta,
-                }
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -767,54 +730,6 @@ def render_html(report: dict) -> str:
                         _fmt(s.get("budget_utilization")),
                     ]
                     for site, s in sorted(streaming.items())
-                ],
-            )
-        )
-
-    # -- bench trajectory --------------------------------------------------
-    bench = report.get("bench") or {}
-    progress = bench.get("progress") or []
-    if progress:
-        parts.append("<h2>Bench trajectory</h2>")
-        series_names: List[str] = []
-        for row in progress:
-            for name in row.get("series") or {}:
-                if name not in series_names:
-                    series_names.append(name)
-        rows = []
-        for name in series_names:
-            vals = [
-                (row.get("series") or {}).get(name, {}).get("new")
-                for row in progress
-            ]
-            deltas = [
-                (row.get("series") or {}).get(name, {}).get("delta_pct")
-                for row in progress
-            ]
-            last_delta = next((d for d in reversed(deltas) if d is not None), None)
-            rows.append(
-                [_esc(name), sparkline_svg(vals),
-                 _fmt(vals[-1] if vals else None),
-                 _fmt(last_delta) + ("%" if last_delta is not None else "")]
-            )
-        parts.append(
-            _table(["series", "trajectory", "latest", "last Δ%"], rows)
-        )
-        if any(row.get("regressed") for row in progress):
-            parts.append(
-                '<p class="aborted">at least one recorded diff regressed '
-                "beyond tolerance</p>"
-            )
-    diff = bench.get("diff") or {}
-    if diff:
-        parts.append("<h3>Baseline diff</h3>")
-        parts.append(
-            _table(
-                ["series", "old", "new", "Δ%"],
-                [
-                    [_esc(name), _fmt(d["old"]), _fmt(d["new"]),
-                     _fmt(d["delta_pct"])]
-                    for name, d in sorted(diff.items())
                 ],
             )
         )

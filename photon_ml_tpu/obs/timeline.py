@@ -70,8 +70,8 @@ def overlap_ratio(
     """Fraction of staging wall time spent concurrently with compute/collect
     work: ``overlap(stage, compute) / union(stage)``. A serial loop (stage,
     then compute, never both) scores 0; a perfectly hidden stage scores 1.
-    This is the one source of truth behind ``photon_stream_overlap_ratio``
-    and BASELINE.md's streamed-overlap claims."""
+    This is the one source of truth behind ``photon_stream_overlap_ratio``:
+    a union of host spans, a count and not a device measure."""
     stage_union = _union_seconds(stage)
     if stage_union <= 0.0:
         return 0.0

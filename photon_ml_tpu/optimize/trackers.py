@@ -22,7 +22,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from ..analysis.runtime import logged_fetch
+from ..utils.transfer import logged_fetch
 from .common import ConvergenceReason, SolverResult
 
 

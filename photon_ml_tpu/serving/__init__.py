@@ -20,8 +20,8 @@ Pieces, composable or standalone:
   behind one address, health-checked via ``/healthz``, with idempotent
   trace_id resubmit when a replica dies mid-request.
 - ``loadgen`` — open-loop Poisson load generation measuring latency from
-  intended send time (the coordinated-omission-proof harness behind
-  ``bench.py --config serving-openloop`` / ``serving-fleet``).
+  intended send time (the coordinated-omission-proof harness; the scoring
+  cells of PERF.md section 7 are to be built on it).
 """
 
 from .batcher import SERVING_LATENCY_BUCKETS, MicroBatcher, ShedError

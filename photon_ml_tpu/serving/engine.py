@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from ..analysis.runtime import logged_fetch
+from ..utils.transfer import logged_fetch
 from ..models.game import score_entity_ell
 
 # Padding ladders for the resident request path. Rows round up to the next

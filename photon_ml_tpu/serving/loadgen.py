@@ -23,9 +23,8 @@ itself is unit-testable:
   offered load the server still serves (served >= ``served_fraction`` x
   offered).
 
-``bench.py --config serving-openloop`` sweeps offered load through this
-module and reports the knee + past-knee behavior through the
-direction-aware ``--diff`` gate.
+No benchmark cell drives this module yet: the scoring cells to build on
+:func:`sweep_open_loop` and :func:`find_knee` are listed in PERF.md section 7.
 """
 
 from __future__ import annotations

@@ -32,7 +32,7 @@ from typing import Dict, List, Mapping, Optional, Sequence
 
 import numpy as np
 
-from ..analysis.runtime import logged_fetch
+from ..utils.transfer import logged_fetch
 from ..io.index_map import MmapIndexMap
 from ..robust.atomic import atomic_write, atomic_write_json
 from ..robust.retry import io_call

@@ -27,7 +27,7 @@ DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".xla_cache")
 
 def enable_persistent_compilation_cache() -> str:
     """Make sure jax persists compiled programs; returns the directory in
-    force. Called by every entry point (CLI drivers, bench, examples,
+    force. Called by every entry point (CLI drivers, the benchmark, examples,
     chip_smoke) before the first compile."""
     import jax
 

@@ -1,4 +1,8 @@
-"""Runtime companion to the static rules: hard transfer enforcement.
+"""Hard transfer enforcement: the runtime half of lint rule R1.
+
+It lives beside ``obs`` and ``utils/compile_cache.py`` (it needs ``jax`` and
+``obs`` only), so the measured path imports no development tooling;
+``photon_ml_tpu.analysis`` re-exports the four public names.
 
 The static linter (R1) catches implicit device->host syncs it can see in the
 source; :func:`transfer_guard` catches the ones it cannot — attribute-chained
@@ -7,7 +11,8 @@ on any *implicit* device->host transfer (``float(arr)``, ``np.asarray(arr)``,
 iterating an array, ...), while explicit ``jax.device_get`` stays allowed.
 The convention, enforced end to end:
 
-- hot loops (the CD sweep, the bench) run inside ``transfer_guard()``;
+- hot loops (the CD sweep, the benchmark's fits) run inside
+  ``transfer_guard()``;
 - every legitimate fetch goes through :func:`logged_fetch`, which is
   explicit (guard-proof) AND counted in the obs registry
   (``photon_device_fetch_bytes_total{site=...}``).

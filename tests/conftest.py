@@ -54,5 +54,24 @@ def _clear_jax_caches_between_modules():
     jax.clear_caches()
 
 
+@pytest.fixture
+def use_re_solver(monkeypatch):
+    """``use_re_solver("vmapped")`` puts the tests' reference solve
+    (testing/reference_solver.py) in the packed solver's place for the rest
+    of the test; ``"packed"`` leaves the solver as it is."""
+
+    def use(solver: str) -> None:
+        assert solver in ("packed", "vmapped"), solver
+        if solver == "vmapped":
+            from photon_ml_tpu.game import coordinate
+            from photon_ml_tpu.testing.reference_solver import train_blocks_vmapped
+
+            monkeypatch.setattr(
+                coordinate, "_train_blocks_packed", train_blocks_vmapped
+            )
+
+    return use
+
+
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running test (multi-process smoke, scale paths)")

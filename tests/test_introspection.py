@@ -1,7 +1,6 @@
 """Live introspection plane tests: Chrome-trace export schema, per-sweep
-phase attribution, the /metrics + /healthz + /statusz endpoints (including a
-concurrent scrape while spans are being emitted), and the bench.py --diff
-regression gate."""
+phase attribution, and the /metrics + /healthz + /statusz endpoints (including
+a concurrent scrape while spans are being emitted)."""
 
 import json
 import socket
@@ -509,152 +508,3 @@ def test_cli_train_trace_out_and_live_status(tmp_path):
         assert set(sweep["phases"]) >= {"solve", "score"}
         assert 0.0 <= sweep["overlap_factor"] < 1.0
     assert tl["total"]["wall_seconds"] > 0
-
-
-# ------------------------------------------------------------ bench --diff
-
-
-def _bench_record(value, quadrants=None, metric="glmix_examples_per_sec_per_chip"):
-    rec = {"metric": metric, "value": value, "unit": "examples/sec/chip"}
-    if quadrants is not None:
-        rec["quadrants"] = quadrants
-    return rec
-
-
-def _write(path, doc):
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    return str(path)
-
-
-def test_diff_parity_exit_zero(tmp_path, capsys):
-    import bench
-
-    old = _write(tmp_path / "old.json", _bench_record(1000.0))
-    new = _write(tmp_path / "new.json", _bench_record(1010.0))
-    rc = bench.run_diff_files(old, new)
-    assert rc == 0
-    assert "parity" in capsys.readouterr().out
-
-
-def test_diff_throughput_regression_exit_one(tmp_path, capsys):
-    import bench
-
-    old = _write(tmp_path / "old.json", _bench_record(1000.0))
-    new = _write(tmp_path / "new.json", _bench_record(850.0))  # -15%
-    rc = bench.run_diff_files(old, new)
-    assert rc == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "-15.00%" in out
-
-
-def test_diff_throughput_improvement_is_not_regression(tmp_path):
-    import bench
-
-    old = _write(tmp_path / "old.json", _bench_record(1000.0))
-    new = _write(tmp_path / "new.json", _bench_record(1500.0))
-    assert bench.run_diff_files(old, new) == 0
-
-
-def test_diff_tolerance_configurable(tmp_path):
-    import bench
-
-    old = _write(tmp_path / "old.json", _bench_record(1000.0))
-    new = _write(tmp_path / "new.json", _bench_record(850.0))
-    assert bench.run_diff_files(old, new, tolerance=0.2) == 0
-    assert bench.run_diff_files(old, new, tolerance=0.1) == 1
-
-
-def test_diff_quadrant_regression_lower_is_better(tmp_path, capsys):
-    import bench
-
-    q_old = {"tpu": {"warm_marginal_sec": 1.0, "cold_sweep_sec": 5.0}}
-    q_new = {"tpu": {"warm_marginal_sec": 1.3, "cold_sweep_sec": 5.0}}
-    old = _write(tmp_path / "old.json", _bench_record(1000.0, q_old))
-    new = _write(tmp_path / "new.json", _bench_record(1000.0, q_new))
-    rc = bench.run_diff_files(old, new)
-    assert rc == 1
-    out = capsys.readouterr().out
-    assert "quadrants.tpu.warm_marginal_sec" in out
-    assert "lower_is_better" in out
-    assert "3 series compared" in out
-
-
-def test_diff_progress_jsonl_appends(tmp_path):
-    import bench
-
-    old = _write(tmp_path / "old.json", _bench_record(1000.0))
-    new = _write(tmp_path / "new.json", _bench_record(800.0))
-    progress = tmp_path / "PROGRESS.jsonl"
-    progress.write_text('{"type": "driver_row"}\n')
-    rc = bench.run_diff_files(old, new, progress_out=str(progress))
-    assert rc == 1
-    lines = [json.loads(l) for l in progress.read_text().splitlines()]
-    assert lines[0] == {"type": "driver_row"}  # append-only: old rows survive
-    row = lines[1]
-    assert row["type"] == "bench_diff" and row["regressed"] is True
-    assert row["tolerance"] == pytest.approx(0.1)
-    series = row["series"]["glmix_examples_per_sec_per_chip"]
-    assert series["old"] == 1000.0 and series["new"] == 800.0
-    assert series["delta_pct"] == pytest.approx(-20.0)
-
-
-def test_diff_main_argv_exit_codes(tmp_path):
-    import bench
-
-    old = _write(tmp_path / "old.json", _bench_record(1000.0))
-    good = _write(tmp_path / "good.json", _bench_record(1001.0))
-    bad = _write(tmp_path / "bad.json", _bench_record(700.0))
-    with pytest.raises(SystemExit) as e:
-        bench.main(["--diff", old, good])
-    assert e.value.code == 0
-    with pytest.raises(SystemExit) as e:
-        bench.main(["--diff", old, bad])
-    assert e.value.code == 1
-
-
-def test_diff_unusable_inputs_exit_two(tmp_path, capsys):
-    import bench
-
-    garbage = tmp_path / "garbage.json"
-    garbage.write_text("not json at all")
-    ok = _write(tmp_path / "ok.json", _bench_record(1.0))
-    with pytest.raises(SystemExit) as e:
-        bench.run_diff_files(str(garbage), ok)
-    assert e.value.code == 2
-    assert "--diff" in capsys.readouterr().err
-
-    other = _write(tmp_path / "other.json", _bench_record(1.0, metric="other_metric"))
-    with pytest.raises(SystemExit) as e:
-        bench.run_diff_files(ok, other)
-    assert e.value.code == 2
-
-    not_a_record = _write(tmp_path / "x.json", {"hello": "world"})
-    with pytest.raises(SystemExit) as e:
-        bench.run_diff_files(not_a_record, ok)
-    assert e.value.code == 2
-
-
-def test_diff_reads_driver_wrapper_shape(tmp_path):
-    import bench
-
-    inner = _bench_record(
-        1000.0, {"tpu": {"warm_marginal_sec": 1.0}}
-    )
-    wrapper = {
-        "n": 4,
-        "cmd": "python bench.py",
-        "rc": 0,
-        "tail": "some log noise\n" + json.dumps(inner) + "\n",
-        "parsed": {"metric": inner["metric"], "value": inner["value"]},
-    }
-    old = _write(tmp_path / "wrap.json", wrapper)
-    rec = bench.load_bench_record(old)
-    assert rec["value"] == 1000.0
-    assert rec["quadrants"]["tpu"]["warm_marginal_sec"] == 1.0
-    # wrapper vs raw record compare cleanly
-    new = _write(
-        tmp_path / "raw.json",
-        _bench_record(1000.0, {"tpu": {"warm_marginal_sec": 1.0}}),
-    )
-    assert bench.run_diff_files(old, new) == 0

@@ -2,7 +2,7 @@
 
 This is the CI gate in test form — the same configuration, baseline, and
 rule set as ``python -m photon_ml_tpu.analysis``. A new unsuppressed finding
-anywhere in the configured paths (photon_ml_tpu/ and bench.py) fails this
+anywhere in the configured paths (photon_ml_tpu/ and chip_smoke.py) fails this
 test with the finding list in the assertion message; fix it, suppress it
 with a reasoned ``# photon: ignore[Rn]``, or (for a deliberate
 grandfathering) add it to lint_baseline.json via --write-baseline."""

@@ -587,10 +587,3 @@ def test_cli_metrics_out_integration(tmp_path):
         assert coord["convergence_reasons"]
         assert sum(coord["convergence_reasons"].values()) >= n_sweeps
     assert rs["best"]["metrics"]["AUC"] == summary["best"]["metrics"]["AUC"]
-
-    # bench.py reads the summary instead of scraping stdout
-    import bench
-
-    line = bench.summary_metric(os.path.join(mdir, "run_summary.json"))
-    assert line["metric"] == "train_run_total_wall_seconds"
-    assert line["value"] == pytest.approx(rs["total_wall_seconds"], abs=0.001)

@@ -15,7 +15,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from photon_ml_tpu.game.coordinate import _train_blocks, _train_blocks_packed
+from photon_ml_tpu.game.coordinate import _train_blocks_packed
+from photon_ml_tpu.testing.reference_solver import train_blocks_vmapped
 
 
 def _problem(seed=0, E=37, K=12, S=9, active_k=10):
@@ -52,7 +53,7 @@ def test_packed_matches_vmapped(opt, l1):
         max_cg_iterations=20,
         max_improvement_failures=5,
     )
-    rv = _train_blocks(*args, **kwargs)
+    rv = train_blocks_vmapped(*args, **kwargs)
     rp = _train_blocks_packed(*args, **kwargs)
     np.testing.assert_allclose(
         np.asarray(rp.coefficients), np.asarray(rv.coefficients), atol=5e-3
@@ -83,7 +84,7 @@ def test_packed_prior_and_warm_start():
         max_cg_iterations=20,
         max_improvement_failures=5,
     )
-    rv = _train_blocks(F, y, off, wt, w0, pm, pp, **kwargs)
+    rv = train_blocks_vmapped(F, y, off, wt, w0, pm, pp, **kwargs)
     rp = _train_blocks_packed(F, y, off, wt, w0, pm, pp, **kwargs)
     np.testing.assert_allclose(
         np.asarray(rp.coefficients), np.asarray(rv.coefficients), atol=5e-3

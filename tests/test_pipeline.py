@@ -290,20 +290,6 @@ def test_overlap_ratio():
     assert overlap_ratio([(0.0, 1.0)], [(1.0, 2.0)]) == 0.0
 
 
-# ------------------------------------------------- bench diff direction
-
-
-def test_bench_diff_overlap_is_higher_is_better():
-    import bench
-
-    assert not bench._lower_is_better("quadrants.stream.overlap_ratio")
-    row = bench._diff_one("quadrants.stream.overlap_ratio", 0.5, 0.2, 0.1)
-    assert row["direction"] == "higher_is_better"
-    assert row["regressed"]  # overlap DROPPING is the regression
-    row = bench._diff_one("quadrants.stream.overlap_ratio", 0.004, 0.5, 0.1)
-    assert not row["regressed"]
-
-
 # ----------------------------------------------- CD depth-2 bit identity
 
 

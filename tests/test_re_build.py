@@ -272,7 +272,7 @@ def test_dealt_build_is_the_sorted_build_reordered(m):
     np.testing.assert_array_equal(a.entity_ids[ra], b.entity_ids[rb])
 
 
-def _bucketed_vs_flat(monkeypatch, solver: str):
+def _bucketed_vs_flat(use_re_solver, solver: str):
     import dataclasses as dc
 
     from photon_ml_tpu.game import (
@@ -283,7 +283,7 @@ def _bucketed_vs_flat(monkeypatch, solver: str):
     from photon_ml_tpu.ops.regularization import RegularizationContext
     from photon_ml_tpu.optimize import OptimizerConfig
 
-    monkeypatch.setenv("PHOTON_RE_SOLVER", solver)
+    use_re_solver(solver)
     raw = mixed_data_to_raw_dataset(
         generate_mixed_effect_data(
             n=1500, d_fixed=4, re_specs={"userId": (60, 8)}, seed=11, entity_skew=1.6
@@ -307,11 +307,11 @@ def _bucketed_vs_flat(monkeypatch, solver: str):
     return m_bucketed, r_bucketed, m_flat, r_flat
 
 
-def test_size_bucketed_solve_equals_single_block(monkeypatch):
+def test_size_bucketed_solve_equals_single_block(use_re_solver):
     """Bucketed per-size solves must reproduce the single-block solve exactly
     on the vmapped solver (padding rows/cols are mathematically inert and
     each vmap lane's op shapes are bucket-independent)."""
-    m_bucketed, r_bucketed, m_flat, r_flat = _bucketed_vs_flat(monkeypatch, "vmapped")
+    m_bucketed, r_bucketed, m_flat, r_flat = _bucketed_vs_flat(use_re_solver, "vmapped")
     np.testing.assert_allclose(
         np.asarray(m_bucketed.coef_values), np.asarray(m_flat.coef_values), atol=1e-12
     )
@@ -320,11 +320,11 @@ def test_size_bucketed_solve_equals_single_block(monkeypatch):
     )
 
 
-def test_size_bucketed_solve_matches_single_block_packed(monkeypatch):
+def test_size_bucketed_solve_matches_single_block_packed(use_re_solver):
     """The entity-minor packed solver reduces over the K axis with
     bucket-dependent tree shapes, so bucketed vs flat agree to optimization
     tolerance (same optimum) rather than bit-exactly."""
-    m_bucketed, r_bucketed, m_flat, r_flat = _bucketed_vs_flat(monkeypatch, "packed")
+    m_bucketed, r_bucketed, m_flat, r_flat = _bucketed_vs_flat(use_re_solver, "packed")
     np.testing.assert_allclose(
         np.asarray(m_bucketed.coef_values),
         np.asarray(m_flat.coef_values),
@@ -427,7 +427,7 @@ class TestGlobalBuildParity:
         agree = (pa == pb).mean()
         assert agree > 0.9, f"kept-column agreement {agree:.3f}"
 
-    def test_training_on_global_build_matches(self, monkeypatch):
+    def test_training_on_global_build_matches(self, use_re_solver):
         """A full RE coordinate train on the device-built dataset equals the
         numpy-built one (same blocks => same solves). Pinned to the vmapped
         solver: a lane's ops depend on its own bucket shape alone, so any
@@ -436,7 +436,7 @@ class TestGlobalBuildParity:
         test_size_bucketed_solve_matches_single_block_packed)."""
         import dataclasses as dc
 
-        monkeypatch.setenv("PHOTON_RE_SOLVER", "vmapped")
+        use_re_solver("vmapped")
 
         from photon_ml_tpu.game import (
             GLMOptimizationConfig,

@@ -95,14 +95,6 @@ def test_iter_metric_snapshots_tolerates_torn_lines():
     assert snaps == [[{"name": "x"}]]
 
 
-def test_bench_diff_oracle():
-    old = {"quadrants": {"fe": {"wall_s": 2.0, "label": "x"}, "re": {"wall_s": 1.0}}}
-    new = {"quadrants": {"fe": {"wall_s": 3.0, "label": "y"}}}
-    d = report_mod.bench_diff(old, new)
-    assert set(d) == {"quadrants.fe.wall_s"}
-    assert d["quadrants.fe.wall_s"]["delta_pct"] == pytest.approx(50.0)
-
-
 def test_sparkline_svg():
     svg = report_mod.sparkline_svg([1.0, None, 3.0, 2.0])
     assert svg.startswith("<svg") and "polyline" in svg
@@ -119,16 +111,24 @@ def _gauge(name, value, **labels):
             "value": value}
 
 
+def _compile_series(event, total):
+    return {"name": "photon_jax_compile_seconds", "kind": "summary", "help": "",
+            "labels": {"event": event}, "sum": total,
+            "stat": {"count": 2, "mean": total / 2, "stdev": 0.1,
+                     "max": total, "min": 0.0}}
+
+
 def _final_snapshot():
     return [
         _gauge("photon_cd_accepted_loss", 4.0, coordinate="global"),
         _gauge("photon_cd_final_loss", 4.0, coordinate="global"),
         _gauge("photon_cd_update_iterations", 7.0, coordinate="global"),
         _gauge("photon_validation_metric", 0.71, metric="AUC", coordinate="global"),
-        {"name": "photon_jax_compile_seconds", "kind": "summary", "help": "",
-         "labels": {"event": "jit_fn"}, "sum": 1.25,
-         "stat": {"count": 2, "mean": 0.625, "stdev": 0.1, "max": 1.0,
-                  "min": 0.25}},
+        # the family as the hook of utils/compile_cache.py fills it: one series
+        # per jax event whose name contains "compile"
+        _compile_series("/jax/core/compile/backend_compile_duration", 1.25),
+        _compile_series("/jax/core/compile/jaxpr_trace_duration", 0.5),
+        _compile_series("/jax/compilation_cache/compile_time_saved_sec", 40.0),
         _gauge("photon_stream_budget_bytes", 1024.0, site="fe.train"),
         _gauge("photon_stream_actual_slice_bytes", 256.0, site="fe.train"),
         _gauge("photon_stream_budget_headroom_bytes", 512.0, site="fe.train"),
@@ -252,12 +252,6 @@ def _make_artifacts(root):
     with open(os.path.join(ck, "MANIFEST.json"), "w") as f:
         json.dump({"step": 1, "iteration": 0, "coordinate": "global",
                    "bytes": 123, "sha256": "0" * 64}, f)
-    with open(os.path.join(root, "bench-progress.jsonl"), "w") as f:
-        f.write(json.dumps({"ts": 1.0, "type": "bench_diff", "tolerance": 0.2,
-                            "regressed": [],
-                            "series": {"fe.wall_s": {"old": 2.0, "new": 1.8,
-                                                     "delta_pct": -10.0}}})
-                + "\n")
     return root
 
 
@@ -270,13 +264,14 @@ def test_report_json_golden_schema(tmp_path):
     root = _make_artifacts(str(tmp_path / "artifacts"))
     doc = report_cli.run([root, "--out", str(tmp_path / "rep")])
 
-    assert doc["schema_version"] == 3
+    assert doc["schema_version"] == 4
     assert set(doc) == {
         "schema_version", "task", "best", "models", "convergence",
-        "performance", "plan", "memory", "checkpoints", "bench", "flight",
+        "performance", "plan", "memory", "checkpoints", "flight",
     }
     assert doc["task"] == "logistic_regression"
 
+    # v4: the "bench" key went with the script that wrote its inputs
     # v3: flight-recorder postmortems ride along (none in these artifacts)
     assert doc["flight"] == []
 
@@ -333,6 +328,9 @@ def test_report_json_golden_schema(tmp_path):
     assert set(perf) == {"total_wall_seconds", "aborted", "compile_seconds",
                          "timeline", "streaming"}
     assert perf["aborted"] is False
+    # 1.25 and not the family's 41.75: a re-trace is not a compilation, and
+    # compile_time_saved_sec is what the persistent cache SAVED (before PR 30
+    # the report summed every series, so a warm run reported the most)
     assert perf["compile_seconds"] == pytest.approx(1.25)
     assert set(perf["timeline"]) == {"n_sweeps", "total",
                                      "overlap_factor_per_sweep"}
@@ -343,7 +341,6 @@ def test_report_json_golden_schema(tmp_path):
     assert doc["memory"]["host"]["rss_bytes"] == 1000
     assert doc["checkpoints"] == [{"step": 1, "iteration": 0,
                                    "coordinate": "global", "bytes": 123}]
-    assert len(doc["bench"]["progress"]) == 1
 
     # files landed and report.json round-trips to the returned doc
     out = str(tmp_path / "rep")
@@ -385,26 +382,6 @@ def test_report_discovers_flight_dumps(tmp_path):
 def test_report_cli_rejects_empty_dir(tmp_path):
     with pytest.raises(SystemExit):
         report_cli.run([str(tmp_path / "empty")])
-
-
-def test_report_cli_bench_pair_required_together(tmp_path):
-    root = _make_artifacts(str(tmp_path / "a"))
-    with pytest.raises(SystemExit):
-        report_cli.run([root, "--bench-baseline", "x.json"])
-
-
-def test_report_cli_bench_diff_section(tmp_path):
-    root = _make_artifacts(str(tmp_path / "a"))
-    old = str(tmp_path / "old.json")
-    new = str(tmp_path / "new.json")
-    with open(old, "w") as f:
-        json.dump({"quadrants": {"fe": {"wall_s": 2.0}}}, f)
-    with open(new, "w") as f:
-        json.dump({"quadrants": {"fe": {"wall_s": 1.0}}}, f)
-    doc = report_cli.run([root, "--out", str(tmp_path / "rep"),
-                          "--bench-baseline", old, "--bench-candidate", new])
-    assert doc["bench"]["diff"]["quadrants.fe.wall_s"]["delta_pct"] == \
-        pytest.approx(-50.0)
 
 
 # ---------------------------------------------------------------- jax-free

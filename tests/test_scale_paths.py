@@ -495,13 +495,13 @@ def test_bucket_operands_keep_every_row_on_its_chip():
 
 
 @pytest.mark.parametrize("solver", ["vmapped", "packed"])
-def test_chunked_bucket_solve_matches_one_chunk(monkeypatch, solver):
+def test_chunked_bucket_solve_matches_one_chunk(use_re_solver, solver):
     """The dealt layout only reorders the block rows and reshapes the buckets:
     per entity id the solve is the one-chunk solve, sharded or not."""
     from photon_ml_tpu.game import RandomEffectCoordinate
     from photon_ml_tpu.parallel import data_parallel_mesh, shard_entity_blocks
 
-    monkeypatch.setenv("PHOTON_RE_SOLVER", solver)
+    use_re_solver(solver)
     raw = mixed_data_to_raw_dataset(
         generate_mixed_effect_data(
             n=2000, d_fixed=4, re_specs={"userId": (48, 8)}, seed=9, entity_skew=1.6
@@ -540,7 +540,7 @@ def test_chunked_bucket_solve_matches_one_chunk(monkeypatch, solver):
 
 
 @pytest.mark.parametrize("solver", ["vmapped", "packed"])
-def test_mesh_fit_matches_one_device_fit_per_entity(monkeypatch, solver):
+def test_mesh_fit_matches_one_device_fit_per_entity(use_re_solver, solver):
     """The same raw data through GameEstimator.fit with and without mesh=:
     per entity id the same coefficients, and the same score on every row
     (eight virtual devices: eight dealt chunks, one a device)."""
@@ -548,7 +548,7 @@ def test_mesh_fit_matches_one_device_fit_per_entity(monkeypatch, solver):
     from photon_ml_tpu.game import RandomEffectCoordinate
     from photon_ml_tpu.parallel import make_mesh
 
-    monkeypatch.setenv("PHOTON_RE_SOLVER", solver)
+    use_re_solver(solver)
     raw = mixed_data_to_raw_dataset(
         generate_mixed_effect_data(
             n=1800, d_fixed=4, re_specs={"userId": (53, 6)}, seed=17, entity_skew=1.5

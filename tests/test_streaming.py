@@ -54,8 +54,8 @@ def _pair(raw, budget_bytes):
 
 
 @pytest.mark.parametrize("solver", ["vmapped", "packed"])
-def test_streamed_train_matches_in_memory(raw, solver, monkeypatch):
-    monkeypatch.setenv("PHOTON_RE_SOLVER", solver)
+def test_streamed_train_matches_in_memory(raw, solver, use_re_solver):
+    use_re_solver(solver)
     mem, streamed = _pair(raw, budget_bytes=64 << 10)  # tiny: many slices
     cm = RandomEffectCoordinate(dataset=mem, task="logistic_regression", config=_cfg())
     cs = RandomEffectCoordinate(
@@ -87,8 +87,7 @@ def test_streamed_train_matches_in_memory(raw, solver, monkeypatch):
     np.testing.assert_array_equal(again, s_str)
 
 
-def test_streamed_warm_start_and_prior(raw, monkeypatch):
-    monkeypatch.setenv("PHOTON_RE_SOLVER", "packed")
+def test_streamed_warm_start_and_prior(raw):
     mem, streamed = _pair(raw, budget_bytes=64 << 10)
     cm = RandomEffectCoordinate(dataset=mem, task="logistic_regression", config=_cfg())
     m0, _ = cm.train(None)
@@ -292,10 +291,10 @@ def test_block_byte_estimates_respect_scalar_itemsize():
     assert 0 < wide <= narrow  # wider scalars -> fewer entities fit
 
 
-def test_solve_streamed_uses_label_dtype_for_budget(raw, monkeypatch):
+def test_solve_streamed_uses_label_dtype_for_budget(raw, use_re_solver):
     """An f64 streamed dataset must budget with 8-byte scalars: the actual
     staged max-slice bytes may not exceed the (corrected) estimate."""
-    monkeypatch.setenv("PHOTON_RE_SOLVER", "vmapped")
+    use_re_solver("vmapped")
     from photon_ml_tpu import obs
 
     kw = dict(active_cap=64, dtype=jnp.float64)
