@@ -14,6 +14,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import logging
+import weakref
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -45,6 +46,14 @@ from ..utils.events import (
 )
 
 logger = logging.getLogger("photon_ml_tpu")
+
+# Validation contexts already built, shared by every estimator of the process:
+# (id(validation set), signature of the build) -> (weak reference to the set,
+# the arrays the build read from it, its ValidationContext). The set is held
+# WEAKLY and its entries leave with it, so a context (and its device arrays)
+# lives exactly as long as the caller's RawDataset; the arrays are held, so
+# that `is` decides a hit and a recycled `id` never can.
+_VALIDATION_CONTEXTS: Dict[tuple, tuple] = {}
 
 
 @dataclasses.dataclass
@@ -250,50 +259,98 @@ class GameEstimator(EventEmitter):
     def _validation_context(
         self, val_raw: RawDataset
     ) -> Tuple[ValidationContext, Dict[str, object]]:
+        """The validation context of ``val_raw``: built by the first call that
+        presents this very data set under this signature, re-used by every
+        later one, of this or any other estimator (``fit`` has the contract).
+        The key is what the build reads and nothing else."""
+        key = (
+            id(val_raw),
+            int(val_raw.n_rows),
+            tuple(self.evaluator_specs or ["RMSE"]),
+            jnp.dtype(self.dtype),
+            tuple(
+                (
+                    cc.name, cc.feature_shard, cc.random_effect_type,
+                    # the width of a fixed effect's dense batch
+                    None if cc.is_random_effect else int(val_raw.shard_dims[cc.feature_shard]),
+                )
+                for cc in self.coordinate_configs
+            ),
+        )
+        # the arrays the build reads: a re-assigned field is a miss (writing
+        # into one in place is not seen)
+        read = (
+            val_raw.labels, val_raw.weights, val_raw.offsets,
+            *val_raw.id_tags.values(),
+            *(x for cc in self.coordinate_configs for x in val_raw.shard_coo[cc.feature_shard]),
+        )
+        cached = _VALIDATION_CONTEXTS.get(key)
+        reused = (
+            cached is not None
+            and cached[0]() is val_raw
+            and len(cached[1]) == len(read)
+            and all(a is b for a, b in zip(cached[1], read))
+        )
+        obs.current_run().registry.counter(
+            "photon_validation_context_total",
+            "validation contexts a fit asked for: built (suite, device batches, "
+            "uploads) against reused (this very validation set, prepared before)",
+        ).labels(kind="reused" if reused else "built").inc()
+        with obs.span(
+            "fit.validation_context", rows=int(val_raw.n_rows), reused=reused
+        ):
+            if reused:
+                # the series exists in every run's registry, hit or miss
+                obs.add_device_put_bytes("fit.validation_context", 0)
+                context = cached[2]
+            else:
+                context = self._build_validation_context(val_raw)
+                # the entry leaves when the data set dies, before its id can
+                # be handed out again (the memo is bound: no global at exit)
+                gone = lambda _, memo=_VALIDATION_CONTEXTS: memo.pop(key, None)  # noqa: E731
+                _VALIDATION_CONTEXTS[key] = (weakref.ref(val_raw, gone), read, context)
+        return context, context.score_fns
+
+    def _build_validation_context(self, val_raw: RawDataset) -> ValidationContext:
         import jax
 
-        with obs.span("fit.validation_context", rows=int(val_raw.n_rows)):
-            suite = build_suite(
-                self.evaluator_specs or ["RMSE"],
-                val_raw.labels,
-                val_raw.weights,
-                id_tags=val_raw.id_tags,
-            )
-            # per-coordinate validation scoring closures
-            from ..game.data import _rows_to_ell  # host helper
-
-            score_fns = {}
-            for cc in self.coordinate_configs:
-                rows, cols, vals = val_raw.shard_coo[cc.feature_shard]
-                if cc.is_random_effect:
-                    idx, val = _rows_to_ell(rows, cols, vals, val_raw.n_rows)
-                    ids = val_raw.id_tags[cc.random_effect_type]
-                    idx_j = jnp.asarray(idx)
-                    val_j = jnp.asarray(val, self.dtype)
-                    uploaded = (idx_j, val_j)
-
-                    def fn(model, _ids=ids, _idx=idx_j, _val=val_j):
-                        erow = jnp.asarray(model.rows_for(_ids).astype(np.int32))
-                        return model.score_ell_rows(erow, _idx, _val)
-
-                else:
-                    batch = val_raw.to_batch(cc.feature_shard, dtype=self.dtype)
-                    uploaded = batch
-
-                    def fn(model, _batch=batch):
-                        return _batch.features.matvec(model.model.coefficients.means)
-
-                # host-known sizes of what this call put on the device: fit
-                # rebuilds the validation context on every call
-                obs.add_device_put_bytes(
-                    "fit.validation_context",
-                    sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(uploaded)),
-                )
-                score_fns[cc.name] = fn
-        return (
-            ValidationContext(suite=suite, score_fns=score_fns, offsets=val_raw.offsets),
-            score_fns,
+        suite = build_suite(
+            self.evaluator_specs or ["RMSE"],
+            val_raw.labels,
+            val_raw.weights,
+            id_tags=val_raw.id_tags,
         )
+        # per-coordinate validation scoring closures
+        from ..game.data import _rows_to_ell  # host helper
+
+        score_fns = {}
+        for cc in self.coordinate_configs:
+            rows, cols, vals = val_raw.shard_coo[cc.feature_shard]
+            if cc.is_random_effect:
+                idx, val = _rows_to_ell(rows, cols, vals, val_raw.n_rows)
+                ids = val_raw.id_tags[cc.random_effect_type]
+                idx_j = jnp.asarray(idx)
+                val_j = jnp.asarray(val, self.dtype)
+                uploaded = (idx_j, val_j)
+
+                def fn(model, _ids=ids, _idx=idx_j, _val=val_j):
+                    erow = jnp.asarray(model.rows_for(_ids).astype(np.int32))
+                    return model.score_ell_rows(erow, _idx, _val)
+
+            else:
+                batch = val_raw.to_batch(cc.feature_shard, dtype=self.dtype)
+                uploaded = batch
+
+                def fn(model, _batch=batch):
+                    return _batch.features.matvec(model.model.coefficients.means)
+
+            # host-known sizes of what this build put on the device
+            obs.add_device_put_bytes(
+                "fit.validation_context",
+                sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(uploaded)),
+            )
+            score_fns[cc.name] = fn
+        return ValidationContext(suite=suite, score_fns=score_fns, offsets=val_raw.offsets)
 
     def _make_coordinates(
         self,
@@ -374,7 +431,19 @@ class GameEstimator(EventEmitter):
         RawDataset. A deferred validation is resolved only AFTER the training
         datasets are built, so a background decode thread (the CLI's ingest
         overlap; the native Avro decoder releases the GIL) runs concurrently
-        with dataset preparation and device uploads."""
+        with dataset preparation and device uploads.
+
+        A validation RawDataset is READ ONCE: its context (evaluation suite,
+        per-coordinate device batches, the evaluator's compiled program) is
+        built by the first fit that presents it and re-used by every later
+        fit, of any estimator with the same evaluators, dtype and coordinate
+        shards, that presents the same object. Writing into its arrays in
+        place afterwards is not seen: re-assign the field or pass a new data
+        set. While the caller keeps the data set, its context keeps
+        N_val * (d_fixed + 2 * sum of the random effects' ELL widths) * 4
+        bytes on the device; both go when the data set goes. Fits that share
+        a validation set run one at a time (the pipelined eval lane evaluates
+        on the fit's own suite)."""
         with obs.span("fit") as fit_span:
             if datasets is None:
                 datasets = self._prepare_datasets(raw)
