@@ -659,8 +659,8 @@ class RandomEffectCoordinate(Coordinate):
         if solver_kwargs.pop("l1") > 0.0:
             raise ValueError(
                 "trial-lanes sweeps support L2 regularization only (the "
-                "OWL-QN l1 weight is compile-time static, not a per-lane "
-                "operand)"
+                "OWL-QN l1 weight is one operand of a solve, not a per-lane "
+                "vector)"
             )
         del solver_kwargs["l2"]  # replaced by the dynamic l2_lanes operand
         results = _train_blocks_packed_lanes(
@@ -1180,6 +1180,8 @@ def _concat_results(parts, S: int, chunks: int = 1, sharded=None) -> SolverResul
 def _stitch_results(S: int, chunks: int, parts) -> SolverResult:
     def field(name):
         columns = [getattr(p, name) for p in parts]
+        if columns[0] is None:  # OWL-QN's counters: the packed solve sets none
+            return None
         if name in ("coefficients", "gradient"):
             columns = [
                 a if a.shape[-1] == S else jnp.pad(a, ((0, 0), (0, S - a.shape[-1])))
@@ -1214,6 +1216,7 @@ def _concat_results_np(parts) -> SolverResult:
         **{
             f.name: np.concatenate([np.asarray(getattr(p, f.name)) for p in parts])
             for f in dataclasses.fields(SolverResult)
+            if getattr(parts[0], f.name) is not None
         }
     )
 

@@ -203,7 +203,9 @@ class GLMProblem:
         if initial_model is not None:
             w0 = jnp.asarray(initial_model.coefficients.means, dtype)
             if self.normalization is not None:
-                w0 = self.normalization.model_to_transformed_space(w0)
+                with obs.span("fe.normalization", coordinate=coordinate, direction="in") as sp:
+                    w0 = self.normalization.model_to_transformed_space(w0)
+                    sp.sync(w0)
             w0 = _pad_dim(w0, batch.dim, 0.0)
         else:
             w0 = jnp.zeros(batch.dim, dtype)
@@ -228,7 +230,11 @@ class GLMProblem:
             coordinate=coordinate,
             optimizer=solver_config.normalized_type().value,
             reg_weight=float(self.config.reg_weight),
+            l1_weight=float(solver_config.l1_weight),
+            l2_weight=float(obj.l2),
         ) as sp:
+            # with a sink, an OWL-QN solve adds ``nonzeros`` and
+            # ``line_search_evals`` here (obs.record_solver_metrics)
             result = optimize(vg_fn(obj), w0, solver_config, hvp=hvp_fn(obj))
             sp.sync(result)
 
@@ -238,7 +244,9 @@ class GLMProblem:
         if self.normalization is not None:
             # padded to batch.dim: tiled coefficients live in the mesh-padded
             # space until the coordinate trims them back to d_true
-            means = self._norm_for(batch).model_to_original_space(means)
+            with obs.span("fe.normalization", coordinate=coordinate, direction="out") as sp:
+                means = self._norm_for(batch).model_to_original_space(means)
+                sp.sync(means)
             # variances stay in transformed space in the reference as well
 
         model = model_for_task(
@@ -355,14 +363,14 @@ class GLMProblem:
 
         Composition limits (checked here because this is the deep entry
         point; game/lanes.py pins the user-facing refusals): L2-only
-        regularization (the OWL-QN l1 weight is compile-time static, not a
-        per-lane operand), variance=NONE, no normalization, no prior."""
+        regularization (the OWL-QN l1 weight is one operand of a solve, not a
+        per-lane vector), variance=NONE, no normalization, no prior."""
         solver_cfg = self.config.solver_config()
         if solver_cfg.l1_weight > 0.0:
             raise ValueError(
                 "trial-lanes sweeps support L2 regularization only (the "
-                "OWL-QN l1 weight is compile-time static, not a per-lane "
-                "operand)"
+                "OWL-QN l1 weight is one operand of a solve, not a per-lane "
+                "vector)"
             )
         if self.config.variance_type.upper() != "NONE":
             raise ValueError(

@@ -161,17 +161,23 @@ def record_solver_metrics(solver: str, result) -> None:
     from .tracing import add_device_fetch_bytes
 
     # explicit fetch: host-level solves run inside the CD sweep's transfer
-    # guard, which rejects a bare np.asarray on a device array
-    iters, reasons, grad = map(
+    # guard, which rejects a bare np.asarray on a device array. An OWL-QN
+    # solve's three counters ride the same fetch.
+    owlqn = (result.line_search_evals, result.orthant_zeroed, result.nonzeros)
+    owlqn = owlqn if all(x is not None for x in owlqn) else ()
+    iters, reasons, grad, *owlqn = map(
         np.asarray,
-        jax.device_get((result.iterations, result.reason, result.gradient)),
+        jax.device_get((result.iterations, result.reason, result.gradient, *owlqn)),
     )
     grad = grad.astype(np.float64)
     add_device_fetch_bytes(
-        f"solver.{solver}", iters.nbytes + reasons.nbytes + grad.nbytes
+        f"solver.{solver}",
+        iters.nbytes + reasons.nbytes + grad.nbytes + sum(x.nbytes for x in owlqn),
     )
 
     reg = run.registry
+    if owlqn:
+        _record_owlqn_path(reg, *(int(x.sum()) for x in owlqn))
     reg.summary(
         "photon_solver_iterations", "iterations per host-level solve"
     ).labels(solver=solver).observe_many(iters.ravel().tolist())
@@ -201,6 +207,32 @@ def record_solver_metrics(solver: str, result) -> None:
     reg.summary(
         "photon_solver_final_grad_norm", "final gradient norm per host-level solve"
     ).labels(solver=solver).observe_many(gn.tolist())
+
+
+def _record_owlqn_path(reg, evals: int, zeroed: int, nonzeros: int) -> None:
+    """What OWL-QN adds to a fixed-effect solve, on the enclosing ``fe.solve``
+    span (``game/problem.py``) and as counters by its coordinate. A solve with
+    no such span around it (a bare ``solve_lbfgs`` call) records nothing."""
+    from .tracing import current_span
+
+    solve_span = current_span()
+    if solve_span is None or solve_span.name != "fe.solve":
+        return
+    solve_span.attrs["line_search_evals"] = evals
+    solve_span.attrs["nonzeros"] = nonzeros
+    coordinate = str(solve_span.attrs.get("coordinate"))
+    reg.counter(
+        "photon_fe_line_search_evals_total",
+        "value-and-gradient evaluations issued by fixed-effect OWL-QN solves",
+    ).labels(coordinate=coordinate).inc(evals)
+    reg.counter(
+        "photon_fe_orthant_zeroed_total",
+        "coefficients set to zero by OWL-QN's orthant projection, over accepted steps",
+    ).labels(coordinate=coordinate).inc(zeroed)
+    reg.gauge(
+        "photon_fe_nonzero_coefficients",
+        "non-zero coefficients of the last fixed-effect OWL-QN solve, in the solver's space",
+    ).labels(coordinate=coordinate).set(nonzeros)
 
 
 def collect_build_info() -> Dict[str, str]:

@@ -103,6 +103,15 @@ class SolverResult:
     # i32, shaped like ``iterations``: TRON's inner CG iterations summed
     # over the solve (one Hessian-vector product each); 0 for L-BFGS/OWL-QN
     cg_iterations: Array
+    # OWL-QN solves alone set these (i32, shaped like ``iterations``); None
+    # elsewhere, so the other solvers' programs have no such output:
+    # objective (value-and-gradient) evaluations the solve issued, the first
+    # one and every line-search trial; coefficients the orthant projection
+    # set to zero, summed over the accepted steps; non-zero coefficients of
+    # the final iterate (in the space the solver ran in)
+    line_search_evals: Optional[Array] = None
+    orthant_zeroed: Optional[Array] = None
+    nonzeros: Optional[Array] = None
 
     @property
     def converged(self) -> Array:

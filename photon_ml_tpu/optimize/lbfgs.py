@@ -12,7 +12,11 @@ single jit/vmap-safe implementation:
 - OWL-QN (l1_weight > 0): pseudo-gradient, direction orthant projection, and
   orthant-constrained line-search steps; the correction pairs use the plain
   gradient, convergence uses the pseudo-gradient — matching the OWL-QN
-  algorithm the reference delegates to Breeze for;
+  algorithm the reference delegates to Breeze for. WHETHER a solve is OWL-QN
+  is decided on the host (a positive weight); the weight itself is an operand
+  of the program, so a lambda path or a tuner's trials share ONE compiled
+  solver, and a solve without it lowers to plain L-BFGS with no pseudo-gradient
+  or orthant op in it;
 - box constraints (L-BFGS-B, reference LBFGSB.scala:39-92): gradient
   projection — the "gradient" driving the two-loop direction and the
   convergence test is the projected gradient w - P(w - g), which vanishes
@@ -75,7 +79,7 @@ _C2 = 0.9  # curvature
 
 
 
-def _pseudo_gradient(w: Array, g: Array, l1: float) -> Array:
+def _pseudo_gradient(w: Array, g: Array, l1: Array) -> Array:
     """OWL-QN pseudo-gradient of f(w) + l1*||w||_1."""
     gp = g + l1
     gm = g - l1
@@ -192,13 +196,19 @@ def _line_search(
     f: Array,
     direction: Array,
     dg: Array,  # directional derivative of the (possibly l1-augmented) objective
-    l1: float,
+    l1: Optional[Array],  # None = no l1 term (plain L-BFGS / L-BFGS-B)
     orthant: Optional[Array],
     max_iters: int,
     box: Optional[Tuple[Array, Array]] = None,
     g_plain: Optional[Array] = None,
-) -> Tuple[Array, Array, Array, Array]:
-    """Strong-Wolfe bisection line search; returns (w_new, f_new, g_new, success).
+) -> Tuple[Array, Array, Array, Array, Array, Array]:
+    """Strong-Wolfe bisection line search; returns (w_new, f_new, g_new,
+    success, the step length kept, the loop's trip count: one objective
+    evaluation each, after the first trial's).
+
+    A trial whose value is not finite (Poisson's ``exp`` past z = 88 in f32
+    gives ``inf``, and ``inf - inf`` downstream ``NaN``) is a FAILED step on
+    every branch: it is never accepted and the step is halved.
 
     For OWL-QN (orthant is not None) each trial point is projected onto the
     orthant and only the Armijo condition is enforced (standard OWL-QN
@@ -220,7 +230,7 @@ def _line_search(
         if box is not None:
             w_t = jnp.clip(w_t, box[0], box[1])
         f_t, g_t = value_and_grad(w_t)
-        if l1 > 0.0:
+        if l1 is not None:
             f_t = f_t + l1 * jnp.sum(jnp.abs(w_t), axis=0)
         return w_t, f_t, g_t
 
@@ -281,7 +291,7 @@ def _line_search(
         )
 
     final = jax.lax.while_loop(cond, body, init)
-    return final.w_t, final.f_t, final.g_t, final.success
+    return final.w_t, final.f_t, final.g_t, final.success, final.t, final.it
 
 
 class _LBFGSState(NamedTuple):
@@ -299,6 +309,9 @@ class _LBFGSState(NamedTuple):
     head: Array
     loss_history: Array
     grad_norm_history: Array
+    # OWL-QN alone (None otherwise: a leafless entry of the loop's carry)
+    evals: Optional[Array] = None  # objective evaluations so far
+    zeroed: Optional[Array] = None  # coefficients the orthant projection zeroed
 
 
 @partial(
@@ -306,7 +319,6 @@ class _LBFGSState(NamedTuple):
     static_argnames=(
         "max_iterations",
         "num_corrections",
-        "l1_weight",
         "max_line_search_iterations",
         "has_box",
         "batched",
@@ -319,7 +331,7 @@ def _solve(
     grad_abs_tol: Array,
     max_iterations: int,
     num_corrections: int,
-    l1_weight: float,
+    l1_weight: Optional[Array],  # an OPERAND; None (no leaf) = plain L-BFGS
     max_line_search_iterations: int,
     has_box: bool,
     box_lower: Array,
@@ -330,10 +342,11 @@ def _solve(
     dtype = w0.dtype
     box = (box_lower, box_upper) if has_box else None
     l1 = l1_weight
+    owlqn = l1 is not None
 
     def full_objective(w):
         f, g = value_and_grad(w)
-        if l1 > 0.0:
+        if owlqn:
             f = f + l1 * jnp.sum(jnp.abs(w), axis=0)
         return f, g
 
@@ -345,7 +358,7 @@ def _solve(
     hist = jnp.full((max_iterations + 1,) + lanes, jnp.nan, dtype)
 
     def effective_grad(w, g):
-        if l1 > 0.0:
+        if owlqn:
             return _pseudo_gradient(w, g, l1)
         if box is not None:
             # projected gradient: zero at bound-held coordinates, so both the
@@ -379,6 +392,8 @@ def _solve(
         head=jnp.asarray(0, jnp.int32) if batched else jnp.zeros(lanes, jnp.int32),
         loss_history=hist.at[0].set(f0),
         grad_norm_history=hist.at[0].set(_norm(pg0)),
+        evals=jnp.ones(lanes, jnp.int32) if owlqn else None,
+        zeroed=jnp.zeros(lanes, jnp.int32) if owlqn else None,
     )
 
     def cond(s: _LBFGSState):
@@ -387,7 +402,7 @@ def _solve(
     def body(s: _LBFGSState):
         pg = effective_grad(s.w, s.g)
         direction = -_two_loop(s.S, s.Y, s.rho, s.count, s.head, pg, unroll=batched)
-        if l1 > 0.0:
+        if owlqn:
             # project direction into the descent orthant of -pg
             direction = jnp.where(direction * pg >= 0, 0.0, direction)
         dg = _vdot(direction, pg)
@@ -397,10 +412,10 @@ def _solve(
         dg = jnp.where(bad, -_vdot(pg, pg), dg)
 
         orthant = None
-        if l1 > 0.0:
+        if owlqn:
             orthant = jnp.where(s.w != 0, jnp.sign(s.w), -jnp.sign(pg))
 
-        w_new, f_new, g_new, ls_ok = _line_search(
+        w_new, f_new, g_new, ls_ok, t_new, ls_trips = _line_search(
             value_and_grad, s.w, s.f, direction, dg, l1, orthant,
             max_line_search_iterations, box=box, g_plain=s.g,
         )
@@ -442,6 +457,13 @@ def _solve(
             S = jnp.where(keep, s.S, S)
             Y = jnp.where(keep, s.Y, Y)
             rho = jnp.where(keep, s.rho, rho)
+
+        evals = zeroed = None
+        if owlqn:
+            # the first trial plus one more every trip of the search's loop
+            evals = jnp.where(keep, s.evals, s.evals + 1 + ls_trips)
+            crossed = ((s.w + t_new * direction) * orthant < 0) & improved & ~keep
+            zeroed = s.zeroed + jnp.sum(crossed, axis=0, dtype=jnp.int32)
 
         it_new = s.it + 1
         pg_new = effective_grad(w_new, g_new)
@@ -490,6 +512,8 @@ def _solve(
             head=head,
             loss_history=lh,
             grad_norm_history=gh,
+            evals=evals,
+            zeroed=zeroed,
         )
 
     final = jax.lax.while_loop(cond, body, init)
@@ -503,6 +527,9 @@ def _solve(
         loss_history=final.loss_history,
         grad_norm_history=final.grad_norm_history,
         cg_iterations=jnp.zeros_like(final.it),
+        line_search_evals=final.evals,
+        orthant_zeroed=final.zeroed,
+        nonzeros=jnp.sum(final.w != 0, axis=0, dtype=jnp.int32) if owlqn else None,
     )
 
 
@@ -520,6 +547,10 @@ def solve_lbfgs(
 ) -> SolverResult:
     """Minimize f(w) (+ l1*||w||_1 when ``l1_weight`` > 0) starting at w0.
 
+    ``l1_weight`` is a host number: positive selects the OWL-QN program and
+    enters it as an operand (every positive weight runs the same compiled
+    solver), zero selects plain L-BFGS, whose program has no l1 operand.
+
     ``value_and_grad`` must be a pure fn of w (closing over its batch); the
     absolute tolerances come from :func:`photon_ml_tpu.optimize.common.abs_tolerances`.
 
@@ -530,6 +561,8 @@ def solve_lbfgs(
     has_box = box_constraints is not None
     zero = jnp.zeros_like(w0)
     lower, upper = box_constraints if has_box else (zero, zero)
+    # the solver KIND is chosen here, from a host value; the weight is data
+    l1 = jnp.asarray(l1_weight, w0.dtype) if float(l1_weight) > 0.0 else None
     result = _solve(
         as_partial(value_and_grad),
         w0,
@@ -537,7 +570,7 @@ def solve_lbfgs(
         jnp.asarray(grad_abs_tol, w0.dtype),
         max_iterations,
         num_corrections,
-        float(l1_weight),
+        l1,
         max_line_search_iterations,
         has_box,
         lower,

@@ -293,8 +293,8 @@ def check_lane_composition(
         if cc.config.regularization.reg_type in ("L1", "ELASTIC_NET"):
             raise PlanError(
                 f"{where}: trial-lanes sweeps support L2 regularization "
-                "only (the OWL-QN l1 weight is compile-time static, not a "
-                "per-lane operand)"
+                "only (the OWL-QN l1 weight is one operand of a solve, not "
+                "a per-lane vector)"
             )
         if cc.config.variance_type.upper() != "NONE":
             raise PlanError(

@@ -70,8 +70,9 @@ def _benchmark_configs():
 @pytest.mark.parametrize("config", [c["name"] for c in _benchmark_configs()])
 def test_benchmark_source_cites_a_baseline_config_that_exists(config):
     """Each configuration's file names "BASELINE.json config N" as the origin
-    of its widths: N is an entry ``BASELINE.json`` ``configs`` still has, and a
-    GLMix one."""
+    of its widths: N is an entry ``BASELINE.json`` ``configs`` still has, and
+    one of the configuration's own kind (a GLMix entry for a ``glmix-*``
+    configuration, the Poisson one for ``poisson-*``)."""
     (entry,) = [c for c in _benchmark_configs() if c["name"] == config]
     source = json.loads((REPO / entry["file"]).read_text(encoding="utf-8"))["source"]
     cited = [int(n) for n in re.findall(r"BASELINE\.json config (\d+)", source)]
@@ -80,4 +81,5 @@ def test_benchmark_source_cites_a_baseline_config_that_exists(config):
     assert "measured_baselines" not in baseline
     for n in cited:
         assert 1 <= n <= len(baseline["configs"]), (n, len(baseline["configs"]))
-        assert "GLMix" in baseline["configs"][n - 1]
+        kind = {"glmix": "GLMix", "poisson": "Poisson"}[config.split("-")[0]]
+        assert kind in baseline["configs"][n - 1]

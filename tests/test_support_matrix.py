@@ -442,7 +442,7 @@ CASES = [
     (
         "lanes-l1",
         "trial-lanes sweeps support L2 regularization only (the OWL-QN l1 "
-        "weight is compile-time static, not a per-lane operand)",
+        "weight is one operand of a solve, not a per-lane vector)",
         ValueError,
         _trigger_lanes_l1,
     ),
