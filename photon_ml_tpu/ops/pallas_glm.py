@@ -11,7 +11,7 @@ the two-pass structure of the dense objective cannot be fused by XLA at all.
 
 — two GEMVs over the same X with a data dependency between them, so XLA
 schedules two full HBM sweeps of X. At GLM shapes (n >> d, X is hundreds of
-times larger than every other operand combined) the op is purely
+times larger than every other operand combined) that path is purely
 HBM-bandwidth-bound, so those two sweeps ARE the cost. The kernels here tile
 X over rows once and compute the margin dot, the pointwise loss, and the
 gradient accumulation per tile while it sits in VMEM — one HBM sweep, i.e. an
@@ -24,10 +24,30 @@ The Hessian-vector product wins more: the objective-level composition
 costs THREE X sweeps per call (z for the curvature weights, u = X v, and the
 transpose accumulation), and it is the inner-loop op of TRON's conjugate
 gradient (optimize/tron.py:85). Every per-row quantity (z_i, u_i, c_i) depends
-only on row i, so the fused kernel computes all three in one sweep — 3x per
-CG iteration, no caching or solver changes needed. The Hessian-diagonal
-aggregates for SIMPLE variances (s2 = (x*x)^T c, plus s1/s0 under
-normalization shifts) get the same one-sweep treatment (_hd_kernel).
+only on row i, so the fused kernel computes all three in one sweep, no
+caching or solver changes needed. The Hessian-diagonal aggregates for SIMPLE
+variances (s2 = (x*x)^T c, plus s1/s0 under normalization shifts) get the
+same one-sweep treatment (_hd_kernel).
+
+What the sweep costs on the chip (PR 33, a stand-alone timing on a TPU v5
+lite at [1,572,864 x 1024] f32, 6.44 GB, 512-row tiles, logistic): an f32 tile
+at Precision.HIGHEST is not bound by its bytes but by the number of
+dot_generals it goes through, each one a split of the whole tile into bf16
+parts and six MXU passes, whichever side of the dot X sits on. The read alone
+takes 8.6 ms (any number of dots at DEFAULT, or one at HIGHEST; 819 GB/s
+allow 7.9 ms); two dots take 10.4 ms, three took 16.6 ms (5.2-5.5 ms a dot
+over the whole X, hidden under the read only while there is one). So both
+kernels pass each tile through exactly TWO dots: _vg_kernel one
+for the margins and one for the gradient, _hv_kernel one STACKED dot
+[coef; v][2, d] . x^T -> [2, TN] for both margins and one for the
+accumulation. With a dot for each margin the Hv kernel took 16.6 ms a call
+(47.6% of the roofline in every cell of the benchmark); stacked it takes
+10.4 ms, the time of fused_value_grad (75.8% against 76.2%;
+tests/test_pallas_glm.py pins the count of dots in both bodies). A [2, d]
+block needs no padding to the sublane tile: Mosaic takes it for f32 and bf16
+at every width the gate admits. 512 rows is the best tile of those that fit
+(256: 11.4 ms, 384: 10.7 ms, 768 and up: out of scoped VMEM). The rows of the
+stacked product are not bit-equal to two M = 1 products (1.3e-7 of max|hv|).
 
 Reference parity: these kernels compute exactly the RAW aggregates of the
 reference's ValueAndGradientAggregator / HessianVectorAggregator
@@ -134,10 +154,13 @@ def _dot_precision(x_dtype):
     results under the default — a silent drop to bf16 input precision,
     ~2.6e-3 relative gradient error), while XLA's jnp GEMV path keeps full
     f32. HIGHEST restores exact-f32 passes (measured 1.1e-6 gradient
-    agreement with the jnp path, ~1.45x the DEFAULT kernel time — still
-    faster than the two-sweep jnp path). A bf16 X keeps DEFAULT: bf16 is the
-    MXU's native single-pass input type, and bf16 storage is the explicit
-    opt-in fast path."""
+    agreement with the jnp path). Its price goes with the dots a tile passes
+    through (PR 33, the module header's timing): at DEFAULT the kernels read
+    6.44 GB in 8.6 ms whatever they compute, at HIGHEST a two-dot kernel
+    takes 10.4 ms (1.21x) and a three-dot one took 16.6 ms (1.93x), still
+    under the two and three sweeps of the jnp path. A bf16 X keeps DEFAULT:
+    bf16 is the MXU's native single-pass input type, and bf16 storage is
+    the explicit opt-in fast path."""
     if x_dtype == jnp.bfloat16:
         return jax.lax.Precision.DEFAULT
     return jax.lax.Precision.HIGHEST
@@ -202,8 +225,8 @@ def _vg_kernel(loss: PointwiseLoss, n: int, tn: int, x_ref, coef_ref, y_ref,
         pl.when(i == last)(lambda: accumulate(True))
 
 
-def _hv_kernel(loss: PointwiseLoss, n: int, tn: int, x_ref, coef_ref, v_ref,
-               y_ref, off_ref, wt_ref, vshift_ref, hv_ref, csum_ref):
+def _hv_kernel(loss: PointwiseLoss, n: int, tn: int, x_ref, cv_ref, y_ref,
+               off_ref, wt_ref, vshift_ref, hv_ref, csum_ref):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -214,14 +237,14 @@ def _hv_kernel(loss: PointwiseLoss, n: int, tn: int, x_ref, coef_ref, v_ref,
     def accumulate(masked):
         x, y, off, wt = _load_tile(n % tn, tn, masked, x_ref, y_ref, off_ref, wt_ref)
         prec = _dot_precision(x.dtype)
-        z = jax.lax.dot_general(
-            coef_ref[...], x, (((1,), (1,)), ((), ())),
+        # [coef; v][2,d] . x^T -> [2, TN]: both margins of this row tile from
+        # ONE pass of the tile through the MXU (see the module header)
+        zu = jax.lax.dot_general(
+            cv_ref[...], x, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
-        ) + off
-        u = jax.lax.dot_general(
-            v_ref[...], x, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec,
-        ) + vshift_ref[...]
+        )
+        z = zu[0:1] + off
+        u = zu[1:2] + vshift_ref[...]
         cu = wt * loss.d2z(z, y) * u  # [1, TN] f32
         csum_ref[...] += jnp.sum(cu).reshape(1, 1)
         hv_ref[...] += jax.lax.dot_general(
@@ -438,11 +461,14 @@ def fused_hessian_vector(
     n, d = x.shape
     tn = tile_rows(d, jnp.dtype(x.dtype).itemsize)
     out_dt = jnp.float32 if x.dtype == jnp.bfloat16 else x.dtype
-    x_spec, d_spec, n_spec, out_d, out_s = _row_specs(tn, d)
+    x_spec, _, n_spec, out_d, out_s = _row_specs(tn, d)
     hv, csum = pl.pallas_call(
         functools.partial(_hv_kernel, loss, n, tn),
         grid=(pl.cdiv(n, tn),),
-        in_specs=[x_spec, d_spec, d_spec, n_spec, n_spec, n_spec, out_s],
+        in_specs=[
+            x_spec, pl.BlockSpec((2, d), lambda i: (0, 0)),
+            n_spec, n_spec, n_spec, out_s,
+        ],
         out_specs=[out_d, out_s],
         out_shape=[
             jax.ShapeDtypeStruct((1, d), out_dt),
@@ -451,8 +477,7 @@ def fused_hessian_vector(
         interpret=interpret,
     )(
         x,
-        eff_coef.astype(x.dtype).reshape(1, d),
-        eff_v.astype(x.dtype).reshape(1, d),
+        jnp.stack([eff_coef, eff_v]).astype(x.dtype),
         labels.astype(out_dt).reshape(1, n),
         offsets.astype(out_dt).reshape(1, n),
         weights.astype(out_dt).reshape(1, n),

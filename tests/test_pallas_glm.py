@@ -8,6 +8,7 @@ except the Mosaic lowering.
 """
 
 import dataclasses
+import functools
 import os
 
 import jax
@@ -212,6 +213,136 @@ def test_kernel_shape_sweep(rng, d, n_off):
     assert np.max(np.abs(g1 - g0)) <= 3e-5 * max(np.max(np.abs(g0)), 1.0)
 
 
+# relative to the result's own magnitude: f32 as everywhere in this file,
+# bf16 as tests/test_bf16_features.py compares the kernel with the jnp path
+# on the same bf16 X (the kernel rounds coef, v and c*u to bf16 at the dots)
+HV_TOL = {"float32": 3e-5, "bfloat16": 1e-2}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("d", [128, 384, 1024])
+@pytest.mark.parametrize("n_off", [0, 1, 127])
+@pytest.mark.parametrize("with_norm", [False, True])
+@pytest.mark.parametrize("loss_name", sorted(LOSSES))
+def test_hv_kernel_shape_sweep(rng, loss_name, with_norm, n_off, d, dtype):
+    """The stacked Hv kernel (ONE dot for the margins of coef and of v)
+    against the three-sweep jnp composition of GLMObjective.hessian_vector:
+    every loss, with and without normalization shifts (a non-zero vshift),
+    full / off-by-one / near-full last tile, both X dtypes."""
+    itemsize = jnp.dtype(dtype).itemsize
+    n = pallas_glm.tile_rows(d, itemsize) * 2 + n_off
+    x = (rng.standard_normal((n, d)) * 0.4).astype(np.float32)
+    y = (rng.random(n) > 0.5).astype(np.float32)
+    if loss_name == "poisson":
+        y = y * 2
+    batch = batch_from_dense(
+        x, y, offsets=(rng.standard_normal(n) * 0.1).astype(np.float32),
+        weights=(rng.random(n) + 0.5).astype(np.float32),
+        feature_dtype=jnp.dtype(dtype),
+    )
+    base = GLMObjective(
+        loss=LOSSES[loss_name], batch=batch, l2=0.1,
+        norm=_norm_ctx(rng, d) if with_norm else None,
+    )
+    fused = dataclasses.replace(base, fused="interpret")
+    w = jnp.asarray((rng.standard_normal(d) * 0.1).astype(np.float32))
+    v = jnp.asarray(rng.standard_normal(d).astype(np.float32))
+    if dtype == "bfloat16":
+        # the kernel rounds the effective coefficients to X's dtype at the
+        # dot: give it ones that are bf16 already, so that both paths see the
+        # same margins and the band where smoothed hinge's l'' is 1 holds the
+        # same rows (v's rounding stays: u is linear in it)
+        eff = w if base.norm is None else w * base.norm.factors
+        eff = eff.astype(jnp.bfloat16).astype(jnp.float32)
+        w = eff if base.norm is None else eff / base.norm.factors
+    h0 = np.asarray(base.hessian_vector(w, v))
+    h1 = np.asarray(fused.hessian_vector(w, v))
+    assert h1.dtype == np.float32 and np.all(np.isfinite(h1))
+    assert np.max(np.abs(h1 - h0)) <= HV_TOL[dtype] * max(np.max(np.abs(h0)), 1.0)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, those of nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _dots_in_kernel_body(fn, *args, **kwargs):
+    """Where the dot_generals of ``fn``'s ONE pallas_call body sit: (the count
+    at the body's top level, [the count inside each top-level equation that
+    holds any, i.e. each ``pl.when`` branch with work in it])."""
+    jaxpr = jax.make_jaxpr(functools.partial(fn, **kwargs))(*args).jaxpr
+    (call,) = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
+    (body,) = jax.core.jaxprs_in_params(call.params)
+    nested = [
+        sum(
+            e.primitive.name == "dot_general"
+            for sub in jax.core.jaxprs_in_params(eqn.params)
+            for e in _eqns(sub)
+        )
+        for eqn in body.eqns
+    ]
+    top = sum(eqn.primitive.name == "dot_general" for eqn in body.eqns)
+    return top, [n for n in nested if n]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n_off", [0, 7])
+def test_kernel_bodies_pass_the_tile_through_two_dots(n_off, dtype):
+    """Structural pin of what the chip's time goes with (ops/pallas_glm.py's
+    header): each X tile enters the MXU twice a call in the Hv kernel, as in
+    the value-and-gradient kernel, in the full-tile branch and in the masked
+    one. A third dot_general over the tile costs 6.2 ms a call at the cells'
+    shape whatever else the kernel does. The expected structure is spelled
+    out (two dots at the top level when n is a multiple of the tile, else none
+    there and two in each of the two branches), so a change in how the body
+    is traced fails here and is not counted as something else."""
+    d = 256
+    n = 2 * pallas_glm.tile_rows(d, jnp.dtype(dtype).itemsize) + n_off
+    x = jax.ShapeDtypeStruct((n, d), jnp.dtype(dtype))
+    vec_d = jax.ShapeDtypeStruct((d,), jnp.float32)
+    vec_n = jax.ShapeDtypeStruct((n,), jnp.float32)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+    loss = LOSSES["logistic"]
+    expected = (0, [2, 2]) if n_off else (2, [])
+    assert _dots_in_kernel_body(
+        pallas_glm.fused_hessian_vector, x, vec_d, vec_d, vec_n, vec_n, vec_n, scalar,
+        loss=loss, interpret=True,
+    ) == expected
+    assert _dots_in_kernel_body(
+        pallas_glm.fused_value_grad, x, vec_d, vec_n, vec_n, vec_n, loss=loss, interpret=True
+    ) == expected
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_sharded_hessian_vector_equals_unsharded(rng, dtype):
+    """sharded_hessian_vector on an 8-device CPU mesh (the kernel per row
+    shard with a masked last tile each, then the psum) against the one-device
+    call on the same arrays, the replicated [2, d] operand and a non-zero
+    vshift included."""
+    from photon_ml_tpu.parallel import make_mesh
+
+    d = D
+    n = 8 * (pallas_glm.tile_rows(d, jnp.dtype(dtype).itemsize) + 13)
+    x = jnp.asarray((rng.standard_normal((n, d)) * 0.4).astype(np.float32), jnp.dtype(dtype))
+    y = jnp.asarray((rng.random(n) > 0.5).astype(np.float32))
+    off = jnp.asarray((rng.standard_normal(n) * 0.1).astype(np.float32))
+    wt = jnp.asarray((rng.random(n) + 0.5).astype(np.float32))
+    w = jnp.asarray((rng.standard_normal(d) * 0.1).astype(np.float32))
+    v = jnp.asarray(rng.standard_normal(d).astype(np.float32))
+    args = (x, w, v, y, off, wt, jnp.float32(0.37), LOSSES["logistic"])
+    hv0, c0 = pallas_glm.sharded_hessian_vector(None, *args, interpret=True)
+    hv1, c1 = pallas_glm.sharded_hessian_vector(
+        make_mesh(n_data=8, n_model=1), *args, interpret=True
+    )
+    hv0, hv1 = np.asarray(hv0), np.asarray(hv1)
+    # only the order of the sums over tiles differs (eight partial sums, psum)
+    assert np.max(np.abs(hv1 - hv0)) <= 3e-5 * max(np.max(np.abs(hv0)), 1.0)
+    np.testing.assert_allclose(float(c1), float(c0), rtol=3e-5, atol=3e-5)
+
+
 def test_end_to_end_sharded_solve(rng, monkeypatch):
     """GLMProblem.run on a mesh-sharded batch picks the shard_map fused path
     and converges to the same model as the unsharded unfused solve."""
@@ -241,24 +372,38 @@ def test_end_to_end_sharded_solve(rng, monkeypatch):
     assert np.max(np.abs(w1_ - w0_)) <= 5e-3 * max(np.max(np.abs(w0_)), 1.0)
 
 
-@pytest.mark.parametrize("optimizer", ["LBFGS", "TRON"])
-def test_end_to_end_solve_matches_unfused(rng, monkeypatch, optimizer):
-    """GLMProblem.run with PHOTON_PALLAS=interpret converges to the same model
-    as the jnp path — the full solver loop (L-BFGS line search / TRON CG)
-    driving the fused kernels."""
-    n = pallas_glm.MIN_FUSED_ROWS
-    batch = _make_batch(rng, n)
-    problem = GLMProblem(
+def _l2_logistic_problem(optimizer, max_iterations, tolerance=1e-9):
+    return GLMProblem(
         task="logistic_regression",
         config=GLMOptimizationConfig(
             optimizer=OptimizerConfig(
-                optimizer_type=optimizer, tolerance=1e-9, max_iterations=60
+                optimizer_type=optimizer, tolerance=tolerance, max_iterations=max_iterations
             ),
             regularization=RegularizationContext("L2"),
             reg_weight=1.0,
             variance_type="SIMPLE",
         ),
     )
+
+
+# TRON's 4th iteration on this problem is at f32's noise floor: whether it is
+# taken at all, and where it lands, is decided by rounding, on the jnp path as
+# on the fused one (data seeds 0-2: the two stop after 5/4, 3/5 and 4/4
+# iterations, 3.6e-5 to 5.2e-5 apart, each up to 5.2e-5 from the float64
+# optimum). Over the three iterations both always take they agree to 7e-8,
+# so the two paths are compared there; that each also converges is
+# test_tron_converges_to_the_float64_optimum's to say.
+E2E_ITERATIONS = {"LBFGS": 60, "TRON": 3}
+
+
+@pytest.mark.parametrize("optimizer", ["LBFGS", "TRON"])
+def test_end_to_end_solve_matches_unfused(rng, monkeypatch, optimizer):
+    """GLMProblem.run with PHOTON_PALLAS=interpret walks to the same model as
+    the jnp path, iteration for iteration — the full solver loop (L-BFGS line
+    search / TRON CG) driving the fused kernels."""
+    n = pallas_glm.MIN_FUSED_ROWS
+    batch = _make_batch(rng, n)
+    problem = _l2_logistic_problem(optimizer, E2E_ITERATIONS[optimizer])
     monkeypatch.setenv("PHOTON_PALLAS", "off")
     m0, r0 = problem.run(batch)
     monkeypatch.setenv("PHOTON_PALLAS", "interpret")
@@ -277,6 +422,29 @@ def test_end_to_end_solve_matches_unfused(rng, monkeypatch, optimizer):
         rtol=1e-3,
         atol=1e-6,
     )
+
+
+@pytest.mark.parametrize("pallas", ["off", "interpret"])
+def test_tron_converges_to_the_float64_optimum(rng, monkeypatch, pallas):
+    """TRON run to its end in f32, over the jnp path and over the fused
+    kernels: each stops within f32's noise floor of the optimum that a
+    float64 solve of the same data finds (which of its last iterations a
+    path takes does not matter here)."""
+    n = pallas_glm.MIN_FUSED_ROWS
+    b32 = _make_batch(rng, n)
+    b64 = batch_from_dense(
+        np.asarray(b32.features.dense, np.float64), np.asarray(b32.labels, np.float64),
+        offsets=np.asarray(b32.offsets, np.float64),
+        weights=np.asarray(b32.weights, np.float64), dtype=jnp.float64,
+    )
+    monkeypatch.setenv("PHOTON_PALLAS", "off")
+    m64, _ = _l2_logistic_problem("TRON", 60, tolerance=1e-12).run(b64)
+    assert m64.coefficients.means.dtype == jnp.float64
+    monkeypatch.setenv("PHOTON_PALLAS", pallas)
+    m32, r32 = _l2_logistic_problem("TRON", 60).run(b32)
+    assert int(r32.iterations) < 60
+    w64, w32 = np.asarray(m64.coefficients.means), np.asarray(m32.coefficients.means)
+    assert np.max(np.abs(w32 - w64)) <= 1e-4
 
 
 def test_tile_rows_and_eligibility_constants():
