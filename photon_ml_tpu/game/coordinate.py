@@ -113,10 +113,25 @@ class FixedEffectCoordinate(Coordinate):
             normalization=self.normalization,
             prior=self.prior_model.model.coefficients if self.prior_model else None,
         )
+        nnz = self.dataset.nnz
+        slots = getattr(batch.features, "slots", None)
+        if nnz is not None and slots is not None:
+            # host-known from the build: the entries every pass of this solve
+            # touches, the shard's own against the layout's padding
+            slot_counter = obs.current_run().registry.counter(
+                "photon_fe_slots_total",
+                "feature slots handed to the fixed-effect solver, "
+                "stored entries against the layout's padding",
+            )
+            slot_counter.labels(coordinate=self.coordinate_id, kind="real").inc(nnz)
+            slot_counter.labels(coordinate=self.coordinate_id, kind="padded").inc(
+                max(slots - nnz, 0)
+            )
         glm, result = problem.run(
             batch,
             initial_model=initial_model.model if initial_model else None,
             coordinate=self.coordinate_id,
+            nnz=nnz,
         )
         if jax.process_count() > 1:
             # tiled solves leave coefficients model-axis-sharded across

@@ -110,6 +110,10 @@ class FixedEffectDataset:
     # the mesh is kept so scoring can reassemble the global row-sharded
     # score vector (n_rows stays the LOCAL true row count)
     mesh: Optional[object] = None
+    # entries the shard's COO held when the batch was built (None for a
+    # dataset assembled around a ready device matrix): against the layout's
+    # ``slots`` it says how much of every pass is padding
+    nnz: Optional[int] = None
 
     @property
     def n_rows(self) -> int:
@@ -403,6 +407,7 @@ def build_fixed_effect_dataset(
         ),
         true_dim=raw.shard_dims[feature_shard],
         true_n_rows=raw.n_rows,
+        nnz=len(raw.shard_coo[feature_shard][0]),
     )
 
 

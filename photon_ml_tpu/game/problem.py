@@ -182,9 +182,11 @@ class GLMProblem:
         batch: LabeledBatch,
         initial_model: Optional[GeneralizedLinearModel] = None,
         coordinate: Optional[str] = None,
+        nnz: Optional[int] = None,
     ) -> Tuple[GeneralizedLinearModel, SolverResult]:
         """Train; returns (model in ORIGINAL space, solver result).
-        ``coordinate`` names the caller's coordinate on the ``fe.solve`` span.
+        ``coordinate`` names the caller's coordinate on the ``fe.solve`` span,
+        ``nnz`` the entries its dataset's build stored (host-known).
 
         Normalization semantics parity (Optimizer.scala:161-185 +
         GeneralizedLinearOptimizationProblem): warm-start coefficients are
@@ -232,9 +234,14 @@ class GLMProblem:
             reg_weight=float(self.config.reg_weight),
             l1_weight=float(solver_config.l1_weight),
             l2_weight=float(obj.l2),
+            # what one pass touches, from shapes and the build: no fetch
+            layout=getattr(batch.features, "layout", None),
+            dim=int(batch.dim),
+            slots=getattr(batch.features, "slots", None),
+            nnz=nnz,
         ) as sp:
-            # with a sink, an OWL-QN solve adds ``nonzeros`` and
-            # ``line_search_evals`` here (obs.record_solver_metrics)
+            # with a sink, an L-BFGS or OWL-QN solve adds ``line_search_evals``
+            # here, OWL-QN ``nonzeros`` too (obs.record_solver_metrics)
             result = optimize(vg_fn(obj), w0, solver_config, hvp=hvp_fn(obj))
             sp.sync(result)
 

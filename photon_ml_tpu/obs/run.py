@@ -161,23 +161,42 @@ def record_solver_metrics(solver: str, result) -> None:
     from .tracing import add_device_fetch_bytes
 
     # explicit fetch: host-level solves run inside the CD sweep's transfer
-    # guard, which rejects a bare np.asarray on a device array. An OWL-QN
-    # solve's three counters ride the same fetch.
-    owlqn = (result.line_search_evals, result.orthant_zeroed, result.nonzeros)
-    owlqn = owlqn if all(x is not None for x in owlqn) else ()
-    iters, reasons, grad, *owlqn = map(
+    # guard, which rejects a bare np.asarray on a device array. The passes a
+    # solve counted (OWL-QN always, plain L-BFGS at host level) and OWL-QN's
+    # two other counters ride the same fetch.
+    #
+    # The final gradient's norm is the solver's own: the row of
+    # ``grad_norm_history`` its last iteration wrote, taken on the device
+    # inside the solve (``_norm`` of the same gradient, every lane). The vector
+    # itself stays where it is: [d] floats a solve is 219 MB at d = 54.7M, and
+    # a norm taken here by a fresh device program would compile inside a
+    # traced window. A host solver's gradient is a host array already.
+    on_device = isinstance(result.gradient, jax.Array)
+    extras = [
+        x for x in (result.line_search_evals, result.orthant_zeroed, result.nonzeros)
+        if x is not None
+    ]
+    norm_source = result.grad_norm_history if on_device else result.gradient
+    iters, reasons, norm_source, *extras = map(
         np.asarray,
-        jax.device_get((result.iterations, result.reason, result.gradient, *owlqn)),
+        jax.device_get((result.iterations, result.reason, norm_source, *extras)),
     )
-    grad = grad.astype(np.float64)
     add_device_fetch_bytes(
         f"solver.{solver}",
-        iters.nbytes + reasons.nbytes + grad.nbytes + sum(x.nbytes for x in owlqn),
+        iters.nbytes + reasons.nbytes + norm_source.nbytes + sum(x.nbytes for x in extras),
     )
+    if on_device:
+        # [max_iter + 1, *lanes]: each lane's row is its own iteration count
+        rows = norm_source.reshape(norm_source.shape[0], -1)
+        gn = rows[iters.ravel(), np.arange(rows.shape[1])].astype(np.float64)
+    else:
+        # [d] for a scalar solve, [d, lanes] for batched ones
+        grad = norm_source.astype(np.float64)
+        gn = np.sqrt((grad * grad).sum(axis=0)).ravel()
 
     reg = run.registry
-    if owlqn:
-        _record_owlqn_path(reg, *(int(x.sum()) for x in owlqn))
+    if result.line_search_evals is not None:
+        _record_fe_path(reg, *(int(x.sum()) for x in extras))
     reg.summary(
         "photon_solver_iterations", "iterations per host-level solve"
     ).labels(solver=solver).observe_many(iters.ravel().tolist())
@@ -201,30 +220,32 @@ def record_solver_metrics(solver: str, result) -> None:
                 "solver lanes frozen at their last good iterate after a "
                 "non-finite loss/gradient",
             ).labels(solver=solver).inc(c)
-    # final gradient norm per solve: gradient is [d] for a scalar solve and
-    # [d, E] (or [d, lanes]) for batched ones — norm over axis 0 covers both
-    gn = np.sqrt((grad * grad).sum(axis=0)).ravel()
     reg.summary(
         "photon_solver_final_grad_norm", "final gradient norm per host-level solve"
     ).labels(solver=solver).observe_many(gn.tolist())
 
 
-def _record_owlqn_path(reg, evals: int, zeroed: int, nonzeros: int) -> None:
-    """What OWL-QN adds to a fixed-effect solve, on the enclosing ``fe.solve``
-    span (``game/problem.py``) and as counters by its coordinate. A solve with
-    no such span around it (a bare ``solve_lbfgs`` call) records nothing."""
+def _record_fe_path(reg, evals: int, zeroed: Optional[int] = None,
+                    nonzeros: Optional[int] = None) -> None:
+    """What a counting L-BFGS adds to a fixed-effect solve, on the enclosing
+    ``fe.solve`` span (``game/problem.py``) and as counters by its coordinate:
+    the value-and-gradient passes it issued and, under OWL-QN, the support and
+    the orthant's work. A solve with no such span around it (a bare
+    ``solve_lbfgs`` call) records nothing."""
     from .tracing import current_span
 
     solve_span = current_span()
     if solve_span is None or solve_span.name != "fe.solve":
         return
     solve_span.attrs["line_search_evals"] = evals
-    solve_span.attrs["nonzeros"] = nonzeros
     coordinate = str(solve_span.attrs.get("coordinate"))
     reg.counter(
         "photon_fe_line_search_evals_total",
-        "value-and-gradient evaluations issued by fixed-effect OWL-QN solves",
+        "value-and-gradient evaluations issued by fixed-effect L-BFGS and OWL-QN solves",
     ).labels(coordinate=coordinate).inc(evals)
+    if nonzeros is None:
+        return
+    solve_span.attrs["nonzeros"] = nonzeros
     reg.counter(
         "photon_fe_orthant_zeroed_total",
         "coefficients set to zero by OWL-QN's orthant projection, over accepted steps",

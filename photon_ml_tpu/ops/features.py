@@ -10,17 +10,44 @@ offset, weight). On TPU the batch is a struct-of-arrays in one of two layouts:
   accumulation is a scatter-add (segment sum). Right layout for wide, sparse
   feature spaces where densification is impossible.
 - ``sorted COO``: flat ``(coo_cols, coo_rows, coo_vals)`` triplets sorted by
-  column. The layout for HUGE d (millions+): the gradient scatter-add runs
-  with ``indices_are_sorted`` (XLA's only non-serial scatter path on TPU),
-  and the column axis partitions contiguously for model-axis sharding
-  (see parallel/sparse.py). Measured on v5e: unstructured gather/scatter is
-  ~7 cycles/element regardless of layout (no HBM cache, no vectorized
-  VMEM gather pre-SparseCore), so single-chip sparse throughput is
-  serialization-bound; the design answer is to *divide* that cost across
-  devices by (data x model) tiling, not to chase a magic kernel. A Pallas
-  route was measured and rejected: tpu.dynamic_gather only shuffles within
-  one (8, 128) vreg, so large-table gathers cannot vectorize on this
-  generation.
+  column. The gradient scatter-add runs with ``indices_are_sorted``, and the
+  column axis partitions contiguously for model-axis sharding (see
+  parallel/sparse.py); it is the layout a column-sharded solve needs, not
+  the faster one on one chip.
+
+What a sparse pass costs, measured on a v5e at twice the rows of the
+benchmark's sparse cell (2,359,296 rows x 12 one-hot slots = 28.3M slots into
+d = 54,686,453, f32, the cell's column law; my
+chip run, PR 34, a stand-alone probe of one value-and-gradient pass):
+
+    layout   pass     gather (matvec)            scatter-add (rmatvec)
+    ELL      0.80 s   0.47 s = 16.8 ns a slot    0.32 s = 0.25 s + a 0.06 s index sort
+    COO      1.52 s   1.02 s (0.80 s gather of w by column + 0.22 s
+                      UNSORTED scatter into rows)  0.49 s (0.24 s gather + 0.25 s)
+
+So a slot costs 16-19 ns to gather from a 219 MB vector and 9-11 ns to
+scatter into one (about 25 cycles the pair at 940 MHz, not the 7 cycles an
+element an older note here gave): 0.3% of what streaming the same bytes at the
+HBM peak would take. ``indices_are_sorted`` buys nothing at this width: the
+scatter into columns takes 0.248 s sorted (COO) or unsorted (ELL, whose
+scatter sorts its (index, update) pairs first, 0.05-0.06 s), and COO pays a
+second gather and an unsorted scatter for its margins. ``auto`` therefore stays
+ELL for every d above the dense limit. The older conclusion stands: one chip's
+sparse throughput is bound by serialized random access (no HBM cache, no
+vectorized VMEM gather before SparseCore), and the design answer is to
+*divide* that cost across devices by (data x model) tiling, not to chase a
+magic kernel. A Pallas route was measured and rejected: tpu.dynamic_gather
+only shuffles within one (8, 128) vreg, so large-table gathers cannot
+vectorize on this generation.
+
+The ELL sums are written over ``[k, n]`` views (``idx.T``, ``val.T``: the row
+axis minor, which is how the TPU lays a tall ``[n, k]`` array out anyway). Over
+``[n, k]`` intermediates the compiler padded k = 12 to 128 lanes, 1.2 GB a
+temporary and three alive at once inside the L-BFGS loop: 15.2 GB for a solve
+that needs 10.7 (tests/test_tpu_compile.py), on a 16 GB chip. What it costs
+(my chip runs, PR 34): the gather reads its indices slot-major and is 2.6%
+slower a pass in the benchmark's cell (0.2216 s against 0.2159 at 14.2M
+slots), a whole fit +0.5% at 1.18M rows and -2.1% at 2.36M.
 
 Zero-valued padding entries contribute nothing to margins or gradients, so no
 separate mask is needed; padded *rows* carry weight 0.
@@ -100,12 +127,22 @@ class FeatureMatrix:
             return self.idx.shape[0]
         return self.coo_n_rows
 
+    @property
+    def slots(self) -> int:
+        """Entries the layout stores, padding included: what one pass over the
+        matrix touches (n*d dense, n*k ELL, m COO). Host-known from shapes."""
+        if self.dense is not None:
+            return self.dense.shape[0] * self.dense.shape[1]
+        if self.idx is not None:
+            return self.idx.shape[0] * self.idx.shape[1]
+        return self.coo_cols.shape[0]
+
     def matvec(self, w: Array) -> Array:
         """x @ w -> [n]."""
         if self.dense is not None:
             return self.dense @ w
         if self.idx is not None:
-            return jnp.sum(self.val * jnp.take(w, self.idx, axis=0), axis=1)
+            return jnp.sum(self.val.T * jnp.take(w, self.idx.T, axis=0), axis=0)
         wv = jnp.take(w, self.coo_cols) * self.coo_vals
         return jnp.zeros(self.coo_n_rows, dtype=wv.dtype).at[self.coo_rows].add(wv)
 
@@ -132,9 +169,9 @@ class FeatureMatrix:
         if self.dense is not None:
             return self.dense.T @ c
         if self.idx is not None:
-            contrib = c[:, None] * self.val
+            contrib = c[None, :] * self.val.T
             return jnp.zeros(self.dim, dtype=contrib.dtype).at[
-                self.idx.reshape(-1)
+                self.idx.T.reshape(-1)
             ].add(contrib.reshape(-1))
         contrib = jnp.take(c, self.coo_rows) * self.coo_vals
         return jnp.zeros(self.dim, dtype=contrib.dtype).at[self.coo_cols].add(
@@ -162,9 +199,9 @@ class FeatureMatrix:
         if self.dense is not None:
             return (self.dense * self.dense).T @ c
         if self.idx is not None:
-            contrib = c[:, None] * self.val * self.val
+            contrib = c[None, :] * self.val.T * self.val.T
             return jnp.zeros(self.dim, dtype=contrib.dtype).at[
-                self.idx.reshape(-1)
+                self.idx.T.reshape(-1)
             ].add(contrib.reshape(-1))
         contrib = jnp.take(c, self.coo_rows) * self.coo_vals * self.coo_vals
         return jnp.zeros(self.dim, dtype=contrib.dtype).at[self.coo_cols].add(

@@ -41,7 +41,8 @@ def optimize(
     config: OptimizerConfig,
     hvp: Optional[HvpFn] = None,
 ) -> SolverResult:
-    if isinstance(w0, jax.core.Tracer):
+    host_level = not isinstance(w0, jax.core.Tracer)
+    if not host_level:
         # traced inside a jitted train function: nothing host-level to time
         # (the rule obs.record_solver_metrics follows)
         loss_tol, grad_tol = abs_tolerances(value_and_grad, w0, config.tolerance)
@@ -67,6 +68,9 @@ def optimize(
             l1_weight=config.l1_weight if kind == OptimizerType.OWLQN else 0.0,
             box_constraints=box,
             max_line_search_iterations=config.max_line_search_iterations,
+            # a host-level solve counts its passes, sink or no sink (one
+            # program either way); obs.record_solver_metrics reads the count
+            count_evals=host_level,
         )
     if kind == OptimizerType.TRON:
         if hvp is None:

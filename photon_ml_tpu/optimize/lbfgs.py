@@ -309,7 +309,9 @@ class _LBFGSState(NamedTuple):
     head: Array
     loss_history: Array
     grad_norm_history: Array
-    # OWL-QN alone (None otherwise: a leafless entry of the loop's carry)
+    # None = a leafless entry of the loop's carry. ``evals`` is carried by an
+    # OWL-QN solve and by one asked to count (``count_evals``: the host-level
+    # fixed-effect solve); ``zeroed`` by OWL-QN alone
     evals: Optional[Array] = None  # objective evaluations so far
     zeroed: Optional[Array] = None  # coefficients the orthant projection zeroed
 
@@ -322,6 +324,7 @@ class _LBFGSState(NamedTuple):
         "max_line_search_iterations",
         "has_box",
         "batched",
+        "count_evals",
     ),
 )
 def _solve(
@@ -337,12 +340,15 @@ def _solve(
     box_lower: Array,
     box_upper: Array,
     batched: bool = False,
+    count_evals: bool = False,
 ) -> SolverResult:
     m = num_corrections
     dtype = w0.dtype
     box = (box_lower, box_upper) if has_box else None
     l1 = l1_weight
     owlqn = l1 is not None
+    # an int32 beside the floats of the carry: it changes none of them
+    counted = owlqn or count_evals
 
     def full_objective(w):
         f, g = value_and_grad(w)
@@ -392,7 +398,7 @@ def _solve(
         head=jnp.asarray(0, jnp.int32) if batched else jnp.zeros(lanes, jnp.int32),
         loss_history=hist.at[0].set(f0),
         grad_norm_history=hist.at[0].set(_norm(pg0)),
-        evals=jnp.ones(lanes, jnp.int32) if owlqn else None,
+        evals=jnp.ones(lanes, jnp.int32) if counted else None,
         zeroed=jnp.zeros(lanes, jnp.int32) if owlqn else None,
     )
 
@@ -459,9 +465,10 @@ def _solve(
             rho = jnp.where(keep, s.rho, rho)
 
         evals = zeroed = None
-        if owlqn:
+        if counted:
             # the first trial plus one more every trip of the search's loop
             evals = jnp.where(keep, s.evals, s.evals + 1 + ls_trips)
+        if owlqn:
             crossed = ((s.w + t_new * direction) * orthant < 0) & improved & ~keep
             zeroed = s.zeroed + jnp.sum(crossed, axis=0, dtype=jnp.int32)
 
@@ -544,6 +551,7 @@ def solve_lbfgs(
     box_constraints: Optional[Tuple[Array, Array]] = None,
     max_line_search_iterations: int = 25,
     batched: bool = False,
+    count_evals: bool = False,
 ) -> SolverResult:
     """Minimize f(w) (+ l1*||w||_1 when ``l1_weight`` > 0) starting at w0.
 
@@ -557,6 +565,11 @@ def solve_lbfgs(
     ``batched=True`` solves an entity-minor stack of independent problems in
     lockstep: ``w0`` is ``[d, E]``, ``value_and_grad`` maps ``[d, E] ->
     ([E], [d, E])``, and the tolerances are per-lane ``[E]``.
+
+    ``count_evals=True`` makes a plain L-BFGS solve carry the count of its
+    objective evaluations as an OWL-QN solve always does
+    (``SolverResult.line_search_evals``); the default leaves the plain
+    program as it was, counter-free (the random effects' packed solves).
     """
     has_box = box_constraints is not None
     zero = jnp.zeros_like(w0)
@@ -576,6 +589,7 @@ def solve_lbfgs(
         lower,
         upper,
         batched,
+        count_evals,
     )
     obs.record_solver_metrics("lbfgs", result)
     return result

@@ -111,14 +111,21 @@ def _python_lines_run(fn, under):
     """Lines of Python ``fn`` executes on this thread in files under the path
     ``under`` (numpy's C work runs none; jax's first-use imports are not the
     build's): the measure of per-entity Python work, whatever the machine's
-    load."""
+    load. Not the build's either: the compile-event hook that a telemetry
+    listener of ANY earlier test of this process leaves installed for good
+    (``utils/compile_cache.py`` feeding ``obs/``), a dozen lines for every
+    program the build compiles at a new size; counted, it made this test pass
+    or fail by which test files its worker had run before."""
+    import os
     import sys
 
     lines = 0
+    not_the_builds = (os.path.join(under, "obs"), os.path.join(under, "utils", "compile_cache.py"))
 
     def tracer(frame, event, arg):
         nonlocal lines
-        if event == "line" and frame.f_code.co_filename.startswith(under):
+        name = frame.f_code.co_filename
+        if event == "line" and name.startswith(under) and not name.startswith(not_the_builds):
             lines += 1
         return tracer
 

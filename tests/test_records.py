@@ -72,7 +72,8 @@ def test_benchmark_source_cites_a_baseline_config_that_exists(config):
     """Each configuration's file names "BASELINE.json config N" as the origin
     of its widths: N is an entry ``BASELINE.json`` ``configs`` still has, and
     one of the configuration's own kind (a GLMix entry for a ``glmix-*``
-    configuration, the Poisson one for ``poisson-*``)."""
+    configuration, the Poisson one for ``poisson-*``, the fixed-effect logistic
+    one for ``logistic-*``)."""
     (entry,) = [c for c in _benchmark_configs() if c["name"] == config]
     source = json.loads((REPO / entry["file"]).read_text(encoding="utf-8"))["source"]
     cited = [int(n) for n in re.findall(r"BASELINE\.json config (\d+)", source)]
@@ -81,5 +82,5 @@ def test_benchmark_source_cites_a_baseline_config_that_exists(config):
     assert "measured_baselines" not in baseline
     for n in cited:
         assert 1 <= n <= len(baseline["configs"]), (n, len(baseline["configs"]))
-        kind = {"glmix": "GLMix", "poisson": "Poisson"}[config.split("-")[0]]
+        kind = {"glmix": "GLMix", "poisson": "Poisson", "logistic": "logistic regression"}[config.split("-")[0]]
         assert kind in baseline["configs"][n - 1]

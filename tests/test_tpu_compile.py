@@ -134,3 +134,46 @@ def test_the_exchange_gathers_a_buckets_slots_on_its_own_chip(mesh, chunks):
         assert sharding.is_equivalent_to(offsets.sharding, 2)
     chip_plane_bytes = E // CHIPS * K * 4
     assert compiled.memory_analysis().temp_size_in_bytes < chip_plane_bytes // 8 + N_ROWS * 4
+
+
+# -- the sparse cell's solver on ONE chip (benchmark/configs/logistic-sparse-1chip.json) --------
+
+
+@pytest.mark.parametrize("layout,limit_gb", [("ell", 11.0), ("coo", 10.6)])
+def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_gb):
+    """``jit__solve`` at one chip's whole share of the sparse deployment
+    (2,359,296 rows x 12 slots into 54,686,453 columns, plain L-BFGS, m = 10;
+    the cell runs half those rows). The TPU tiles a
+    ``[10, d]`` history as (8, 128): 16 rows, 3.5 GB each, 7 GB the two, and
+    that is the floor. What this pins is the rest: with the ELL sums written
+    over ``[n, k]`` intermediates the compiler padded each to 128 lanes (1.2 GB,
+    three alive at once: 14.2 GB of temporaries, no room on a 16 GB chip); over
+    ``[k, n]`` (the row axis minor, as the arrays lie on the device) nothing is
+    padded and the solve holds 10.66 GB in all (9.67 GB of it temporaries), as over sorted COO (10.47 GB)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from photon_ml_tpu.ops.features import FeatureMatrix, LabeledBatch
+    from photon_ml_tpu.ops.glm import GLMObjective, vg_fn
+    from photon_ml_tpu.ops.losses import get_loss
+    from photon_ml_tpu.optimize import lbfgs
+    from photon_ml_tpu.optimize.common import as_partial
+
+    n, k, d = 2_359_296, 12, 54_686_453
+    one = SingleDeviceSharding(topo.devices[0])
+    s = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    if layout == "ell":
+        features = FeatureMatrix(dim=d, idx=s((n, k), jnp.int32), val=s((n, k)))
+    else:
+        features = FeatureMatrix(dim=d, coo_cols=s((n * k,), jnp.int32), coo_rows=s((n * k,), jnp.int32),
+                                 coo_vals=s((n * k,)), coo_n_rows=n)
+    batch = LabeledBatch(features=features, labels=s((n,)), offsets=s((n,)), weights=s((n,)))
+    objective = GLMObjective(loss=get_loss("logistic_regression"), batch=batch, l2=1000.0)
+    compiled = lbfgs._solve.lower(
+        as_partial(vg_fn(objective)), s((d,)), s(()), s(()), 100, 10, None, 25, False, s((d,)), s((d,)),
+        False, True,
+    ).compile()
+    memory = compiled.memory_analysis()
+    total = memory.temp_size_in_bytes + memory.argument_size_in_bytes + memory.output_size_in_bytes
+    assert total < limit_gb * 1e9, total
+    # no [n, k] temporary padded to 128 lanes: n * 128 * 4 bytes each
+    assert f"[{n},{k}]{{1,0:T(8,128)}}" not in compiled.as_text()
