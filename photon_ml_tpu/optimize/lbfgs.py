@@ -203,8 +203,12 @@ def _line_search(
     g_plain: Optional[Array] = None,
 ) -> Tuple[Array, Array, Array, Array, Array, Array]:
     """Strong-Wolfe bisection line search; returns (w_new, f_new, g_new,
-    success, the step length kept, the loop's trip count: one objective
-    evaluation each, after the first trial's).
+    success, the step length kept, the number of trials judged: one objective
+    evaluation each, the first trial's among them).
+
+    Each trial is judged (Armijo / curvature / finite, bracket, next step
+    length) before the next is evaluated, as ``host_driver._line_search`` does
+    for one lane: nothing is evaluated after the trial that ends the search.
 
     A trial whose value is not finite (Poisson's ``exp`` past z = 88 in f32
     gives ``inf``, and ``inf - inf`` downstream ``NaN``) is a FAILED step on
@@ -234,9 +238,53 @@ def _line_search(
             f_t = f_t + l1 * jnp.sum(jnp.abs(w_t), axis=0)
         return w_t, f_t, g_t
 
+    def judge(s: _LineSearchState) -> _LineSearchState:
+        # s holds every lane's newest trial: the verdict on it, the bracket and,
+        # in ``t`` of a lane that searches on, the step length to try next. A
+        # done lane's trial is frozen, so judging it again says the same.
+        # The barrier keeps the verdict's reductions out of the evaluation's
+        # fusions: they read the trial as written, as they did across the
+        # loop's carry when the loop judged first. Fused, the packed lanes of
+        # a TPU round otherwise and stop on other iterations (PERF.md, PR 35)
+        s = jax.lax.optimization_barrier(s)
+        if box is not None:
+            armijo_ok = s.f_t <= f + _C1 * _vdot(g_plain, s.w_t - w)
+        else:
+            armijo_ok = s.f_t <= f + _C1 * s.t * dg
+        ok = armijo_ok & jnp.isfinite(s.f_t)
+        if orthant is None and box is None:
+            # weak Wolfe (Lewis-Overton bisection scheme): convergent under pure
+            # bisection/expansion and still guarantees s.y > 0 for the history
+            curv_ok = _vdot(s.g_t, direction) >= _C2 * dg
+        else:
+            curv_ok = jnp.ones(lanes, bool)
+        accept = ok & curv_ok
+
+        # bracket update
+        new_hi = jnp.where(ok, s.hi, s.t)
+        new_lo = jnp.where(ok & ~curv_ok, s.t, s.lo)
+        new_t = jnp.where(
+            jnp.isinf(new_hi), 2.0 * new_lo + 1.0, 0.5 * (new_lo + new_hi)
+        )
+        # if Armijo failed, bisect downward
+        new_t = jnp.where(ok, new_t, 0.5 * (s.lo + s.t))
+
+        it = s.it + 1
+        done = accept | (it >= max_iters)
+        return s._replace(
+            t=jnp.where(done, s.t, new_t),
+            lo=jnp.where(done, s.lo, new_lo),
+            hi=jnp.where(done, s.hi, new_hi),
+            it=it,
+            done=done,
+            success=s.success | accept,
+        )
+
     w0_t, f0_t, g0_t = trial(jnp.asarray(1.0, dtype))
 
-    init = _LineSearchState(
+    # the first trial is judged before the loop: a search whose full step every
+    # lane accepts runs no trip
+    init = judge(_LineSearchState(
         t=jnp.full(lanes, 1.0, dtype),
         lo=jnp.zeros(lanes, dtype),
         hi=jnp.full(lanes, jnp.inf, dtype),
@@ -246,49 +294,21 @@ def _line_search(
         it=jnp.asarray(0, jnp.int32),
         done=jnp.zeros(lanes, bool),
         success=jnp.zeros(lanes, bool),
-    )
+    ))
 
     def cond(s: _LineSearchState):
         return jnp.logical_not(jnp.all(s.done))
 
     def body(s: _LineSearchState):
-        if box is not None:
-            armijo_ok = s.f_t <= f + _C1 * _vdot(g_plain, s.w_t - w)
-        else:
-            armijo_ok = s.f_t <= f + _C1 * s.t * dg
-        if orthant is None and box is None:
-            # weak Wolfe (Lewis-Overton bisection scheme): convergent under pure
-            # bisection/expansion and still guarantees s.y > 0 for the history
-            curv_ok = _vdot(s.g_t, direction) >= _C2 * dg
-        else:
-            curv_ok = jnp.ones(lanes, bool)
-        accept = armijo_ok & curv_ok & jnp.isfinite(s.f_t)
-
-        # bracket update
-        new_hi = jnp.where(armijo_ok & jnp.isfinite(s.f_t), s.hi, s.t)
-        new_lo = jnp.where(armijo_ok & jnp.isfinite(s.f_t) & ~curv_ok, s.t, s.lo)
-        new_t = jnp.where(
-            jnp.isinf(new_hi), 2.0 * new_lo + 1.0, 0.5 * (new_lo + new_hi)
-        )
-        # if Armijo failed, bisect downward
-        new_t = jnp.where(armijo_ok & jnp.isfinite(s.f_t), new_t, 0.5 * (s.lo + s.t))
-
-        hit_max = s.it + 1 >= max_iters
-        done = accept | hit_max
-
-        w_t, f_t, g_t = trial(new_t)
+        # evaluate first, judge last: a trip runs only while some lane still
+        # searches, so no evaluation follows an accepted step
+        w_t, f_t, g_t = trial(s.t)
         # freeze trial values if done
-        return _LineSearchState(
-            t=jnp.where(done, s.t, new_t),
-            lo=jnp.where(done, s.lo, new_lo),
-            hi=jnp.where(done, s.hi, new_hi),
-            f_t=jnp.where(done, s.f_t, f_t),
-            g_t=jnp.where(done, s.g_t, g_t),
-            w_t=jnp.where(done, s.w_t, w_t),
-            it=s.it + 1,
-            done=done,
-            success=s.success | accept,
-        )
+        return judge(s._replace(
+            f_t=jnp.where(s.done, s.f_t, f_t),
+            g_t=jnp.where(s.done, s.g_t, g_t),
+            w_t=jnp.where(s.done, s.w_t, w_t),
+        ))
 
     final = jax.lax.while_loop(cond, body, init)
     return final.w_t, final.f_t, final.g_t, final.success, final.t, final.it
@@ -421,7 +441,7 @@ def _solve(
         if owlqn:
             orthant = jnp.where(s.w != 0, jnp.sign(s.w), -jnp.sign(pg))
 
-        w_new, f_new, g_new, ls_ok, t_new, ls_trips = _line_search(
+        w_new, f_new, g_new, ls_ok, t_new, ls_trials = _line_search(
             value_and_grad, s.w, s.f, direction, dg, l1, orthant,
             max_line_search_iterations, box=box, g_plain=s.g,
         )
@@ -466,8 +486,8 @@ def _solve(
 
         evals = zeroed = None
         if counted:
-            # the first trial plus one more every trip of the search's loop
-            evals = jnp.where(keep, s.evals, s.evals + 1 + ls_trips)
+            # one evaluation for every trial the search judged
+            evals = jnp.where(keep, s.evals, s.evals + ls_trials)
         if owlqn:
             crossed = ((s.w + t_new * direction) * orthant < 0) & improved & ~keep
             zeroed = s.zeroed + jnp.sum(crossed, axis=0, dtype=jnp.int32)

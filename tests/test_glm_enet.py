@@ -232,10 +232,12 @@ def _lowered_text(fn, *args, **kwargs) -> str:
 
 
 def test_an_l2_solve_lowers_to_the_program_it_was_before_the_l1_operand():
-    """Golden hashes recorded on the parent commit (02bdbd5, static l1 weight)
-    under this conftest (CPU, x64 on, explicit f32 shapes), BEFORE the change:
-    the random effects' packed solve and a scalar L-BFGS solve carry no
-    pseudo-gradient, orthant or counter op when the choice is L2."""
+    """Golden hashes recorded on PR 35's commit (the child of 3bcf70e: the line
+    search that judges a trial before it evaluates the next one lowers both
+    solves to another program than PR 32's parent 02bdbd5 did) under this
+    conftest (CPU, x64 on, explicit f32 shapes): the random effects' packed
+    solve and a scalar L-BFGS solve carry no pseudo-gradient, orthant or
+    counter op when the choice is L2."""
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
     e, k, s = 16, 8, 4
     packed = _lowered_text(
@@ -243,7 +245,7 @@ def test_an_l2_solve_lowers_to_the_program_it_was_before_the_l1_operand():
         f32(e, k, s), f32(e, k), f32(e, k), f32(e, k), f32(e, s), f32(e, s), f32(e, s),
         task="logistic_regression", l2=1.0, l1=0.0, optimizer_type="LBFGS", tolerance=1e-6,
         max_iterations=30, num_corrections=10, max_cg_iterations=20, max_improvement_failures=5)
-    assert packed == "573dd7db5b2ed1289932ed6b14c3540bf01a0afd0c2f987bb5e140635598bc35"
+    assert packed == "1ff3a8032e03746d3011e3908ed46616ea962b9b087966d2577b3adb34ad1631"
 
     def run(a, b, w0):
         vg = lambda w: (0.5 * jnp.sum((a @ w - b) ** 2), a.T @ (a @ w - b))
@@ -252,7 +254,7 @@ def test_an_l2_solve_lowers_to_the_program_it_was_before_the_l1_operand():
         return r.coefficients, r.iterations
 
     scalar = _lowered_text(jax.jit(run), f32(32, 8), f32(32), f32(8))
-    assert scalar == "825b953aea8639f5e6713efd41668ce1c8bc14b1801403987a23d9fc542e0da1"
+    assert scalar == "cb2dd8466a55007b5e73f6ace917fe3478136c8f977688a4ea1ff896473560f0"
 
 
 # -- a non-finite trial value is a failed step --------------------------------------

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 import scipy.optimize
+import scipy.special
 
 from photon_ml_tpu.ops import GLMObjective, LOGISTIC, POISSON, SQUARED, batch_from_dense
 from photon_ml_tpu.optimize import (
@@ -14,10 +15,12 @@ from photon_ml_tpu.optimize import (
     OptimizerConfig,
     OptimizerType,
     optimize,
+    lbfgs,
     solve_lbfgs,
     solve_tron,
 )
 from photon_ml_tpu.optimize.common import abs_tolerances
+from photon_ml_tpu.optimize.host_driver import solve_lbfgs_host
 
 
 def quadratic_fn(A, b):
@@ -398,3 +401,149 @@ def test_state_tracker_history(rng):
     # loss history monotonically non-increasing
     assert np.all(np.diff(hist[: k + 1]) <= 1e-12)
     assert np.all(np.isnan(hist[k + 1:]))
+
+
+# -- the line search judges a trial before it evaluates the next one -----------------
+
+
+def _counting(vg):
+    """``vg`` with a list that grows by one every time the program EXECUTES it."""
+    executed = []
+
+    def counted(w):
+        jax.debug.callback(lambda: executed.append(1))
+        return vg(w)
+
+    return counted, executed
+
+
+def _steep_logistic(seed=3, n=120, d=6, l2=0.05):
+    """A logistic loss whose full first steps overshoot (features five units
+    wide), for the device and for the host solver."""
+    rng = np.random.default_rng(seed)
+    x = 5.0 * rng.normal(size=(n, d))
+    y = (rng.uniform(size=n) < scipy.special.expit(x @ rng.normal(size=d) / 5.0)).astype(float)
+
+    def make(xp, sigmoid):
+        xa, ya = xp.asarray(x), xp.asarray(y)
+
+        def vg(w):
+            z = xa @ w
+            f = xp.sum(xp.logaddexp(0.0, z) - ya * z) + 0.5 * l2 * w @ w
+            return f, xa.T @ (sigmoid(z) - ya) + l2 * w
+
+        return vg
+
+    return make(jnp, jax.nn.sigmoid), make(np, scipy.special.expit), d
+
+
+def _bounded_qp(seed=5, d=6):
+    """0.5 w'Aw - b'w whose free optimum lies outside the box on four sides."""
+    rng = np.random.default_rng(seed)
+    m = rng.normal(size=(d, d))
+    A, b = m @ m.T + 0.5 * np.eye(d), 3.0 * rng.normal(size=d)
+
+    def make(xp):
+        Aa, ba = xp.asarray(A), xp.asarray(b)
+        return lambda w: (0.5 * w @ (Aa @ w) - ba @ w, Aa @ w - ba)
+
+    return make(jnp), make(np), d
+
+
+@pytest.mark.parametrize("mode", ["plain", "owlqn", "box"])
+def test_a_solve_executes_one_pass_per_judged_trial_as_the_host_solver_does(mode):
+    """1 pass at the start point + one for every trial a search judged, and not
+    one more: the program's executions, its own count and the calls of the host
+    solver (which returns on acceptance before it evaluates again) agree."""
+    vg, host_vg, d = _bounded_qp() if mode == "box" else _steep_logistic()
+    kwargs = dict(max_iterations=60)
+    if mode == "owlqn":
+        kwargs["l1_weight"] = 2.0
+    box = (np.full(d, -0.3), np.full(d, 0.4)) if mode == "box" else None
+    calls = []
+
+    def host_counted(w):
+        calls.append(1)
+        return host_vg(w)
+
+    host = solve_lbfgs_host(host_counted, np.zeros(d), 1e-9, 1e-7, box_constraints=box, **kwargs)
+
+    counted, executed = _counting(vg)
+    res = solve_lbfgs(counted, jnp.zeros(d, jnp.float64), jnp.asarray(1e-9), jnp.asarray(1e-7),
+                      box_constraints=box and tuple(jnp.asarray(b) for b in box), count_evals=True, **kwargs)
+    jax.effects_barrier()
+    assert len(executed) == int(res.line_search_evals) == len(calls)
+    assert int(res.iterations) == int(host.iterations) > 2
+    # some search needed a second trial, so the agreement is not one of 1 + iterations alone
+    assert len(executed) > int(res.iterations) + 1
+    np.testing.assert_allclose(np.asarray(res.coefficients), host.coefficients, atol=1e-9)
+
+
+def test_an_accepted_first_trial_is_the_iterations_only_pass(rng):
+    """A well-scaled quadratic takes the full step every time, with an empty
+    history and with pairs in it: each iteration executes the objective once."""
+    q, _ = np.linalg.qr(rng.normal(size=(8, 8)))
+    A = q * rng.uniform(0.8, 1.25, size=8)  # singular values near one
+    counted, executed = _counting(quadratic_fn(A, rng.normal(size=8)))
+    res = solve_lbfgs(counted, jnp.zeros(8, jnp.float64), jnp.asarray(1e-30), jnp.asarray(1e-9),
+                      max_iterations=50, count_evals=True)
+    jax.effects_barrier()
+    assert int(res.iterations) >= 3  # the later searches ran on a history
+    assert len(executed) == int(res.line_search_evals) == 1 + int(res.iterations)
+
+
+@pytest.mark.parametrize("k", [1, 3, 6])
+def test_a_search_no_step_satisfies_executes_its_cap_of_trials(k):
+    """The gradient promises a descent the value never delivers: every trial
+    fails Armijo, ``k`` trials are evaluated and judged, none after the last."""
+    w = jnp.asarray([1.0, -2.0, 0.5], jnp.float64)
+    g = jnp.asarray([1.0, 1.0, 1.0], jnp.float64)
+    lying = lambda u: (jnp.sum((u - w) ** 2), g)  # noqa: E731
+    counted, executed = _counting(lying)
+    direction = -g
+    w_t, _, _, success, t, trials = lbfgs._line_search(
+        counted, w, jnp.asarray(0.0, jnp.float64), direction, jnp.vdot(direction, g), None, None, k)
+    jax.effects_barrier()
+    assert len(executed) == int(trials) == k and not bool(success)
+    # the point returned is the last one judged: the step halved k - 1 times
+    assert float(t) == 0.5 ** (k - 1)
+    np.testing.assert_array_equal(np.asarray(w_t), np.asarray(w + t * direction))
+
+    # and a solve that meets such a search books 1 + k passes and stops where it stood
+    counted, executed = _counting(lying)
+    res = solve_lbfgs(counted, w, jnp.asarray(1e-12), jnp.asarray(1e-12),
+                      max_line_search_iterations=k, count_evals=True)
+    jax.effects_barrier()
+    assert len(executed) == int(res.line_search_evals) == 1 + k
+    assert int(res.reason) == ConvergenceReason.OBJECTIVE_NOT_IMPROVING
+    np.testing.assert_array_equal(np.asarray(res.coefficients), np.asarray(w))
+
+
+def test_batched_lanes_of_1_2_and_4_trials_cost_the_slowest_lanes_trials(rng):
+    """Lane e minimises 0.5 c_e |w - a_e|^2 from 0: the full step is exact at
+    c = 1, overshoots to an equal value at c = 2 (accepted at t = 1/2) and
+    needs t = 1/8 at c = 8. In lockstep the program evaluates four trials, not
+    five, and each lane ends where its own single-lane solve does."""
+    c = np.asarray([1.0, 2.0, 8.0])
+    a = rng.normal(size=(4, 3))
+    cj, aj = jnp.asarray(c), jnp.asarray(a)
+    lt, gt = jnp.asarray(1e-12), jnp.asarray(1e-10)
+
+    def lane(e):
+        return lambda w: (0.5 * cj[e] * jnp.sum((w - aj[:, e]) ** 2), cj[e] * (w - aj[:, e]))
+
+    singles = [solve_lbfgs(lane(e), jnp.zeros(4, jnp.float64), lt, gt, count_evals=True) for e in range(3)]
+    assert [int(r.line_search_evals) - 1 for r in singles] == [1, 2, 4]
+
+    counted, executed = _counting(
+        lambda W: (0.5 * cj * jnp.sum((W - aj) ** 2, axis=0), cj * (W - aj)))
+    res = solve_lbfgs(counted, jnp.zeros((4, 3), jnp.float64), jnp.full(3, lt), jnp.full(3, gt),
+                      batched=True, count_evals=True)
+    jax.effects_barrier()
+    assert len(executed) == 1 + 4
+    # a lane's count is the passes the program made while the lane was live
+    np.testing.assert_array_equal(np.asarray(res.line_search_evals), [5, 5, 5])
+    for e, single in enumerate(singles):
+        assert int(res.iterations[e]) == int(single.iterations) == 1
+        np.testing.assert_allclose(np.asarray(res.coefficients[:, e]), np.asarray(single.coefficients), atol=1e-12)
+        np.testing.assert_allclose(float(res.loss[e]), float(single.loss), atol=1e-12)
