@@ -14,6 +14,7 @@ descent loop owns residual composition (CoordinateDataScores semantics, P7).
 from __future__ import annotations
 
 import dataclasses
+import time
 import weakref
 from functools import partial
 from typing import Optional, Tuple, Union
@@ -458,26 +459,34 @@ class RandomEffectCoordinate(Coordinate):
         else:
             xp, xdt = jnp, dtype
             to_host = lambda a: a  # noqa: E731 — single decision point
-        if initial_model is not None:
-            w0 = to_host(
-                _initial_subspace_coefficients(self.dataset, initial_model, dtype)
-            )
-        else:
-            w0 = xp.zeros((E, S), xdt)
+        # the solver's [E, S] state: a warm start and a prior come through
+        # the model projection, whose layout fetches block (the span is
+        # their parent)
+        with obs.span(
+            "re.warm_start", coordinate=self.coordinate_id,
+            warm=initial_model is not None, priors=self.prior_model is not None,
+        ) as sp:
+            if initial_model is not None:
+                w0 = to_host(
+                    _initial_subspace_coefficients(self.dataset, initial_model, dtype)
+                )
+            else:
+                w0 = xp.zeros((E, S), xdt)
 
-        prior_mean = xp.zeros((E, S), xdt)
-        prior_prec = xp.ones((E, S), xdt)
-        if self.prior_model is not None:
-            prior_mean = to_host(
-                _project_model_values(
-                    self.dataset, self.prior_model, self.prior_model.coef_values, dtype
+            prior_mean = xp.zeros((E, S), xdt)
+            prior_prec = xp.ones((E, S), xdt)
+            if self.prior_model is not None:
+                prior_mean = to_host(
+                    _project_model_values(
+                        self.dataset, self.prior_model, self.prior_model.coef_values, dtype
+                    )
                 )
-            )
-            if self.prior_model.variances is not None:
-                var = _project_model_values(
-                    self.dataset, self.prior_model, self.prior_model.variances, dtype
-                )
-                prior_prec = to_host(1.0 / jnp.maximum(var, 1e-12))
+                if self.prior_model.variances is not None:
+                    var = _project_model_values(
+                        self.dataset, self.prior_model, self.prior_model.variances, dtype
+                    )
+                    prior_prec = to_host(1.0 / jnp.maximum(var, 1e-12))
+            sp.sync(w0, prior_mean, prior_prec)
 
         solver_kwargs = self._solver_kwargs()
         counts = self.dataset.entity_counts
@@ -506,14 +515,20 @@ class RandomEffectCoordinate(Coordinate):
                         w0, prior_mean, prior_prec, **solver_kwargs,
                     )
                 else:
-                    part = _train_blocks_packed(
-                        *_bucket_operands(
-                            (blocks.features, blocks.labels, blocks.weights),
-                            offsets, (w0, prior_mean, prior_prec),
-                            chunks, sharded, start, end, kb, sb,
-                        ),
-                        **solver_kwargs,
+                    # with a sink the bucket says how much of its enqueue is
+                    # the cut: the rest of enqueue_s is the solve's dispatch
+                    # photon: ignore[R7] — an attribute of the bucket's own
+                    # span (a child span a bucket would be one more event)
+                    cut_start = time.perf_counter() if obs.active() else None
+                    operands = _bucket_operands(
+                        (blocks.features, blocks.labels, blocks.weights),
+                        offsets, (w0, prior_mean, prior_prec),
+                        chunks, sharded, start, end, kb, sb,
                     )
+                    if cut_start is not None:
+                        # photon: ignore[R7] — closes the stamp above
+                        sp.attrs["cut_s"] = time.perf_counter() - cut_start
+                    part = _train_blocks_packed(*operands, **solver_kwargs)
                 sp.sync(part)
             if obs.active() and not multiproc:
                 # (across processes a bucket's lanes are not all addressable

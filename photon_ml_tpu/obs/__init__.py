@@ -56,6 +56,7 @@ from .tracing import (
     current_span,
     get_process_index,
     get_replica_id,
+    record_device_fetch,
     record_span,
     set_process_index,
     set_replica_id,
@@ -95,6 +96,7 @@ __all__ = [
     "memory_block",
     "read_host_memory",
     "record_build_info",
+    "record_device_fetch",
     "record_solver_metrics",
     "record_span",
     "sample_memory",

@@ -16,6 +16,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import threading
+import time
 from typing import Dict, List, Optional
 
 from ..utils.events import Event, EventEmitter, EventListener
@@ -158,7 +159,7 @@ def record_solver_metrics(solver: str, result) -> None:
     import numpy as np
 
     from ..optimize.common import ConvergenceReason
-    from .tracing import add_device_fetch_bytes
+    from .tracing import record_device_fetch
 
     # explicit fetch: host-level solves run inside the CD sweep's transfer
     # guard, which rejects a bare np.asarray on a device array. The passes a
@@ -177,13 +178,19 @@ def record_solver_metrics(solver: str, result) -> None:
         if x is not None
     ]
     norm_source = result.grad_norm_history if on_device else result.gradient
+    fetch_start = time.perf_counter()
     iters, reasons, norm_source, *extras = map(
         np.asarray,
         jax.device_get((result.iterations, result.reason, norm_source, *extras)),
     )
-    add_device_fetch_bytes(
+    fetch_end = time.perf_counter()
+    # this fetch, not the enclosing fe.solve span's fence, is where a traced
+    # run waits for the solve: the fence finds the device drained
+    record_device_fetch(
         f"solver.{solver}",
         iters.nbytes + reasons.nbytes + norm_source.nbytes + sum(x.nbytes for x in extras),
+        fetch_start,
+        fetch_end,
     )
     if on_device:
         # [max_iter + 1, *lanes]: each lane's row is its own iteration count

@@ -13,12 +13,14 @@ up two kinds of annotation:
   ``compile_s`` — host cost apart from execute cost;
 - device-transfer byte counters (``add_device_fetch_bytes`` /
   ``add_device_put_bytes``), called at the known host<->device crossing
-  points (tracker aggregation, streamed staging/collection).
+  points (tracker aggregation, streamed staging/collection), and the
+  seconds of each blocking fetch (``record_device_fetch``).
 
 Device work is dispatched asynchronously, so a host span around a device
 phase times the enqueue. ``Span.sync(*arrays)`` fences the phase when a
 sink is attached: the span then covers the phase's host AND device time
-(``attrs["device"]``), which is why a traced run is a per-layer run and
+(``attrs["device"]``, split into ``enqueue_s`` before the fence and
+``wait_s`` inside it), which is why a traced run is a per-layer run and
 never an end-to-end timing. With no sink it does nothing.
 
 Span exit emits a ``SpanEvent`` through the current run's EventEmitter, so a
@@ -138,15 +140,25 @@ class Span:
 
     def sync(self, *arrays) -> None:
         """Fence a device phase: with a sink attached, wait for ``arrays``
-        so the span's duration covers the device work it dispatched, and
-        mark it ``device``. A wait, not a transfer — legal under the sweep's
-        transfer guard. With no sink: nothing (the untraced program keeps
-        its async dispatch)."""
+        so the span's duration covers the device work it dispatched, mark it
+        ``device`` and split it into ``enqueue_s`` (span start to the first
+        fence) and ``wait_s`` (inside fences). A wait, not a transfer —
+        legal under the sweep's transfer guard. With no sink: nothing (the
+        untraced program keeps its async dispatch)."""
         if not _run.current_run().has_listeners():
             return
         import jax
 
+        fenced = time.perf_counter()
         jax.block_until_ready(arrays)
+        waited = time.perf_counter() - fenced
+        # the span's two halves: the host's own work up to its first fence
+        # (cuts, tracing-cache look-ups, dispatch), and the seconds it then
+        # stood waiting (summed if the span fences twice). A fenced phase
+        # starts on a drained device, so wait_s is the device time the
+        # enqueue did not cover
+        self.attrs.setdefault("enqueue_s", fenced - self.start_perf)
+        self.attrs["wait_s"] = self.attrs.get("wait_s", 0.0) + waited
         self.attrs["device"] = True
 
 
@@ -265,6 +277,25 @@ def _add_transfer_bytes(direction: str, site: str, nbytes: int) -> None:
 def add_device_fetch_bytes(site: str, nbytes: int) -> None:
     """Count a device->host fetch (nbytes is host-known: no extra sync)."""
     _add_transfer_bytes("fetch", site, nbytes)
+
+
+def record_device_fetch(
+    site: str, nbytes: int, start_perf: float, end_perf: float
+) -> None:
+    """Count a blocking device->host fetch: its bytes and the seconds it
+    blocked. With no fence in the way (no sink), the seconds summed over
+    sites are what the host stood waiting for the device. With a sink a
+    fetch inside a span is also a closed ``fetch`` leaf under it (outside
+    any span there is no tree to place the wait in: the serving worker's
+    per-batch fetch stays a counter)."""
+    add_device_fetch_bytes(site, nbytes)
+    _run.current_run().registry.counter(
+        "photon_device_fetch_seconds_total",
+        "host seconds inside blocking device-fetch calls at instrumented sites",
+    ).labels(site=site).inc(end_perf - start_perf)
+    parent = _ctx.get()
+    if parent is not None:
+        record_span("fetch", start_perf, end_perf, parent=parent, site=site, bytes=int(nbytes))
 
 
 def add_device_put_bytes(site: str, nbytes: int) -> None:

@@ -15,7 +15,8 @@ The convention, enforced end to end:
   ``transfer_guard()``;
 - every legitimate fetch goes through :func:`logged_fetch`, which is
   explicit (guard-proof) AND counted in the obs registry
-  (``photon_device_fetch_bytes_total{site=...}``).
+  (``photon_device_fetch_bytes_total{site=...}``, and the seconds it blocked
+  in ``photon_device_fetch_seconds_total{site=...}``).
 
 Together they promote PR 1's zero-fetch invariant from "a test asserts the
 tracker was lazy" to "the runtime hard-errors on any unlogged fetch".
@@ -36,6 +37,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import time
 from typing import Iterator
 
 import jax
@@ -108,7 +110,11 @@ def logged_fetch(site: str, tree):
     Returns host numpy (``jax.device_get``); numpy inputs pass through
     unchanged and are not counted. ``site`` labels the transfer in
     ``photon_device_fetch_bytes_total`` so a sweep's fetch budget is
-    attributable line-item by line-item."""
+    attributable line-item by line-item, and in
+    ``photon_device_fetch_seconds_total``: the call blocks until the device
+    has produced ``tree``, so with no sink attached (no fence drains the
+    device first) the seconds over all sites against the job's wall are the
+    share of time the host stood waiting for the chip."""
     import numpy as np
 
     nbytes = sum(
@@ -116,7 +122,9 @@ def logged_fetch(site: str, tree):
         for leaf in jax.tree_util.tree_leaves(tree)
         if not isinstance(leaf, (np.ndarray, np.generic))
     )
+    start = time.perf_counter()
     host = jax.device_get(tree)
+    end = time.perf_counter()
     if nbytes:
-        obs.add_device_fetch_bytes(site, nbytes)
+        obs.record_device_fetch(site, nbytes, start, end)
     return host

@@ -29,6 +29,21 @@ SINK_ONLY_FETCH_SITES = {
 }
 
 
+# the device phases: each waits for its result before it closes
+FENCED = {
+    "fe.solve", "fe.tolerances", "fe.score",
+    "re.exchange", "re.warm_start", "re.bucket", "re.collect", "re.score",
+}
+
+
+# where the untraced fit of this file fetches (and so waits for the device)
+# (coordinate.host_state is the CPU backend's: the solver's state as host numpy)
+UNTRACED_FETCH_SITES = {
+    "cd.update_guard", "coordinate.project_layout", "coordinate.host_state",
+    "evaluation.device_metrics",
+}
+
+
 class _Spans(EventListener):
     def __init__(self):
         self.spans = []
@@ -81,11 +96,19 @@ def _fit(data, run, n_fits=1):
 
 @pytest.fixture(scope="module")
 def traced(data):
-    """Two fits with a collecting listener and the timeline recorder."""
+    """Two fits with a collecting listener and the timeline recorder. (The
+    INFO optimization summary fetches on its own account, under
+    cd.coordinate, and an earlier test of the process may have left INFO on.)"""
     run, spans, timeline = obs.RunTelemetry(), _Spans(), obs.TimelineRecorder()
     run.register_listener(spans)
     run.register_listener(timeline)
-    results = _fit(data, run, n_fits=2)
+    logger = logging.getLogger("photon_ml_tpu")
+    level = logger.level
+    logger.setLevel(logging.WARNING)
+    try:
+        results = _fit(data, run, n_fits=2)
+    finally:
+        logger.setLevel(level)
     return results, spans.spans, run.registry.snapshot(), timeline
 
 
@@ -127,9 +150,14 @@ def test_tree_shape_and_stable_names(traced):
         "fe.tolerances": {"fe.solve"},
         "fe.score": {"cd.score"},
         "re.exchange": {"cd.train"},
+        "re.warm_start": {"cd.train"},
         "re.bucket": {"cd.train"},
         "re.collect": {"cd.train"},
         "re.score": {"cd.score"},
+        # a blocking fetch is a leaf under the span that waited for it: the
+        # TRON solve's own metrics, the projection's layout, a bucket's
+        # iterations, the tracker's reductions, the guard, the metric
+        "fetch": {"fe.solve", "re.warm_start", "cd.train", "cd.tracker", "cd.guard", "evaluate.device"},
     }
     # everything variable is an attribute, never part of a name
     fit = next(s for s in spans if s.name == "fit")
@@ -146,13 +174,107 @@ def test_tree_shape_and_stable_names(traced):
     # the device phases were fenced (a sink is attached) and say so; the
     # spans that only group them carry no such mark, and no new span below
     # cd.train / cd.score carries a phase
-    fenced = {"fe.solve", "fe.tolerances", "fe.score", "re.exchange", "re.bucket", "re.collect", "re.score"}
     for s in spans:
-        assert (s.attrs.get("device") is True) == (s.name in fenced), s.name
-        if s.name in fenced:
+        assert (s.attrs.get("device") is True) == (s.name in FENCED), s.name
+        if s.name in FENCED:
             assert "phase" not in s.attrs
+    warm = [s for s in spans if s.name == "re.warm_start" and s.root_id == fit.root_id]
+    # the first sweep starts from a zero model, every later one from the last
+    assert [(s.attrs["coordinate"], s.attrs["warm"], s.attrs["priors"]) for s in warm] == [
+        ("per-user", it > 0, False) for it in range(N_SWEEPS)
+    ]
     val_ctx = next(s for s in spans if s.name == "fit.validation_context")
     assert val_ctx.attrs["rows"] == 200 and val_ctx.attrs["put_bytes"] > 0
+
+
+def test_a_fenced_span_splits_into_enqueue_and_wait(traced):
+    """``Span.sync`` stamps the span it fences: the host's seconds up to the
+    fence, the seconds inside it, and nothing left over but the span's close;
+    a bucket also says how much of its enqueue was the cut."""
+    _, spans, _, _ = traced
+    assert {s.name for s in spans if "wait_s" in s.attrs} == FENCED
+    for s in spans:
+        if s.name not in FENCED:
+            assert not {"enqueue_s", "wait_s", "cut_s"} & set(s.attrs), s.name
+            continue
+        assert 0.0 <= s.attrs["enqueue_s"] and 0.0 <= s.attrs["wait_s"], s.name
+        assert s.attrs["enqueue_s"] + s.attrs["wait_s"] <= s.duration_s, s.name
+        if s.name == "re.bucket":
+            assert 0.0 <= s.attrs["cut_s"] <= s.attrs["enqueue_s"]
+        else:
+            assert "cut_s" not in s.attrs
+
+
+def test_a_fence_twice_sums_the_waits_and_keeps_the_first_enqueue():
+    run = obs.RunTelemetry()
+    run.register_listener(_Spans())
+    with obs.use_run(run), obs.span("phase") as sp:
+        sp.sync(jnp.ones(3))
+        first = dict(sp.attrs)
+        sp.sync(jnp.ones(3))
+    assert sp.attrs["enqueue_s"] == first["enqueue_s"]
+    assert sp.attrs["wait_s"] > first["wait_s"]
+    assert sp.attrs["enqueue_s"] + sp.attrs["wait_s"] <= sp.duration_s
+
+
+def test_fetch_spans_and_the_seconds_counter_agree_with_the_bytes_counter(traced):
+    """Every blocking fetch is one ``fetch`` leaf with its site and bytes, and
+    ``photon_device_fetch_seconds_total`` has the bytes counter's sites."""
+    _, spans, snapshot, _ = traced
+    fetches = [s for s in spans if s.name == "fetch"]
+    by_site = collections.Counter()
+    for s in fetches:
+        by_site[s.attrs["site"]] += s.attrs["bytes"]
+        assert s.attrs["bytes"] > 0 and s.duration_s >= 0.0
+    parents = {s.parent_id for s in spans}
+    assert not any(s.span_id in parents for s in fetches)  # leaves
+    counted = {
+        name: {m["labels"]["site"]: m["value"] for m in snapshot if m["name"] == name}
+        for name in ("photon_device_fetch_bytes_total", "photon_device_fetch_seconds_total")
+    }
+    assert dict(by_site) == counted["photon_device_fetch_bytes_total"]
+    seconds = counted["photon_device_fetch_seconds_total"]
+    assert set(seconds) == set(by_site)
+    for site, total in seconds.items():
+        assert total == pytest.approx(sum(s.duration_s for s in fetches if s.attrs["site"] == site))
+    # the warm start's fetches are the projection's (and, on the CPU backend,
+    # the projected state's way to host numpy): it is their only parent
+    by_id = {s.span_id: s for s in spans}
+    for site in ("coordinate.project_layout", "coordinate.host_state"):
+        assert {by_id[s.parent_id].name for s in fetches if s.attrs["site"] == site} == {"re.warm_start"}
+
+
+def test_a_fetch_outside_any_span_is_counted_and_makes_no_span():
+    """The serving worker fetches once a batch under no span: the counters
+    have it, the sinks are not sent a leaf with no tree."""
+    from photon_ml_tpu.utils.transfer import logged_fetch
+
+    run, spans = obs.RunTelemetry(), _Spans()
+    run.register_listener(spans)
+    with obs.use_run(run):
+        logged_fetch("somewhere", jnp.ones(4))
+        with obs.span("phase"):
+            logged_fetch("somewhere", jnp.ones(4))
+            logged_fetch("somewhere", np.ones(4))  # host numpy: no fetch at all
+    assert [(s.name, s.attrs.get("site")) for s in spans.spans] == [("fetch", "somewhere"), ("phase", None)]
+    snapshot = run.registry.snapshot()
+    assert _counter(snapshot, "photon_device_fetch_bytes_total", site="somewhere") == 2 * jnp.ones(4).nbytes
+    assert _counter(snapshot, "photon_device_fetch_seconds_total", site="somewhere") > 0.0
+
+
+def test_a_random_effects_train_call_is_covered_by_its_children(traced):
+    """``re.exchange``, ``re.warm_start``, the buckets and their sink-only
+    fetches, ``re.collect``: what is left of ``cd.train`` is the loop's own
+    Python (most of a CPU fit at this size; the chip's figure is PERF.md's)."""
+    _, spans, _, _ = traced
+    trains = [s for s in spans if s.name == "cd.train" and s.attrs["coordinate"] == "per-user"]
+    assert len(trains) == 2 * N_SWEEPS
+    for train in trains:
+        children = [s for s in spans if s.parent_id == train.span_id]
+        assert [s.name for s in children if s.name != "fetch"][:2] == ["re.exchange", "re.warm_start"]
+        assert children[-1].name == "re.collect"
+        self_s = train.duration_s - sum(s.duration_s for s in children)
+        assert 0.0 <= self_s < 0.2 * train.duration_s
 
 
 def test_one_root_id_per_fit(traced):
@@ -308,13 +430,34 @@ def test_no_listener_no_fence_no_fetch_and_the_same_model(traced, data, monkeypa
     real = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready", lambda x: (fences.append(1), real(x))[1])
     quiet = obs.RunTelemetry()  # a registry, no listener
+    recorded = []
+    real_record = obs.tracing.record_span
+    monkeypatch.setattr(
+        obs.tracing, "record_span",
+        lambda *a, **kw: (recorded.append(real_record(*a, **kw)), recorded[-1])[1],
+    )
+    synced = []
+    real_sync = obs.Span.sync
+    monkeypatch.setattr(
+        obs.Span, "sync", lambda self, *arrays: (real_sync(self, *arrays), synced.append(self))[0]
+    )
     untraced = _fit(data, quiet)[0]
     assert fences == []
+    # every fetch still passes the one place a ``fetch`` span would be made,
+    # and none is; every phase still calls ``sync``, and none is stamped
+    assert recorded and set(recorded) == {None}
+    assert {s.name for s in synced} == FENCED
+    assert not any({"enqueue_s", "wait_s", "cut_s", "device"} & set(s.attrs) for s in synced)
     sites = {
         m["labels"]["site"] for m in quiet.registry.snapshot()
         if m["name"] == "photon_device_fetch_bytes_total"
     }
-    assert not sites & SINK_ONLY_FETCH_SITES, sites
+    assert sites == UNTRACED_FETCH_SITES
+    # the one always-on addition: the seconds of those same fetches
+    assert sites == {
+        m["labels"]["site"] for m in quiet.registry.snapshot()
+        if m["name"] == "photon_device_fetch_seconds_total"
+    }
     assert not [m for m in quiet.registry.snapshot() if m["name"].startswith("photon_re_lane_")]
     # with a listener the spans do fence, and the sink-only sites are exactly
     # what the ledger gains

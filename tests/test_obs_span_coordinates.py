@@ -17,7 +17,10 @@ from photon_ml_tpu.testing import generate_mixed_effect_data
 from photon_ml_tpu.testing.generators import mixed_data_to_raw_dataset
 from photon_ml_tpu.utils.events import EventListener
 
-PHASES = {"fe.solve", "fe.tolerances", "fe.score", "re.exchange", "re.bucket", "re.collect", "re.score"}
+PHASES = {
+    "fe.solve", "fe.tolerances", "fe.score",
+    "re.exchange", "re.warm_start", "re.bucket", "re.collect", "re.score",
+}
 RANDOM_EFFECTS = {
     "per-user": ("userShard", "userId", (40, 4), 16),
     "per-item": ("itemShard", "itemId", (6, 3), 64),
