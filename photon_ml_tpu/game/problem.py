@@ -224,9 +224,12 @@ class GLMProblem:
 
             w0 = reshard(jnp.asarray(w0, dtype), mesh, PartitionSpec(MODEL_AXIS))
 
-        from ..ops.glm import hvp_fn, vg_fn
+        from ..ops.glm import hvp_fn, margin_fns, vg_fn
 
         solver_config = self.config.solver_config()
+        # the two-pass objective comes as its steps too, whatever the layout:
+        # a plain L-BFGS walks them (one matvec and one rmatvec an iteration)
+        margins = margin_fns(obj) if fused is None else None
         with obs.span(
             "fe.solve",
             coordinate=coordinate,
@@ -240,9 +243,12 @@ class GLMProblem:
             slots=getattr(batch.features, "slots", None),
             nnz=nnz,
         ) as sp:
-            # with a sink, an L-BFGS or OWL-QN solve adds ``line_search_evals``
+            # with a sink, an L-BFGS or OWL-QN solve adds ``line_search`` (the
+            # search it ran: ``margins`` | ``points``) and ``line_search_evals``
             # here, OWL-QN ``nonzeros`` too (obs.record_solver_metrics)
-            result = optimize(vg_fn(obj), w0, solver_config, hvp=hvp_fn(obj))
+            result = optimize(
+                vg_fn(obj), w0, solver_config, hvp=hvp_fn(obj), margins=margins
+            )
             sp.sync(result)
 
         variances = compute_variances(obj, result.coefficients, self.config.variance_type)

@@ -162,9 +162,10 @@ def record_solver_metrics(solver: str, result) -> None:
     from .tracing import record_device_fetch
 
     # explicit fetch: host-level solves run inside the CD sweep's transfer
-    # guard, which rejects a bare np.asarray on a device array. The passes a
-    # solve counted (OWL-QN always, plain L-BFGS at host level) and OWL-QN's
-    # two other counters ride the same fetch.
+    # guard, which rejects a bare np.asarray on a device array. The evaluations
+    # a solve counted (OWL-QN always, plain L-BFGS at host level), the feature
+    # passes of one that walked margins and OWL-QN's two other counters ride
+    # the same fetch.
     #
     # The final gradient's norm is the solver's own: the row of
     # ``grad_norm_history`` its last iteration wrote, taken on the device
@@ -173,15 +174,16 @@ def record_solver_metrics(solver: str, result) -> None:
     # a norm taken here by a fresh device program would compile inside a
     # traced window. A host solver's gradient is a host array already.
     on_device = isinstance(result.gradient, jax.Array)
-    extras = [
-        x for x in (result.line_search_evals, result.orthant_zeroed, result.nonzeros)
-        if x is not None
-    ]
+    counts = {
+        name: getattr(result, name)
+        for name in ("line_search_evals", "orthant_zeroed", "nonzeros", "matvecs", "rmatvecs")
+        if getattr(result, name, None) is not None
+    }
     norm_source = result.grad_norm_history if on_device else result.gradient
     fetch_start = time.perf_counter()
     iters, reasons, norm_source, *extras = map(
         np.asarray,
-        jax.device_get((result.iterations, result.reason, norm_source, *extras)),
+        jax.device_get((result.iterations, result.reason, norm_source, *counts.values())),
     )
     fetch_end = time.perf_counter()
     # this fetch, not the enclosing fe.solve span's fence, is where a traced
@@ -203,7 +205,7 @@ def record_solver_metrics(solver: str, result) -> None:
 
     reg = run.registry
     if result.line_search_evals is not None:
-        _record_fe_path(reg, *(int(x.sum()) for x in extras))
+        _record_fe_path(reg, **{name: int(x.sum()) for name, x in zip(counts, extras)})
     reg.summary(
         "photon_solver_iterations", "iterations per host-level solve"
     ).labels(solver=solver).observe_many(iters.ravel().tolist())
@@ -232,31 +234,44 @@ def record_solver_metrics(solver: str, result) -> None:
     ).labels(solver=solver).observe_many(gn.tolist())
 
 
-def _record_fe_path(reg, evals: int, zeroed: Optional[int] = None,
-                    nonzeros: Optional[int] = None) -> None:
+def _record_fe_path(reg, line_search_evals: int, orthant_zeroed: Optional[int] = None,
+                    nonzeros: Optional[int] = None, matvecs: Optional[int] = None,
+                    rmatvecs: Optional[int] = None) -> None:
     """What a counting L-BFGS adds to a fixed-effect solve, on the enclosing
     ``fe.solve`` span (``game/problem.py``) and as counters by its coordinate:
-    the value-and-gradient passes it issued and, under OWL-QN, the support and
-    the orthant's work. A solve with no such span around it (a bare
-    ``solve_lbfgs`` call) records nothing."""
+    the search it ran (``line_search``: ``margins`` where the solve counted
+    its own passes over the features, else ``points``), the trials its
+    searches judged (and the first evaluation), a margin walk's passes and,
+    under OWL-QN, the support and the orthant's work. A solve with no such
+    span around it (a bare ``solve_lbfgs`` call) records nothing."""
     from .tracing import current_span
 
     solve_span = current_span()
     if solve_span is None or solve_span.name != "fe.solve":
         return
-    solve_span.attrs["line_search_evals"] = evals
+    solve_span.attrs["line_search"] = "points" if matvecs is None else "margins"
+    solve_span.attrs["line_search_evals"] = line_search_evals
     coordinate = str(solve_span.attrs.get("coordinate"))
     reg.counter(
         "photon_fe_line_search_evals_total",
-        "value-and-gradient evaluations issued by fixed-effect L-BFGS and OWL-QN solves",
-    ).labels(coordinate=coordinate).inc(evals)
+        "objective evaluations of fixed-effect L-BFGS and OWL-QN solves: the first and every trial judged",
+    ).labels(coordinate=coordinate).inc(line_search_evals)
+    if matvecs is not None:
+        # a search over points is not counted here: its evaluations are the
+        # counter above, and what one reads of X is its objective's to say
+        passes = reg.counter(
+            "photon_fe_feature_passes_total",
+            "passes over the feature matrix made by fixed-effect L-BFGS solves that walk margins",
+        )
+        passes.labels(coordinate=coordinate, kind="matvec").inc(matvecs)
+        passes.labels(coordinate=coordinate, kind="rmatvec").inc(rmatvecs)
     if nonzeros is None:
         return
     solve_span.attrs["nonzeros"] = nonzeros
     reg.counter(
         "photon_fe_orthant_zeroed_total",
         "coefficients set to zero by OWL-QN's orthant projection, over accepted steps",
-    ).labels(coordinate=coordinate).inc(zeroed)
+    ).labels(coordinate=coordinate).inc(orthant_zeroed)
     reg.gauge(
         "photon_fe_nonzero_coefficients",
         "non-zero coefficients of the last fixed-effect OWL-QN solve, in the solver's space",

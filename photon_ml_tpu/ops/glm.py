@@ -73,6 +73,17 @@ class GLMObjective:
     per-entity blocks. The reference achieved this with abstract
     ``type Data`` polymorphism (ObjectiveFunction.scala:25-74); here it falls
     out of JAX's transforms.
+
+    On the two-pass ``jnp`` path (``fused is None``) ``value_and_grad`` is two
+    steps with the row-length margins between them, ``grad_from_margins(
+    margins(w), w)``, and the steps are offered apart (:func:`margin_fns`): the
+    margins are AFFINE in the coefficients, so along a search direction p
+    ``margins(w + t p) = margins(w) + t * direction_margins(p)``, and
+    ``value_and_slope`` gives the objective and its derivative along p at any
+    step length from row-length sums, with no pass over the features. The
+    L-BFGS line search walks them (optimize/lbfgs.py): one ``matvec`` and one
+    ``rmatvec`` an iteration, however many step lengths it tries. The fused
+    kernels read X once for value AND gradient and keep ``value_and_grad``.
     """
 
     loss: PointwiseLoss
@@ -112,11 +123,6 @@ class GLMObjective:
             jnp.ones_like(like) if self.prior_precision is None else self.prior_precision
         )
 
-    def _margins(self, coef: Array) -> Tuple[Array, Array]:
-        """Returns (margins, effective_coef)."""
-        eff, mshift = self._norm().effective_coefficients(coef)
-        return self.batch.features.matvec(eff) + mshift + self.batch.offsets, eff
-
     def value(self, coef: Array) -> Array:
         return self.value_and_grad(coef)[0]
 
@@ -125,43 +131,74 @@ class GLMObjective:
 
     def value_and_grad(self, coef: Array) -> Tuple[Array, Array]:
         b = self.batch
-        norm = self._norm()
-        if self.fused is not None and b.features.is_dense:
-            # single-sweep Pallas kernel returns the raw aggregates; the
-            # normalization/L2 algebra below is identical to the jnp path
-            from .pallas_glm import sharded_value_grad
+        if self.fused is None or not b.features.is_dense:
+            return self.grad_from_margins(self.margins(coef), coef)
+        # single-sweep Pallas kernel returns the raw aggregates; the
+        # normalization/L2 algebra is the jnp path's
+        from .pallas_glm import sharded_value_grad
 
-            eff, mshift = norm.effective_coefficients(coef)
-            value, raw_grad, wdz_sum = sharded_value_grad(
-                self.fused_mesh, b.features.dense, eff, b.labels,
-                b.offsets + mshift, b.weights, self.loss,
-                interpret=(self.fused == "interpret"),
-            )
-            grad = raw_grad
-            if norm.shifts is not None:
-                grad = grad - norm.shifts * wdz_sum
-        else:
-            z, _ = self._margins(coef)
-            loss, dz = self.loss.loss_and_dz(z, b.labels)
-            wdz = b.weights * dz
-            value = jnp.sum(b.weights * loss)
-            raw_grad = b.features.rmatvec(wdz)
-            # grad_j = factor_j * (raw_grad_j - shift_j * sum_i w_i dz_i)
-            grad = raw_grad
-            if norm.shifts is not None:
-                grad = grad - norm.shifts * jnp.sum(wdz)
-        if norm.factors is not None:
-            grad = grad * norm.factors
-        delta = self._reg_delta(coef)
-        prec = self._precision(coef)
-        value = value + 0.5 * self.l2 * jnp.dot(delta, prec * delta)
-        grad = grad + self.l2 * prec * delta
-        return value, grad
+        norm = self._norm()
+        eff, mshift = norm.effective_coefficients(coef)
+        value, raw_grad, wdz_sum = sharded_value_grad(
+            self.fused_mesh, b.features.dense, eff, b.labels,
+            b.offsets + mshift, b.weights, self.loss,
+            interpret=(self.fused == "interpret"),
+        )
+        return self._finish_value_grad(coef, value, raw_grad, wdz_sum)
+
+    def _finish_value_grad(
+        self, coef: Array, value: Array, raw_grad: Array, wdz_sum: Array
+    ) -> Tuple[Array, Array]:
+        """The row sums' way into the solver's space: shifts, factors, and the
+        L2 / prior term at ``coef`` (the streamed objective's finalize step)."""
+        return finalize_value_grad(
+            coef, value, raw_grad, wdz_sum, self._norm(), self.l2,
+            self.prior_mean, self.prior_precision,
+        )
+
+    # -- the two-pass path's steps, apart (see the class docstring) ------------
+
+    def margins(self, coef: Array) -> Array:
+        """z = X eff(coef) + shift(coef) + offsets: the gather."""
+        eff, mshift = self._norm().effective_coefficients(coef)
+        return self.batch.features.matvec(eff) + mshift + self.batch.offsets
+
+    def direction_margins(self, direction: Array) -> Array:
+        """u with ``margins(w + t p) = margins(w) + t u``: ``margins(p)``
+        without the offsets."""
+        eff, mshift = self._norm().effective_coefficients(direction)
+        return self.batch.features.matvec(eff) + mshift
+
+    def value_and_slope(
+        self, z: Array, u: Array, t: Array, coef: Array, direction: Array
+    ) -> Tuple[Array, Array]:
+        """phi(t) = F(coef + t direction) and phi'(t), given z = margins(coef)
+        and u = direction_margins(direction): row-length sums, plus the L2 /
+        prior term streamed at coef + t direction. No feature is touched."""
+        b = self.batch
+        loss, dz = self.loss.loss_and_dz(z + t * u, b.labels)
+        value = jnp.sum(b.weights * loss)
+        slope = jnp.sum(b.weights * dz * u)
+        delta = self._reg_delta(coef + t * direction)
+        scaled = self._precision(coef) * delta
+        value = value + 0.5 * self.l2 * jnp.dot(delta, scaled)
+        slope = slope + self.l2 * jnp.dot(scaled, direction)
+        return value, slope
+
+    def grad_from_margins(self, z: Array, coef: Array) -> Tuple[Array, Array]:
+        """Value and full gradient at ``coef`` given z = margins(coef): the
+        scatter-add ``rmatvec(w l'(z))``, shifts, factors, L2 / prior."""
+        b = self.batch
+        loss, dz = self.loss.loss_and_dz(z, b.labels)
+        wdz = b.weights * dz
+        value = jnp.sum(b.weights * loss)
+        raw_grad = b.features.rmatvec(wdz)
+        wdz_sum = jnp.sum(wdz) if self._norm().shifts is not None else None
+        return self._finish_value_grad(coef, value, raw_grad, wdz_sum)
 
     def _d2z_weights(self, coef: Array) -> Array:
         b = self.batch
-        z, _ = self._margins(coef)
-        return b.weights * self.loss.d2z(z, b.labels)
+        return b.weights * self.loss.d2z(self.margins(coef), b.labels)
 
     def hessian_vector(self, coef: Array, v: Array) -> Array:
         """H(w') v — the TRON inner-CG kernel
@@ -187,10 +224,7 @@ class GLMObjective:
             if norm.shifts is not None:
                 hv = hv - norm.shifts * csum
         else:
-            wl2 = self._d2z_weights(coef)
-            eff_v, vshift = norm.effective_coefficients(v)
-            u = b.features.matvec(eff_v) + vshift
-            c = wl2 * u
+            c = self._d2z_weights(coef) * self.direction_margins(v)
             hv = b.features.rmatvec(c)
             if norm.shifts is not None:
                 hv = hv - norm.shifts * jnp.sum(c)
@@ -396,6 +430,34 @@ def vg_fn(obj: GLMObjective):
 
 def hvp_fn(obj: GLMObjective):
     return jax.tree_util.Partial(_hvp, obj)
+
+
+def _margins(obj: "GLMObjective", coef: Array) -> Array:
+    return obj.margins(coef)
+
+
+def _direction_margins(obj: "GLMObjective", direction: Array) -> Array:
+    return obj.direction_margins(direction)
+
+
+def _value_and_slope(obj: "GLMObjective", z, u, t, coef, direction):
+    return obj.value_and_slope(z, u, t, coef, direction)
+
+
+def _grad_from_margins(obj: "GLMObjective", z: Array, coef: Array):
+    return obj.grad_from_margins(z, coef)
+
+
+def margin_fns(obj: GLMObjective):
+    """The two-pass objective's steps for a search that walks margins, in the
+    order of ``optimize.common.MarginFns`` (margins, direction_margins,
+    value_and_slope, grad_from_margins), each jit-cache-stable as ``vg_fn``
+    is. For the two-pass path: the fused kernels read X once for value and
+    gradient, and a solve over them keeps ``vg_fn``."""
+    return tuple(
+        jax.tree_util.Partial(fn, obj)
+        for fn in (_margins, _direction_margins, _value_and_slope, _grad_from_margins)
+    )
 
 
 @jax.jit

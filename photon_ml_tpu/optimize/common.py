@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +31,20 @@ Array = jax.Array
 ValueAndGradFn = Callable[[Array], Tuple[Array, Array]]
 # Callable (w, v) -> H(w) v
 HvpFn = Callable[[Array, Array], Array]
+
+
+class MarginFns(NamedTuple):
+    """An objective F(w) = sum_i l(z_i) + reg(w) whose margins z are AFFINE in
+    w, as its steps: what a line search needs to try step lengths along a
+    direction p without a pass over the features (lbfgs.py, the ``margins``
+    search). ``value_and_grad(w) == grad_from_margins(margins(w), w)`` and
+    ``margins(w + t p) == margins(w) + t * direction_margins(p)``."""
+
+    margins: Callable[[Array], Array]  # w -> z (one matvec)
+    direction_margins: Callable[[Array], Array]  # p -> u (one matvec)
+    # (z, u, t, w, p) -> F(w + t p), dF/dt: row-length sums, no features
+    value_and_slope: Callable[[Array, Array, Array, Array, Array], Tuple[Array, Array]]
+    grad_from_margins: Callable[[Array, Array], Tuple[Array, Array]]  # (z, w) -> F, grad (one rmatvec)
 
 
 class ConvergenceReason(enum.IntEnum):
@@ -112,6 +126,12 @@ class SolverResult:
     line_search_evals: Optional[Array] = None
     orthant_zeroed: Optional[Array] = None
     nonzeros: Optional[Array] = None
+    # a counting L-BFGS solve whose search walked margins alone (i32): the
+    # passes over the features it made, ``direction_margins`` and the first
+    # ``margins`` / ``grad_from_margins``. None where every evaluation is a
+    # pass of each kind (``line_search_evals`` then counts them)
+    matvecs: Optional[Array] = None
+    rmatvecs: Optional[Array] = None
 
     @property
     def converged(self) -> Array:

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from .. import obs
 from .common import (
     HvpFn,
+    MarginFns,
     OptimizerConfig,
     OptimizerType,
     SolverResult,
@@ -40,7 +41,10 @@ def optimize(
     w0: Array,
     config: OptimizerConfig,
     hvp: Optional[HvpFn] = None,
+    margins: Optional[MarginFns] = None,
 ) -> SolverResult:
+    """``hvp`` is TRON's; ``margins`` are the objective as its steps, for an
+    L-BFGS whose search can walk them (``lbfgs.walks_margins``)."""
     host_level = not isinstance(w0, jax.core.Tracer)
     if not host_level:
         # traced inside a jitted train function: nothing host-level to time
@@ -71,6 +75,7 @@ def optimize(
             # a host-level solve counts its passes, sink or no sink (one
             # program either way); obs.record_solver_metrics reads the count
             count_evals=host_level,
+            margins=margins,
         )
     if kind == OptimizerType.TRON:
         if hvp is None:

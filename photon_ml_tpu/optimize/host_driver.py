@@ -11,7 +11,10 @@ driver and every evaluation is a ``treeAggregate`` over disk-persisted
 partitions (photon-lib .../optimization/LBFGS.scala:38-154,
 DistributedObjectiveFunction + AvroDataReader.scala:165-209).
 
-Parity contract with the device twins, single lane (scalar f, ``[d]`` g):
+Parity contract with the device twins, single lane (scalar f, ``[d]`` g); the
+device L-BFGS has two line searches since PR 37 and the twin here is its
+``points`` search (a streamed objective is an opaque callable: it brings no
+margin functions):
 
 - same constants (c1=1e-4, c2=0.9; TRON eta/sigma), same bracket updates,
   same correction-pair guard ``s.y > 1e-10 ||y||^2``, same steepest-descent

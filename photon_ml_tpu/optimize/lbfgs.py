@@ -7,8 +7,23 @@ single jit/vmap-safe implementation:
 - fixed-size (m, d) correction history with circular indexing (static shapes
   for XLA; m = numCorrections, default 10);
 - two-loop recursion preconditioned by the gamma = s.y/y.y scaling;
-- strong-Wolfe line search by bisection/expansion (c1=1e-4, c2=0.9) run inside
-  ``lax.while_loop`` with masked state so vmapped lanes freeze independently;
+- weak-Wolfe line search by bisection/expansion (c1=1e-4, c2=0.9) run inside
+  ``lax.while_loop`` with masked state so vmapped lanes freeze independently.
+  There are two searches and one verdict (``_verdict``: the same Armijo and
+  curvature tests, bracket, bisection, finiteness and trial cap). The ``points``
+  search (``_line_search``) evaluates ``value_and_grad(w + t p)`` at every step
+  length it tries: a pass over the features each way a trial. The ``margins``
+  search (``_margin_search``) serves an objective that comes as its steps
+  (``common.MarginFns``: a GLM's margins z are affine in w): the carry holds z
+  beside w, an iteration takes u = direction_margins(p) once, every trial is
+  phi(t), phi'(t) from row-length sums over z + t u, and the gradient is taken
+  once, at the step kept, from z + t u: ONE matvec and ONE rmatvec an
+  iteration however many lengths are tried, and no d-length array in the
+  search's state. z is carried from step to step (z + t u), computed from w
+  only at the solve's first evaluation; its drift is rounding (PERF.md, PR 37).
+  Which search runs is decided on what the solve is handed
+  (``walks_margins``): margin functions, no l1 weight, no box, one lane.
+  ``host_driver`` (streamed rows) mirrors the ``points`` search;
 - OWL-QN (l1_weight > 0): pseudo-gradient, direction orthant projection, and
   orthant-constrained line-search steps; the correction pairs use the plain
   gradient, convergence uses the pseudo-gradient — matching the OWL-QN
@@ -62,6 +77,7 @@ import jax.numpy as jnp
 from .. import obs
 from .common import (
     ConvergenceReason,
+    MarginFns,
     SolverResult,
     ValueAndGradFn,
     _norm,
@@ -178,6 +194,47 @@ def _two_loop(
     return jax.lax.fori_loop(0, m, loop2, r)
 
 
+def _verdict(s, f: Array, dg: Array, max_iters: int, decrease=None, slope=None):
+    """The verdict on a search state's newest trial, for both searches (``s``
+    is a ``_LineSearchState`` or a ``_MarginSearchState``): Armijo and finite,
+    curvature, the bracket, the step length to try next, ``done``.
+
+    ``decrease`` is Armijo's right-hand term, ``c1 * t * dg`` unless given
+    (L-BFGS-B measures it on the projected displacement). ``slope`` gives the
+    trial's directional derivative; None enforces Armijo alone (OWL-QN and
+    L-BFGS-B backtracking). It is a callable, called after the Armijo verdict,
+    so that the points search's reduction stays where its program had it."""
+    armijo_ok = s.f_t <= f + (_C1 * s.t * dg if decrease is None else decrease)
+    ok = armijo_ok & jnp.isfinite(s.f_t)
+    if slope is not None:
+        # weak Wolfe (Lewis-Overton bisection scheme): convergent under pure
+        # bisection/expansion and still guarantees s.y > 0 for the history
+        curv_ok = slope() >= _C2 * dg
+    else:
+        curv_ok = jnp.ones(jnp.shape(f), bool)
+    accept = ok & curv_ok
+
+    # bracket update
+    new_hi = jnp.where(ok, s.hi, s.t)
+    new_lo = jnp.where(ok & ~curv_ok, s.t, s.lo)
+    new_t = jnp.where(
+        jnp.isinf(new_hi), 2.0 * new_lo + 1.0, 0.5 * (new_lo + new_hi)
+    )
+    # if Armijo failed, bisect downward
+    new_t = jnp.where(ok, new_t, 0.5 * (s.lo + s.t))
+
+    it = s.it + 1
+    done = accept | (it >= max_iters)
+    return s._replace(
+        t=jnp.where(done, s.t, new_t),
+        lo=jnp.where(done, s.lo, new_lo),
+        hi=jnp.where(done, s.hi, new_hi),
+        it=it,
+        done=done,
+        success=s.success | accept,
+    )
+
+
 class _LineSearchState(NamedTuple):
     t: Array
     lo: Array
@@ -202,7 +259,9 @@ def _line_search(
     box: Optional[Tuple[Array, Array]] = None,
     g_plain: Optional[Array] = None,
 ) -> Tuple[Array, Array, Array, Array, Array, Array]:
-    """Strong-Wolfe bisection line search; returns (w_new, f_new, g_new,
+    """The ``points`` search: weak-Wolfe bisection over trial POINTS, each a
+    ``value_and_grad`` call (``_margin_search`` is the other search; the
+    verdict, ``_verdict``, is shared). Returns (w_new, f_new, g_new,
     success, the step length kept, the number of trials judged: one objective
     evaluation each, the first trial's among them).
 
@@ -248,37 +307,10 @@ def _line_search(
         # a TPU round otherwise and stop on other iterations (PERF.md, PR 35)
         s = jax.lax.optimization_barrier(s)
         if box is not None:
-            armijo_ok = s.f_t <= f + _C1 * _vdot(g_plain, s.w_t - w)
-        else:
-            armijo_ok = s.f_t <= f + _C1 * s.t * dg
-        ok = armijo_ok & jnp.isfinite(s.f_t)
-        if orthant is None and box is None:
-            # weak Wolfe (Lewis-Overton bisection scheme): convergent under pure
-            # bisection/expansion and still guarantees s.y > 0 for the history
-            curv_ok = _vdot(s.g_t, direction) >= _C2 * dg
-        else:
-            curv_ok = jnp.ones(lanes, bool)
-        accept = ok & curv_ok
-
-        # bracket update
-        new_hi = jnp.where(ok, s.hi, s.t)
-        new_lo = jnp.where(ok & ~curv_ok, s.t, s.lo)
-        new_t = jnp.where(
-            jnp.isinf(new_hi), 2.0 * new_lo + 1.0, 0.5 * (new_lo + new_hi)
-        )
-        # if Armijo failed, bisect downward
-        new_t = jnp.where(ok, new_t, 0.5 * (s.lo + s.t))
-
-        it = s.it + 1
-        done = accept | (it >= max_iters)
-        return s._replace(
-            t=jnp.where(done, s.t, new_t),
-            lo=jnp.where(done, s.lo, new_lo),
-            hi=jnp.where(done, s.hi, new_hi),
-            it=it,
-            done=done,
-            success=s.success | accept,
-        )
+            return _verdict(s, f, dg, max_iters, decrease=_C1 * _vdot(g_plain, s.w_t - w))
+        if orthant is not None:
+            return _verdict(s, f, dg, max_iters)
+        return _verdict(s, f, dg, max_iters, slope=lambda: _vdot(s.g_t, direction))
 
     w0_t, f0_t, g0_t = trial(jnp.asarray(1.0, dtype))
 
@@ -314,6 +346,55 @@ def _line_search(
     return final.w_t, final.f_t, final.g_t, final.success, final.t, final.it
 
 
+class _MarginSearchState(NamedTuple):
+    """``_LineSearchState`` without its d-length arrays: the trial is two
+    scalars, phi(t) in ``f_t`` and phi'(t) in ``slope_t``."""
+
+    t: Array
+    lo: Array
+    hi: Array
+    f_t: Array
+    slope_t: Array
+    it: Array
+    done: Array
+    success: Array
+
+
+def _margin_search(
+    value_and_slope, f: Array, dg: Array, max_iters: int
+) -> Tuple[Array, Array, Array]:
+    """``_line_search`` for one plain L-BFGS lane whose objective's margins
+    are affine in w (``MarginFns``): ``value_and_slope(t)`` gives phi(t) =
+    F(w + t p) and phi'(t) from row-length sums, where ``_line_search`` reads
+    F and ``grad F . p`` off a pass over the features. The same verdict on the
+    same two numbers (``_verdict``), so the same step lengths; the search
+    touches no feature and carries no d-length array. Returns (the step
+    length kept, success, the number of trials judged); the caller takes the
+    gradient once, at the step kept."""
+    dtype = f.dtype
+
+    def judged(s: _MarginSearchState, t: Array) -> _MarginSearchState:
+        f_t, slope_t = value_and_slope(t)
+        s = s._replace(f_t=f_t, slope_t=slope_t)
+        return _verdict(s, f, dg, max_iters, slope=lambda: s.slope_t)
+
+    one = jnp.asarray(1.0, dtype)
+    init = judged(_MarginSearchState(
+        t=one,
+        lo=jnp.asarray(0.0, dtype),
+        hi=jnp.asarray(jnp.inf, dtype),
+        f_t=f,
+        slope_t=dg,
+        it=jnp.asarray(0, jnp.int32),
+        done=jnp.asarray(False),
+        success=jnp.asarray(False),
+    ), one)
+    final = jax.lax.while_loop(
+        lambda s: jnp.logical_not(s.done), lambda s: judged(s, s.t), init
+    )
+    return final.t, final.success, final.it
+
+
 class _LBFGSState(NamedTuple):
     w: Array
     f: Array  # objective incl. l1 term if OWL-QN
@@ -334,6 +415,11 @@ class _LBFGSState(NamedTuple):
     # fixed-effect solve); ``zeroed`` by OWL-QN alone
     evals: Optional[Array] = None  # objective evaluations so far
     zeroed: Optional[Array] = None  # coefficients the orthant projection zeroed
+    # the margins search alone: z = margins(w), carried (z + t u at a step
+    # kept), and, where the solve counts, its passes over the features
+    z: Optional[Array] = None
+    matvecs: Optional[Array] = None
+    rmatvecs: Optional[Array] = None
 
 
 @partial(
@@ -361,6 +447,7 @@ def _solve(
     box_upper: Array,
     batched: bool = False,
     count_evals: bool = False,
+    margins: Optional[MarginFns] = None,  # solve_lbfgs: plain one-lane L-BFGS only
 ) -> SolverResult:
     m = num_corrections
     dtype = w0.dtype
@@ -369,6 +456,7 @@ def _solve(
     owlqn = l1 is not None
     # an int32 beside the floats of the carry: it changes none of them
     counted = owlqn or count_evals
+    walk = margins is not None
 
     def full_objective(w):
         f, g = value_and_grad(w)
@@ -378,7 +466,13 @@ def _solve(
 
     if box is not None:
         w0 = jnp.clip(w0, box[0], box[1])  # start feasible
-    f0, g0 = full_objective(w0)
+    z0 = None
+    if margins is not None:
+        # the solve's one margins(w): every later z is z + t u
+        z0 = margins.margins(w0)
+        f0, g0 = margins.grad_from_margins(z0, w0)
+    else:
+        f0, g0 = full_objective(w0)
     lanes = jnp.shape(f0)  # () single problem / [E] entity-minor batch
 
     hist = jnp.full((max_iterations + 1,) + lanes, jnp.nan, dtype)
@@ -420,6 +514,9 @@ def _solve(
         grad_norm_history=hist.at[0].set(_norm(pg0)),
         evals=jnp.ones(lanes, jnp.int32) if counted else None,
         zeroed=jnp.zeros(lanes, jnp.int32) if owlqn else None,
+        z=z0,
+        matvecs=jnp.ones(lanes, jnp.int32) if walk and counted else None,
+        rmatvecs=jnp.ones(lanes, jnp.int32) if walk and counted else None,
     )
 
     def cond(s: _LBFGSState):
@@ -441,10 +538,22 @@ def _solve(
         if owlqn:
             orthant = jnp.where(s.w != 0, jnp.sign(s.w), -jnp.sign(pg))
 
-        w_new, f_new, g_new, ls_ok, t_new, ls_trials = _line_search(
-            value_and_grad, s.w, s.f, direction, dg, l1, orthant,
-            max_line_search_iterations, box=box, g_plain=s.g,
-        )
+        if walk:
+            # one gather for the direction, a search over row-length sums, one
+            # scatter-add for the gradient at the step kept
+            u = margins.direction_margins(direction)
+            t_new, ls_ok, ls_trials = _margin_search(
+                lambda t: margins.value_and_slope(s.z, u, t, s.w, direction),
+                s.f, dg, max_line_search_iterations,
+            )
+            w_new = s.w + t_new * direction
+            z_new = s.z + t_new * u
+            f_new, g_new = margins.grad_from_margins(z_new, w_new)
+        else:
+            w_new, f_new, g_new, ls_ok, t_new, ls_trials = _line_search(
+                value_and_grad, s.w, s.f, direction, dg, l1, orthant,
+                max_line_search_iterations, box=box, g_plain=s.g,
+            )
 
         # a non-finite trial outcome is numerical divergence: the masked
         # commit below keeps the last good iterate (rollback is free), and
@@ -491,6 +600,13 @@ def _solve(
         if owlqn:
             crossed = ((s.w + t_new * direction) * orthant < 0) & improved & ~keep
             zeroed = s.zeroed + jnp.sum(crossed, axis=0, dtype=jnp.int32)
+        z_out = matvecs = rmatvecs = None
+        if walk:
+            z_out = jnp.where(improved & ~keep, z_new, s.z)
+            if counted:
+                # direction_margins and grad_from_margins, once each
+                matvecs = jnp.where(keep, s.matvecs, s.matvecs + 1)
+                rmatvecs = jnp.where(keep, s.rmatvecs, s.rmatvecs + 1)
 
         it_new = s.it + 1
         pg_new = effective_grad(w_new, g_new)
@@ -541,6 +657,9 @@ def _solve(
             grad_norm_history=gh,
             evals=evals,
             zeroed=zeroed,
+            z=z_out,
+            matvecs=matvecs,
+            rmatvecs=rmatvecs,
         )
 
     final = jax.lax.while_loop(cond, body, init)
@@ -557,6 +676,22 @@ def _solve(
         line_search_evals=final.evals,
         orthant_zeroed=final.zeroed,
         nonzeros=jnp.sum(final.w != 0, axis=0, dtype=jnp.int32) if owlqn else None,
+        matvecs=final.matvecs,
+        rmatvecs=final.rmatvecs,
+    )
+
+
+def walks_margins(margins, l1_weight: float, box_constraints, batched: bool) -> bool:
+    """Whether a solve's line search walks margins (``_margin_search``) or
+    evaluates points (``_line_search``). Only plain one-lane L-BFGS can walk:
+    OWL-QN projects each trial point onto an orthant and L-BFGS-B clips it to
+    the box, so their margins are not affine in the step length; the packed
+    lanes keep the search their rounding was tuned on (PERF.md, PR 35)."""
+    return (
+        margins is not None
+        and not float(l1_weight) > 0.0
+        and box_constraints is None
+        and not batched
     )
 
 
@@ -572,6 +707,7 @@ def solve_lbfgs(
     max_line_search_iterations: int = 25,
     batched: bool = False,
     count_evals: bool = False,
+    margins: Optional[MarginFns] = None,
 ) -> SolverResult:
     """Minimize f(w) (+ l1*||w||_1 when ``l1_weight`` > 0) starting at w0.
 
@@ -590,6 +726,12 @@ def solve_lbfgs(
     objective evaluations as an OWL-QN solve always does
     (``SolverResult.line_search_evals``); the default leaves the plain
     program as it was, counter-free (the random effects' packed solves).
+
+    ``margins`` are the same objective as its steps (``MarginFns``: its
+    margins are affine in w). A solve that can walk them does
+    (:func:`walks_margins`) and never calls ``value_and_grad``; any other
+    solve never sees them. A counting walk also reports its passes over the
+    features (``SolverResult.matvecs`` / ``rmatvecs``).
     """
     has_box = box_constraints is not None
     zero = jnp.zeros_like(w0)
@@ -610,6 +752,9 @@ def solve_lbfgs(
         upper,
         batched,
         count_evals,
+        MarginFns(*map(as_partial, margins))
+        if walks_margins(margins, l1_weight, box_constraints, batched)
+        else None,
     )
     obs.record_solver_metrics("lbfgs", result)
     return result

@@ -257,6 +257,70 @@ def test_an_l2_solve_lowers_to_the_program_it_was_before_the_l1_operand():
     assert scalar == "cb2dd8466a55007b5e73f6ace917fe3478136c8f977688a4ea1ff896473560f0"
 
 
+@pytest.mark.parametrize("kind, golden", [
+    ("owlqn", "f16ca238344247a728f9f5e44470df3941321c67ad2b01846edc333f479a1f34"),
+    ("box", "864fbb005d966c5715b2f616cf836613636bde4306e5f10b5bd2e5995f5a8936"),
+    ("counted", "a6be5db6f185cda1daa20af1167357deb4708b2bd9aaf7e1321b938d173f3656"),
+])
+def test_a_points_solve_lowers_to_the_program_it_was_before_the_margin_walk(kind, golden):
+    """Golden hashes recorded on PR 36's commit f182e32, PR 37's parent, under
+    this conftest: the verdict the two searches share (``lbfgs._verdict``)
+    moved no operation of an OWL-QN solve, of an L-BFGS-B solve or of a
+    counting plain solve that is handed no margin functions. With the two
+    hashes of the test above (the packed lanes and the counter-free scalar
+    solve) these are every program of ``_solve`` that the cells which must not
+    move compile."""
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    def run(a, b, w0):
+        vg = lambda w: (0.5 * jnp.sum((a @ w - b) ** 2), a.T @ (a @ w - b))
+        tol = jnp.asarray(1e-6, jnp.float32)
+        kwargs = {"owlqn": dict(l1_weight=0.5), "box": dict(box_constraints=(w0 - 0.25, w0 + 0.5)),
+                  "counted": dict(count_evals=True)}[kind]
+        r = solve_lbfgs(vg, w0, tol, tol, max_iterations=20, **kwargs)
+        return r.coefficients, r.iterations
+
+    assert _lowered_text(jax.jit(run), f32(32, 8), f32(32), f32(8)) == golden
+
+
+@pytest.mark.parametrize("fused, which, golden", [
+    (None, "value_and_grad", "c95a577d8eb1abd9990956eda865c1eef8181c42e8b6f3252f98bc6154b99102"),
+    (None, "hessian_vector", "d6ed461a397a6ff279068382c808dce7a3f2647b3d55e25eef61700d5b86dd2c"),
+    (None, "hessian_diagonal", "a2cbc3d99ee6f28492e1ab4c7fd41b4fdc978efbb6544aaa2132739caea15caf"),
+    ("interpret", "value_and_grad", "ebc166d84da1557159d91fe19f004dc2f8443626a29fd05e515bd04f5072ac25"),
+    ("interpret", "hessian_vector", "4e204271f09727b3910a18f3972dd635c883177df72861aa429c48010e698da8"),
+    ("interpret", "hessian_diagonal", "c1ff393ab597f8ade4af2343e30502a04d5728e8a45140b4705eace283791af9"),
+])
+def test_the_objective_lowers_to_the_programs_it_was_before_it_came_as_steps(fused, which, golden):
+    """Golden hashes recorded on PR 36's commit f182e32, PR 37's parent, under
+    this conftest: ``value_and_grad`` written as ``grad_from_margins(margins(w),
+    w)``, and ``hessian_vector`` over ``direction_margins``, are the parent's
+    operations in the parent's order on the two-pass path (normalization with
+    shifts and a prior with precisions on), and the fused path's three programs
+    are untouched: what TRON and the tolerance pass compile in the cells that
+    must not move."""
+    from photon_ml_tpu.ops import GLMObjective, NormalizationContext, batch_from_dense, get_loss
+    from photon_ml_tpu.ops.glm import hvp_fn, vg_fn
+
+    n, d = 1024, 128
+    rng = np.random.default_rng(0)
+    batch = batch_from_dense(rng.normal(size=(n, d)), (rng.uniform(size=n) < 0.5).astype(float), dtype=jnp.float32)
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    obj = GLMObjective(
+        loss=get_loss("logistic_regression"), batch=batch, l2=0.5,
+        norm=NormalizationContext(factors=f32(rng.uniform(0.5, 2, d)), shifts=f32(rng.normal(size=d))),
+        prior_mean=None if fused else f32(rng.normal(size=d)),
+        prior_precision=None if fused else f32(rng.uniform(0.5, 2, d)), fused=fused)
+    w = jnp.zeros(d, jnp.float32)
+    if which == "value_and_grad":
+        text = _lowered_text(jax.jit(lambda fn, a: fn(a)), vg_fn(obj), w)
+    elif which == "hessian_vector":
+        text = _lowered_text(jax.jit(lambda fn, a, b: fn(a, b)), hvp_fn(obj), w, w + 1)
+    else:
+        text = _lowered_text(jax.jit(lambda o, a: o.hessian_diagonal(a)), obj, w)
+    assert text == golden
+
+
 # -- a non-finite trial value is a failed step --------------------------------------
 
 

@@ -393,7 +393,13 @@ def _l2_logistic_problem(optimizer, max_iterations, tolerance=1e-9):
 # optimum). Over the three iterations both always take they agree to 7e-8,
 # so the two paths are compared there; that each also converges is
 # test_tron_converges_to_the_float64_optimum's to say.
-E2E_ITERATIONS = {"LBFGS": 60, "TRON": 3}
+# L-BFGS likewise since PR 37: the jnp path's search walks margins, the fused
+# path's evaluates points. Over the six iterations both take they agree to
+# 4e-8; the seventh is at the floor (the points search's full step no longer
+# lowers the value and it stops where it stood, the margins search takes two
+# more steps). Where each lands when run to its end is
+# test_lbfgs_converges_to_the_float64_optimum's to say.
+E2E_ITERATIONS = {"LBFGS": 6, "TRON": 3}
 
 
 @pytest.mark.parametrize("optimizer", ["LBFGS", "TRON"])
@@ -424,14 +430,8 @@ def test_end_to_end_solve_matches_unfused(rng, monkeypatch, optimizer):
     )
 
 
-@pytest.mark.parametrize("pallas", ["off", "interpret"])
-def test_tron_converges_to_the_float64_optimum(rng, monkeypatch, pallas):
-    """TRON run to its end in f32, over the jnp path and over the fused
-    kernels: each stops within f32's noise floor of the optimum that a
-    float64 solve of the same data finds (which of its last iterations a
-    path takes does not matter here)."""
-    n = pallas_glm.MIN_FUSED_ROWS
-    b32 = _make_batch(rng, n)
+def _float64_optimum(b32, monkeypatch):
+    """The optimum of ``b32``'s problem: a float64 TRON solve of the same data."""
     b64 = batch_from_dense(
         np.asarray(b32.features.dense, np.float64), np.asarray(b32.labels, np.float64),
         offsets=np.asarray(b32.offsets, np.float64),
@@ -440,11 +440,42 @@ def test_tron_converges_to_the_float64_optimum(rng, monkeypatch, pallas):
     monkeypatch.setenv("PHOTON_PALLAS", "off")
     m64, _ = _l2_logistic_problem("TRON", 60, tolerance=1e-12).run(b64)
     assert m64.coefficients.means.dtype == jnp.float64
+    return np.asarray(m64.coefficients.means)
+
+
+@pytest.mark.parametrize("pallas", ["off", "interpret"])
+def test_tron_converges_to_the_float64_optimum(rng, monkeypatch, pallas):
+    """TRON run to its end in f32, over the jnp path and over the fused
+    kernels: each stops within f32's noise floor of the optimum that a
+    float64 solve of the same data finds (which of its last iterations a
+    path takes does not matter here)."""
+    b32 = _make_batch(rng, pallas_glm.MIN_FUSED_ROWS)
+    w64 = _float64_optimum(b32, monkeypatch)
     monkeypatch.setenv("PHOTON_PALLAS", pallas)
     m32, r32 = _l2_logistic_problem("TRON", 60).run(b32)
     assert int(r32.iterations) < 60
-    w64, w32 = np.asarray(m64.coefficients.means), np.asarray(m32.coefficients.means)
-    assert np.max(np.abs(w32 - w64)) <= 1e-4
+    assert np.max(np.abs(np.asarray(m32.coefficients.means) - w64)) <= 1e-4
+
+
+@pytest.mark.parametrize("pallas", ["off", "interpret"])
+def test_lbfgs_converges_to_the_float64_optimum(rng, monkeypatch, pallas):
+    """L-BFGS run to its end in f32 (cap 60, as the end-to-end test ran it
+    before PR 37): the jnp path, whose search walks margins, and the fused
+    path, whose search evaluates points, each stop within f32's floor of the
+    float64 optimum. Measured (PR 37, max |w32 - w64|; data seeds 0, 1, 2):
+    margins 5.5e-6 after 9 iterations, 1.14e-4 after 7, 1.5e-5 after 8;
+    points 1.92e-4 after 7 (its 7th moves nothing), 1.14e-4 after 7, 1.5e-5
+    after 8. One ulp of the objective (2652.46, 2.4e-4) is worth that much in
+    a coefficient. A solve one iteration short of the points search's last
+    step reads 3.8e-4 (seed 0, five iterations), two short 1.2e-3."""
+    b32 = _make_batch(rng, pallas_glm.MIN_FUSED_ROWS)
+    w64 = _float64_optimum(b32, monkeypatch)
+    monkeypatch.setenv("PHOTON_PALLAS", pallas)
+    m32, r32 = _l2_logistic_problem("LBFGS", 60).run(b32)
+    assert int(r32.iterations) < 60
+    # the search each path ran: only a margin walk counts its own passes
+    assert (r32.matvecs is not None) == (pallas == "off")
+    assert np.max(np.abs(np.asarray(m32.coefficients.means) - w64)) <= 2.7e-4
 
 
 def test_tile_rows_and_eligibility_constants():

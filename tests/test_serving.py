@@ -426,6 +426,18 @@ def test_kill_and_keep_serving_drill(tmp_path, run_telemetry):
 # -- the AF_UNIX front -------------------------------------------------------
 
 
+def _connect_when_listening(client, sock_path, deadline):
+    """``serve_socket`` binds its path, then listens: a client that saw the
+    path appear may still be refused for a moment."""
+    while True:
+        try:
+            return client.connect(sock_path)
+        except ConnectionRefusedError:
+            if time.time() >= deadline:
+                raise
+            time.sleep(0.01)
+
+
 def test_socket_server_roundtrip(tmp_path, run_telemetry):
     model = make_model()
     store_dir = serving.build_store_from_model(model, str(tmp_path / "store"))
@@ -452,7 +464,7 @@ def test_socket_server_roundtrip(tmp_path, run_telemetry):
             "offset": req.offset,
         }
         with socket.socket(socket.AF_UNIX, socket.SOCK_STREAM) as c:
-            c.connect(sock_path)
+            _connect_when_listening(c, sock_path, deadline)
             f = c.makefile("rwb")
             f.write((json.dumps(payload) + "\n").encode())
             f.flush()
@@ -508,7 +520,7 @@ def test_shed_response_carries_trace_id(tmp_path, run_telemetry):
             "trace_id": "shed-trace-9",
         }
         with socket.socket(socket.AF_UNIX, socket.SOCK_STREAM) as c:
-            c.connect(sock_path)
+            _connect_when_listening(c, sock_path, deadline)
             f = c.makefile("rwb")
             f.write((json.dumps(payload) + "\n").encode())
             f.flush()
@@ -667,7 +679,7 @@ def test_cli_serve_store_dir_socket(tmp_path):
             time.sleep(0.01)
         assert os.path.exists(sock_path), "cli.serve never bound its socket"
         with socket.socket(socket.AF_UNIX, socket.SOCK_STREAM) as c:
-            c.connect(sock_path)
+            _connect_when_listening(c, sock_path, deadline)
             f = c.makefile("rwb")
             f.write(b'{"features": {"globalShard": [[0], [1.0]]}}\n')
             f.flush()

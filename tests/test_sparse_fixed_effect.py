@@ -320,3 +320,180 @@ def test_slots_are_what_a_pass_touches(layout, slots):
                      shard_coo={SHARD: (r, c, np.ones(9))}, shard_dims={SHARD: 5}, id_tags={})
     f = raw.to_batch(SHARD, layout=layout).features
     assert isinstance(f, FeatureMatrix) and f.layout == layout and f.slots == slots
+
+
+# -- the search that walks margins (PR 37): who gets it, what it counts -----------------------
+
+
+def _ragged(n, d, seed=37):
+    """An ELL batch of a shape no other test of this file compiles for."""
+    rng = np.random.default_rng(seed)
+    counts = rng.integers(1, 6, n)
+    r = np.repeat(np.arange(n), counts)
+    raw = RawDataset(n_rows=n, labels=(rng.random(n) < 0.4).astype(np.float64), offsets=np.zeros(n),
+                     weights=np.ones(n), shard_coo={SHARD: (r, rng.integers(0, d, len(r)), rng.standard_normal(len(r)))},
+                     shard_dims={SHARD: d}, id_tags={})
+    return raw
+
+
+def test_a_walking_solve_passes_over_the_features_once_an_iteration_each_way(monkeypatch):
+    """The twin of the points path's count above: here the objective's
+    evaluations are not passes. ``matvec`` and ``rmatvec`` count their own
+    executions: one each at the start, one each an iteration, however many
+    step lengths the searches tried."""
+    from photon_ml_tpu.ops.glm import margin_fns, vg_fn
+
+    executed = {"matvec": [], "rmatvec": []}
+    for name in executed:
+        real = getattr(FeatureMatrix, name)
+
+        def counted(self, x, real=real, name=name):
+            jax.debug.callback(lambda name=name: executed[name].append(1))
+            return real(self, x)
+
+        monkeypatch.setattr(FeatureMatrix, name, counted)
+    batch = _ragged(601, 401).to_batch(SHARD, dtype=jnp.float32, layout="ell")
+    obj = GLMObjective(loss=get_loss("logistic_regression"), batch=batch, l2=0.1)
+    tol = jnp.asarray(1e-7, jnp.float32)
+    r = solve_lbfgs(vg_fn(obj), jnp.zeros(batch.dim, jnp.float32), tol, tol, count_evals=True, margins=margin_fns(obj))
+    jax.effects_barrier()
+    assert len(executed["matvec"]) == int(r.matvecs) == int(r.iterations) + 1 > 4
+    assert len(executed["rmatvec"]) == int(r.rmatvecs) == int(r.iterations) + 1
+    assert int(r.line_search_evals) > int(r.iterations) + 1  # trials judged, not passes
+
+
+class _CountingSteps:
+    """``MarginFns`` of a quadratic that note every time one of them is traced."""
+
+    def __init__(self):
+        self.traced = []
+
+    def fns(self):
+        def note(name, out):
+            def fn(*args):
+                self.traced.append(name)
+                return out(*args)
+            return fn
+
+        return (note("margins", lambda w: w), note("direction_margins", lambda p: p),
+                note("value_and_slope", lambda z, u, t, w, p: (0.5 * jnp.sum((z + t * u) ** 2, axis=0),
+                                                                 jnp.sum((z + t * u) * u, axis=0))),
+                note("grad_from_margins", lambda z, w: (0.5 * jnp.sum(z * z, axis=0), z)))
+
+
+@pytest.mark.parametrize("mode", ["plain", "owlqn", "box", "batched"])
+def test_only_a_plain_one_lane_solve_walks_the_margin_functions(mode):
+    """OWL-QN projects its trial points and L-BFGS-B clips them (their margins
+    are not affine in the step length), and the packed lanes keep their search:
+    handed margin functions, such a solve neither traces nor calls them and
+    returns what it returned without them, bit for bit."""
+    shape = (7, 3) if mode == "batched" else (7,)
+    w0 = jnp.asarray(np.random.default_rng(2).normal(size=shape))
+    kwargs = {"owlqn": dict(l1_weight=0.3), "box": dict(box_constraints=(w0 * 0 - 0.2, w0 * 0 + 0.4)),
+              "batched": dict(batched=True)}.get(mode, {})
+    tol = jnp.full(shape[1:], 1e-9)
+    vg = lambda w: (0.5 * jnp.sum(w * w, axis=0), w)  # noqa: E731
+    steps = _CountingSteps()
+    with_steps = solve_lbfgs(vg, w0, tol, tol, margins=steps.fns(), **kwargs)
+    if mode == "plain":
+        assert set(steps.traced) == {"margins", "direction_margins", "value_and_slope", "grad_from_margins"}
+        np.testing.assert_allclose(np.asarray(with_steps.coefficients), 0.0, atol=1e-8)
+        return
+    assert steps.traced == []
+    without = solve_lbfgs(vg, w0, tol, tol, **kwargs)
+    for a, b in zip(jax.tree_util.tree_leaves(with_steps), jax.tree_util.tree_leaves(without)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("case", ["fused", "tron", "owlqn"])
+def test_a_solve_that_cannot_walk_says_points_and_is_handed_no_margin_functions(case, monkeypatch):
+    """A fused objective has no margin steps (``GLMProblem.run`` builds none);
+    TRON has no line search; OWL-QN is handed them and leaves them alone."""
+    from photon_ml_tpu.ops import batch_from_dense, glm, pallas_glm
+    from photon_ml_tpu.optimize import lbfgs
+
+    built = []
+    real = glm.margin_fns
+    monkeypatch.setattr(glm, "margin_fns", lambda obj: built.append(1) or real(obj))
+    walked = []
+    search = lbfgs._margin_search
+    monkeypatch.setattr(lbfgs, "_margin_search", lambda *a, **k: walked.append(1) or search(*a, **k))
+    rng = np.random.default_rng(4)
+    if case == "fused":
+        monkeypatch.setenv("PHOTON_PALLAS", "interpret")
+        n, d = pallas_glm.MIN_FUSED_ROWS, pallas_glm.LANE
+        batch = batch_from_dense(rng.standard_normal((n, d)), (rng.random(n) < 0.4).astype(np.float64), dtype=jnp.float32)
+        config = _config(0.5, max_iterations=3)
+    else:
+        batch = _ragged(602, 402).to_batch(SHARD, dtype=jnp.float32, layout="ell")
+        config = _config(0.5, OptimizerType.TRON if case == "tron" else OptimizerType.LBFGS, max_iterations=5)
+        if case == "owlqn":
+            config = GLMOptimizationConfig(optimizer=config.optimizer, regularization=RegularizationContext("L1"),
+                                           reg_weight=0.5)
+    run, sink = obs.RunTelemetry(), _Spans()
+    run.register_listener(sink)
+    with obs.use_run(run):
+        GLMProblem(task="logistic_regression", config=config).run(batch, coordinate="global")
+    span, = [s for s in sink.spans if s.name == "fe.solve"]
+    # TRON has no line search and its span no such attribute
+    assert span.attrs.get("line_search") == (None if case == "tron" else "points")
+    assert len(built) == (0 if case == "fused" else 1) and not walked
+    # only a solve that walked margins counts passes of its own
+    assert not _series(run.registry, "photon_fe_feature_passes_total")
+
+
+def _ell_estimator():
+    return GameEstimator(
+        task="logistic_regression",
+        # under this penalty some search tries a second length (0.5: none does)
+        coordinate_configs=[CoordinateConfig(name="global", feature_shard=SHARD, config=_config(0.1), layout="ell")],
+        n_cd_iterations=1, dtype=jnp.float32)
+
+
+def test_a_fit_over_an_ell_shard_compiles_one_solver_and_walks_margins():
+    """What the benchmark's sparse cell requires of a first fit
+    (``benchmark/jobs/fit_sparse.py`` ``solver_programs``), and what a sink
+    sees of the walk: ``line_search=margins`` on ``fe.solve``, iterations + 1
+    passes of each kind, riding the one fetch a sink already made."""
+    import logging
+
+    from photon_ml_tpu.optimize import lbfgs
+
+    raw = _ragged(603, 403)
+    est = _ell_estimator()
+    datasets = est.prepare_datasets(raw)
+    programs = lbfgs._solve._cache_size()
+    first, = est.fit(None, datasets=datasets)
+    assert lbfgs._solve._cache_size() == programs + 1
+    run, sink = obs.RunTelemetry(), _Spans()
+    run.register_listener(sink)
+    logger = logging.getLogger("photon_ml_tpu")
+    level = logger.level
+    logger.setLevel(logging.WARNING)  # the INFO summary fetches on its own account
+    try:
+        with obs.use_run(run):
+            second, = _ell_estimator().fit(None, datasets=datasets)
+        quiet = obs.RunTelemetry()
+        with obs.use_run(quiet):
+            _ell_estimator().fit(None, datasets=datasets)
+    finally:
+        logger.setLevel(level)
+    assert lbfgs._solve._cache_size() == programs + 1
+    a, b = first.trackers["global"].result, second.trackers["global"].result
+    assert (int(a.iterations), int(a.line_search_evals), float(a.loss)) == (
+        int(b.iterations), int(b.line_search_evals), float(b.loss))
+    span, = [s for s in sink.spans if s.name == "fe.solve"]
+    assert span.attrs["line_search"] == "margins" and span.attrs["layout"] == "ell"
+    assert span.attrs["line_search_evals"] == int(b.line_search_evals)
+    passes = {m["labels"]["kind"]: m["value"]
+              for m in _series(run.registry, "photon_fe_feature_passes_total", coordinate="global")}
+    assert passes == {"matvec": int(b.iterations) + 1, "rmatvec": int(b.iterations) + 1}
+    assert int(b.matvecs) == int(b.rmatvecs) == int(b.iterations) + 1 < int(b.line_search_evals)
+    # one fetch for the solve's counts, two int32 wider than it was; none with no sink
+    fetches = [s for s in sink.spans if s.name == "fetch" and s.attrs["site"] == "solver.lbfgs"]
+    history = np.asarray(b.grad_norm_history).nbytes
+    assert len(fetches) == 1 and fetches[0].attrs["bytes"] == history + 5 * 4
+    assert not _series(quiet.registry, "photon_device_fetch_bytes_total", site="solver.lbfgs")
+    assert not _series(quiet.registry, "photon_fe_feature_passes_total")
+    sites = lambda reg: {m["labels"]["site"] for m in _series(reg, "photon_device_fetch_bytes_total")}  # noqa: E731
+    assert sites(quiet.registry) == sites(run.registry) - {"solver.lbfgs", "tracker_metrics", "tracker_aggregates"}

@@ -149,14 +149,19 @@ def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_g
     over ``[n, k]`` intermediates the compiler padded each to 128 lanes (1.2 GB,
     three alive at once: 14.2 GB of temporaries, no room on a 16 GB chip); over
     ``[k, n]`` (the row axis minor, as the arrays lie on the device) nothing is
-    padded and the solve holds 10.66 GB in all (9.67 GB of it temporaries), as over sorted COO (10.47 GB)."""
+    padded and the solve held 10.66 GB in all (9.67 GB of it temporaries), as over sorted COO (10.47 GB).
+    Since PR 37 the solve walks margins (what ``GLMProblem.run`` hands a two-pass
+    objective's L-BFGS): 8.78 GB of temporaries under either layout, the search's
+    loop carrying scalars; the objective comes as an argument once for every
+    margin step that reads it (the same buffers, counted each time: 10.39 /
+    10.50 GB as counted here)."""
     from jax.sharding import SingleDeviceSharding
 
     from photon_ml_tpu.ops.features import FeatureMatrix, LabeledBatch
-    from photon_ml_tpu.ops.glm import GLMObjective, vg_fn
+    from photon_ml_tpu.ops.glm import GLMObjective, margin_fns, vg_fn
     from photon_ml_tpu.ops.losses import get_loss
     from photon_ml_tpu.optimize import lbfgs
-    from photon_ml_tpu.optimize.common import as_partial
+    from photon_ml_tpu.optimize.common import MarginFns, as_partial
 
     n, k, d = 2_359_296, 12, 54_686_453
     one = SingleDeviceSharding(topo.devices[0])
@@ -170,7 +175,7 @@ def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_g
     objective = GLMObjective(loss=get_loss("logistic_regression"), batch=batch, l2=1000.0)
     compiled = lbfgs._solve.lower(
         as_partial(vg_fn(objective)), s((d,)), s(()), s(()), 100, 10, None, 25, False, s((d,)), s((d,)),
-        False, True,
+        False, True, MarginFns(*margin_fns(objective)),
     ).compile()
     memory = compiled.memory_analysis()
     total = memory.temp_size_in_bytes + memory.argument_size_in_bytes + memory.output_size_in_bytes
