@@ -377,7 +377,9 @@ def phase_train(paths: dict, index_dir: str, workdir: str) -> dict:
     blocks = datasets["per-user"].blocks
     check(blocks.features.shape[0] == N_USERS and blocks.features.shape[2] == D_RE_NAMED + 1,
           f"entity blocks {blocks.features.shape}")
-    placed = {"batch": batch.features.dense, "blocks": blocks.features,
+    # the entity blocks are stored one array a size bucket (BucketedArray)
+    placed = {"batch": batch.features.dense,
+              **{f"blocks[{b}]": part for b, part in enumerate(blocks.features.parts)},
               **model_arrays(game_model)}
     for name, arr in placed.items():
         check(isinstance(arr, jax.Array), f"{name} is {type(arr).__name__}, not on device")

@@ -17,7 +17,7 @@ import dataclasses
 import time
 import weakref
 from functools import partial
-from typing import Optional, Tuple, Union
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +34,15 @@ from ..ops.normalization import NormalizationContext
 from ..optimize import OptimizerType, SolverResult, solve_lbfgs, solve_tron
 from ..optimize.common import abs_tolerances
 from ..robust import faults
-from .data import FixedEffectDataset, RandomEffectDataset
+from .data import (
+    EntityBlocks,
+    FixedEffectDataset,
+    RandomEffectDataset,
+    _chunk_rows,
+    bucket_blocks,
+    record_block_store,
+    size_buckets,
+)
 from .problem import GLMOptimizationConfig, GLMProblem
 from .sampling import down_sample
 
@@ -87,7 +95,8 @@ class FixedEffectCoordinate(Coordinate):
         if self.dataset.streamed:
             return self._train_streamed(residual_scores, initial_model)
         batch = self.dataset.batch
-        if residual_scores is not None:
+        residuals = residual_scores is not None
+        if residuals:
             # residual scores live in true sample space; padded batch rows
             # (mesh row multiples) carry zero residual
             n_pad = batch.n_rows - residual_scores.shape[0]
@@ -133,6 +142,7 @@ class FixedEffectCoordinate(Coordinate):
             initial_model=initial_model.model if initial_model else None,
             coordinate=self.coordinate_id,
             nnz=nnz,
+            residuals=residuals,
         )
         if jax.process_count() > 1:
             # tiled solves leave coefficients model-axis-sharded across
@@ -393,32 +403,30 @@ class RandomEffectCoordinate(Coordinate):
     ) -> Tuple[RandomEffectModel, SolverResult]:
         if self.dataset.streamed:
             return self._train_streamed(residual_scores, initial_model)
-        blocks = self.dataset.blocks
-        E, K, S = blocks.features.shape
-        # solver state stays in the WIDE dtype: features may be stored bf16
-        # (feature_dtype), labels/weights/offsets carry the solve precision
-        dtype = blocks.labels.dtype
-
         # Size-bucketed solves: each of the dataset's chunks is sorted by
         # descending row count and dealt the same size profile, so a (K, S)-
         # rounded bucket is the SAME local row range of every chunk; solving
         # per bucket avoids every small entity paying the padding of the
         # largest (RandomEffectDatasetPartitioner's size-awareness, re-purposed
         # for vmap lane economy), and under a mesh every chip holds an equal
-        # share of every bucket. No buckets: one whole-block solve, on the
-        # arrays as they stand (a full-range slice would copy).
-        segments = _size_buckets(self.dataset)
+        # share of every bucket. The blocks are STORED at these shapes
+        # (game/data.py): a bucket's arrays go to the solver whole. No
+        # buckets: one whole-extent bucket.
+        blocks, buckets = _bucketed_blocks(self.dataset)
+        E, K, S = blocks.features.shape
+        # solver state stays in the WIDE dtype: features may be stored bf16
+        # (feature_dtype), labels/weights/offsets carry the solve precision
+        dtype = blocks.labels.dtype
         chunks = self.dataset.entity_chunks
         sharded = _chunk_axis(blocks.features, chunks)
-        buckets = tuple(segments or [(0, E // chunks, K, S)])
         exchange = partial(
-            _bucket_offsets, blocks.active_rows, blocks.offsets,
-            segments=buckets, chunks=chunks, sharded=sharded,
+            _bucket_offsets, blocks.active_rows.parts, blocks.offsets.parts,
+            chunks=chunks, sharded=sharded,
         )
         if residual_scores is not None:
             # the residual exchange: every slot a bucket will solve gathers
             # its row's residual (the other coordinates' summed scores);
-            # block_slots is the [E, K] plane the buckets are cut from
+            # block_slots is the logical [E, K] extent the buckets lie in
             with obs.span(
                 "re.exchange", coordinate=self.coordinate_id, entities=E,
                 slots=sum(chunks * (end - start) * kb for start, end, kb, _ in buckets),
@@ -443,8 +451,8 @@ class RandomEffectCoordinate(Coordinate):
         # w0/priors: multi-process passes host numpy (every process holds the
         # full array; jit treats numpy inputs as replicated contributions).
         # Single-process on an ACCELERATOR creates the default zeros/ones ON
-        # DEVICE — three host [E, S] uploads per train call (~7 MB at bench
-        # shapes) would otherwise ride the host->device link every sweep. On
+        # DEVICE — host [E, S] uploads per train call would otherwise ride
+        # the host->device link every sweep. On
         # the CPU backend host numpy is kept: the transfer is a memcpy, and
         # device-created inputs to the sharded-blocks pjit tickled an XLA:CPU
         # compiler segfault under long test sessions (observed at
@@ -459,9 +467,11 @@ class RandomEffectCoordinate(Coordinate):
         else:
             xp, xdt = jnp, dtype
             to_host = lambda a: a  # noqa: E731 — single decision point
-        # the solver's [E, S] state: a warm start and a prior come through
-        # the model projection, whose layout fetches block (the span is
-        # their parent)
+        # the solver's state: a warm start and a prior come as [E, S] tables
+        # through the model projection, whose layout fetches block (the span
+        # is their parent); the defaults (zeros, ones) are made at each
+        # bucket's own shape below and never as tables
+        w0 = prior_mean = prior_prec = None
         with obs.span(
             "re.warm_start", coordinate=self.coordinate_id,
             warm=initial_model is not None, priors=self.prior_model is not None,
@@ -470,11 +480,6 @@ class RandomEffectCoordinate(Coordinate):
                 w0 = to_host(
                     _initial_subspace_coefficients(self.dataset, initial_model, dtype)
                 )
-            else:
-                w0 = xp.zeros((E, S), xdt)
-
-            prior_mean = xp.zeros((E, S), xdt)
-            prior_prec = xp.ones((E, S), xdt)
             if self.prior_model is not None:
                 prior_mean = to_host(
                     _project_model_values(
@@ -489,80 +494,47 @@ class RandomEffectCoordinate(Coordinate):
             sp.sync(w0, prior_mean, prior_prec)
 
         solver_kwargs = self._solver_kwargs()
-        counts = self.dataset.entity_counts
-        if counts is not None:
-            chunk_counts = np.asarray(counts).reshape(chunks, -1)
-        real_slots = padded_slots = 0
+        defaults = _default_state(self.dataset, blocks, xp, xdt)
+        # a bucket's shape and its real rows and cells are the data set's,
+        # reckoned once: a train call makes no pass over the statistics
+        accounts = _bucket_accounts(self.dataset, blocks)
         parts = []
-        for (start, end, kb, sb), offsets in zip(buckets, bucket_offsets):
-            entities = chunks * (end - start)
-            slots = entities * kb
-            shape = dict(
-                coordinate=self.coordinate_id,
-                k=kb, s=sb, entities=entities, slots=slots, chunks=chunks,
-            )
-            if counts is not None:
-                chunk_real = chunk_counts[:, start:end].sum(axis=1)
-                shape["real_rows"] = int(chunk_real.sum())
-                # the chips' balance: real_rows over chunks * this
-                shape["max_chunk_real_rows"] = int(chunk_real.max())
-                real_slots += shape["real_rows"]
-                padded_slots += slots - shape["real_rows"]
-            with obs.span("re.bucket", **shape) as sp:
-                if segments is None:
-                    part = _train_blocks_packed(
-                        blocks.features, blocks.labels, offsets, blocks.weights,
-                        w0, prior_mean, prior_prec, **solver_kwargs,
+        for b, ((start, end, kb, sb), offsets) in enumerate(zip(buckets, bucket_offsets)):
+            shape = accounts.buckets[b]
+            with obs.span("re.bucket", coordinate=self.coordinate_id, **shape) as sp:
+                # with a sink the bucket says how much of its enqueue is the
+                # cut of the [E, S] state tables a warm start or a prior
+                # brought (the blocks come cut: they are stored so): the rest
+                # of enqueue_s is the solve's dispatch
+                # photon: ignore[R7] — an attribute of the bucket's own
+                # span (a child span a bucket would be one more event)
+                cut_start = time.perf_counter() if obs.active() else None
+                zeros, ones = defaults[b]
+                state = tuple(
+                    default if table is None
+                    else _state_rows(table, chunks, sharded, start, end, sb)
+                    for table, default in (
+                        (w0, zeros), (prior_mean, zeros), (prior_prec, ones)
                     )
-                else:
-                    # with a sink the bucket says how much of its enqueue is
-                    # the cut: the rest of enqueue_s is the solve's dispatch
-                    # photon: ignore[R7] — an attribute of the bucket's own
-                    # span (a child span a bucket would be one more event)
-                    cut_start = time.perf_counter() if obs.active() else None
-                    operands = _bucket_operands(
-                        (blocks.features, blocks.labels, blocks.weights),
-                        offsets, (w0, prior_mean, prior_prec),
-                        chunks, sharded, start, end, kb, sb,
-                    )
-                    if cut_start is not None:
-                        # photon: ignore[R7] — closes the stamp above
-                        sp.attrs["cut_s"] = time.perf_counter() - cut_start
-                    part = _train_blocks_packed(*operands, **solver_kwargs)
+                )
+                if cut_start is not None:
+                    # photon: ignore[R7] — closes the stamp above
+                    sp.attrs["cut_s"] = time.perf_counter() - cut_start
+                part = _train_blocks_packed(
+                    blocks.features.parts[b], blocks.labels.parts[b], offsets,
+                    blocks.weights.parts[b], *state, **solver_kwargs,
+                )
                 sp.sync(part)
             if obs.active() and not multiproc:
                 # (across processes a bucket's lanes are not all addressable
                 # from here; the trackers count them after the collect)
                 self._record_lane_iterations(part)
             parts.append(part)
-        if counts is not None:
-            slot_counter = obs.current_run().registry.counter(
-                "photon_re_block_slots_total",
-                "entity-block row slots handed to the random-effect solver, "
-                "real rows against bucket padding",
-            )
-            slot_counter.labels(coordinate=self.coordinate_id, kind="real").inc(real_slots)
-            slot_counter.labels(coordinate=self.coordinate_id, kind="padded").inc(
-                padded_slots
-            )
-            # host-known from the dataset: the rows this coordinate trains on
-            # (the buckets' real slots) against the rows over the active cap,
-            # which it only scores
-            row_counter = obs.current_run().registry.counter(
-                "photon_re_rows_total",
-                "rows of a random-effect coordinate per train call: active "
-                "(in an entity block) against passive (scored, never trained)",
-            )
-            row_counter.labels(coordinate=self.coordinate_id, kind="active").inc(
-                real_slots
-            )
-            row_counter.labels(coordinate=self.coordinate_id, kind="passive").inc(
-                len(self.dataset.passive_rows)
-            )
+        _record_accounts(self.coordinate_id, accounts)
         with obs.span("re.collect", coordinate=self.coordinate_id) as sp:
             results = (
                 parts[0]
-                if segments is None
+                if len(parts) == 1 and parts[0].coefficients.shape == (E, S)
                 else _concat_results(parts, S, chunks, sharded)
             )
             if multiproc:
@@ -658,7 +630,10 @@ class RandomEffectCoordinate(Coordinate):
             raise ValueError(
                 "regularize-by-prior is not supported with trial-lanes"
             )
-        blocks = self.dataset.blocks
+        # one full-shape solve over the logical planes, assembled from the
+        # store once a dataset (ROADMAP.md Design: the lane-stacked solve has
+        # no bucketed form yet)
+        blocks = _plane_blocks(self.dataset)
         E, K, S = blocks.features.shape
         dtype = blocks.labels.dtype
         L = residual_lanes.shape[1]
@@ -708,21 +683,20 @@ class RandomEffectCoordinate(Coordinate):
 
     def score_lanes(self, coef_values: Array) -> Array:
         """Per-sample scores [n, L] of lane-stacked per-entity coefficients
-        [E, S, L], reusing the densified-subspace cache of the sequential
-        scoring hot path (one row gather + fused dot for all L lanes)."""
-        from ..models.game import ell_row_subspace, score_entity_rows_dense_lanes
+        [E, S, L], reusing the score cache of the sequential scoring hot path
+        (``_score_cache``: in either form one gather + a dot for all L lanes)."""
+        from ..models.game import (
+            score_entity_ell_at_lanes,
+            score_entity_rows_dense_lanes,
+        )
 
         ds = self.dataset
-        row_entity = ds.row_entity
-        cache = getattr(ds, "_score_xsub_cache", None)
-        if cache is None:
-            cache = ell_row_subspace(
-                ds.blocks.proj_cols, row_entity, ds.ell_idx, ds.ell_val
-            )
-            object.__setattr__(ds, "_score_xsub_cache", cache)
+        form, cache = _score_cache(ds)
         score_dt = jnp.promote_types(ds.ell_val.dtype, ds.blocks.labels.dtype)
         vals = jnp.asarray(coef_values, score_dt)
-        return score_entity_rows_dense_lanes(vals, row_entity, cache)
+        if form == "subspace":
+            return score_entity_rows_dense_lanes(vals, ds.row_entity, cache)
+        return score_entity_ell_at_lanes(vals, ds.row_entity, *cache, ds.ell_val)
 
     def _train_streamed(
         self,
@@ -913,42 +887,50 @@ class RandomEffectCoordinate(Coordinate):
                 )
             return scores
         with obs.span("re.score", coordinate=self.coordinate_id) as sp:
-            scores = self._score_resident(model)
+            scores = self._score_resident(model, sp)
             sp.sync(scores)
         return scores
 
-    def _score_resident(self, model: RandomEffectModel) -> Array:
+    def _score_resident(self, model: RandomEffectModel, sp=None) -> Array:
         row_entity = self.dataset.row_entity
         # The model's entity-row order may differ from this dataset's block
         # order (warm start from a loaded model, locked partial-retrain
         # models): remap dataset block rows -> model rows by entity id.
         # Device-side gather: works when row_entity is sharded across
         # processes (multi-process) as well as single-host.
-        ds_ids = list(map(str, self.dataset.entity_ids))
-        m_ids = list(map(str, model.entity_ids))
-        if ds_ids == m_ids and self._support_layout_matches(model):
+        # (identity first: a model this coordinate trained carries the
+        # dataset's own id array, and two str() lists of every entity a score
+        # are host time that grows with E)
+        same_ids = model.entity_ids is self.dataset.entity_ids
+        if not same_ids:
+            ds_ids = list(map(str, self.dataset.entity_ids))
+            m_ids = list(map(str, model.entity_ids))
+            same_ids = ds_ids == m_ids
+        if same_ids and self._support_layout_matches(model):
             # coordinate-descent hot path: the support LAYOUT is this
-            # dataset's own block layout, so the row features are densified
-            # into entity-subspace layout once and cached; each sweep's score
-            # is then one contiguous row gather + elementwise dot
-            # (models/game.py score_entity_rows_dense)
-            from ..models.game import ell_row_subspace, score_entity_rows_dense
+            # dataset's own block layout, so where each row's features land
+            # in its entity's subspace is resolved once and cached
+            # (``_score_cache``); each sweep's score is then one gather and
+            # a dot
+            from ..models.game import score_entity_ell_at, score_entity_rows_dense
 
-            cache = getattr(self.dataset, "_score_xsub_cache", None)
-            if cache is None:
-                cache = ell_row_subspace(
-                    model.coef_indices, row_entity,
-                    self.dataset.ell_idx, self.dataset.ell_val,
-                )
-                object.__setattr__(self.dataset, "_score_xsub_cache", cache)
+            form, cache = _score_cache(self.dataset)
+            if sp is not None:
+                sp.attrs["form"] = form
             # scores compute in the WIDE dtype: bf16 feature storage must not
             # truncate the coefficients or the residual stream
             score_dt = jnp.promote_types(
                 self.dataset.ell_val.dtype, self.dataset.blocks.labels.dtype
             )
             vals = jnp.asarray(model.coef_values, score_dt)
-            return score_entity_rows_dense(vals, row_entity, cache)
-        if ds_ids != m_ids:
+            if form == "subspace":
+                return score_entity_rows_dense(vals, row_entity, cache)
+            return score_entity_ell_at(
+                vals, row_entity, *cache, self.dataset.ell_val
+            )
+        if sp is not None:
+            sp.attrs["form"] = "searched"
+        if not same_ids:
             block_to_model = model.rows_for(self.dataset.entity_ids).astype(np.int32)
             row_entity = jnp.where(
                 row_entity >= 0,
@@ -965,71 +947,236 @@ class RandomEffectCoordinate(Coordinate):
         return model.score_ell_rows(row_entity, self.dataset.ell_idx, self.dataset.ell_val)
 
 
-def _pow2_ceil(x: np.ndarray) -> np.ndarray:
-    """Exact elementwise 2**ceil(log2(max(x, 1))) for int64 inputs < 2^53
-    (frexp exponents of exactly-represented ints are bit_lengths)."""
-    v = np.maximum(np.asarray(x, dtype=np.int64), 1) - 1
-    return np.int64(1) << np.frexp(v.astype(np.float64))[1].astype(np.int64)
-
-
 def _size_buckets(
     dataset: RandomEffectDataset,
     min_dim: int = 8,
     align: int = 1,
 ):
-    """Chunk-local entity segments with power-of-2-rounded (K, S) block shapes.
-
-    Returns [(start, end, K_b, S_b)], or None when per-entity stats are
-    unavailable or bucketing cannot shrink anything. ``start``/``end`` are
-    rows of ONE chunk: the dataset's block rows are ``entity_chunks`` equal
-    chunks, each size-sorted descending and dealt the same size profile
-    (``_entity_plan``), and a bucket is rows [start, end) of EVERY chunk. One
-    set of bounds serves all chunks: the row count at a local position is
-    taken as the largest over the chunks, so an entity a chunk reaches one
-    position early fits the larger K of the bucket before it. With one chunk
-    the segments are plain block-row ranges. Rounding to powers of two (floored
-    at ``min_dim``) bounds the number of distinct compiled solver shapes at
-    O(log^2) while removing the bulk of the padding FLOPs.
-
-    Fully vectorized (no per-entity Python work — this runs on every train()
-    call, potentially over millions of entities). ``align`` (the per-device
-    entity chunk) is accepted for the benchmark's re_pad_share reader, which
-    passes it, and changes nothing: a bucket takes the same rows of every
-    chunk, so no slice splits a device shard.
-    """
+    """The dataset's size buckets ``[(start, end, K_b, S_b)]`` (game/data.py
+    ``size_buckets``, from its per-entity stats), or None when per-entity
+    stats are unavailable or bucketing cannot shrink anything: the shapes the
+    entity blocks are stored at and each bucket's solve runs at. The stats
+    cover ALL block rows (streamed + sharded blocks hold one host's range of
+    them). ``align`` (the per-device entity chunk) is accepted for the
+    benchmark's re_pad_share reader, which passes it, and changes nothing: a
+    bucket takes the same rows of every chunk, so no part splits a device
+    shard."""
     del align
     counts = dataset.entity_counts
     svec = dataset.entity_subspace_dims
-    if counts is None or svec is None or len(counts) == 0:
+    if counts is None or svec is None:
         return None
     _, K, S = dataset.blocks.features.shape
-    chunks = dataset.entity_chunks
-    # the stats cover ALL block rows (streamed + sharded blocks hold one
-    # host's range of them); per local position, the largest over the chunks
-    counts = np.asarray(counts, dtype=np.int64).reshape(chunks, -1).max(axis=0)
-    sv = np.asarray(svec, dtype=np.int64).reshape(chunks, -1).max(axis=0)
-    chunk_rows = len(counts)
+    return size_buckets(counts, svec, K, S, dataset.entity_chunks, min_dim)
 
-    kb_of = np.minimum(np.maximum(_pow2_ceil(counts), min_dim), K)
-    bounds = np.flatnonzero(np.diff(kb_of)) + 1  # starts of new equal-K runs
-    starts = np.concatenate([[0], bounds])
-    ends = np.concatenate([bounds, [chunk_rows]])
 
-    sb_of = np.minimum(
-        np.maximum(_pow2_ceil(np.maximum.reduceat(sv, starts)), min_dim), S
-    )
-    segments = [
-        (
-            int(s),
-            int(e),
-            int(kb_of[s]),  # counts non-increasing => max K of the segment
-            int(sb),
+def _bucketed_blocks(dataset: RandomEffectDataset):
+    """(the resident dataset's entity blocks stored by bucket, the buckets).
+    The single-process build stores them so. Blocks that arrive as planes
+    (hand-assembled; the multi-process build, game/data_mp.py) are cut once,
+    here, each bucket's five arrays in one program (per device under a mesh),
+    and kept on the dataset beside the plane (ROADMAP.md Design)."""
+    blocks = dataset.blocks
+    if blocks.bucketed:
+        return blocks, blocks.features.segments
+    cached = getattr(dataset, "_bucketed_blocks_cache", None)
+    if cached is None:
+        E, K, S = blocks.features.shape
+        chunks = dataset.entity_chunks
+        segments = tuple(_size_buckets(dataset) or [(0, E // chunks, K, S)])
+        cut = None
+        if chunks > 1 and not isinstance(blocks.features, np.ndarray):
+            sharded = _chunk_axis(blocks.features, chunks)
+            cut = lambda planes, start, end, dims: _chunk_rows_of(  # noqa: E731
+                planes, chunks=chunks, start=start, end=end, dims=dims, sharded=sharded
+            )
+        bucketed = bucket_blocks(blocks, segments, chunks, cut)
+        cached = (bucketed, segments)
+        object.__setattr__(dataset, "_bucketed_blocks_cache", cached)
+    return cached
+
+
+def _score_cache(dataset: RandomEffectDataset):
+    """(form, cached operand) of the random-effect score under the dataset's
+    own support layout, resolved once a dataset. The two forms compute the
+    same sums; which one is what the shapes say, nothing else:
+
+    - ``subspace``: every row's features densified into its entity's
+      subspace, ``x_sub f[n, S]`` (models/game.py ``ell_row_subspace``), the
+      score one contiguous row gather of the ``[E, S]`` table and a dot.
+      Taken when S <= F (a dense shard: every entity's subspace is the
+      shard, and x_sub is no larger than the rows' own slots).
+    - ``slots``: where each of the row's F slots lands, ``(pos, hit)
+      [n, F]`` (``ell_slot_positions``: no ``[n, S]`` array on the way
+      either), the score a gather of the table at (entity, pos) pairs. Taken
+      when S > F (ragged subspaces over a sparse shard: x_sub would be S / F
+      times the data, most of it zeros under the widest entity's width)."""
+    cache = getattr(dataset, "_score_form_cache", None)
+    if cache is None:
+        from ..models.game import ell_row_subspace, ell_slot_positions
+
+        proj_cols = dataset.blocks.proj_cols
+        if proj_cols.shape[1] <= dataset.ell_idx.shape[1]:
+            cache = ("subspace", ell_row_subspace(
+                proj_cols, dataset.row_entity, dataset.ell_idx, dataset.ell_val
+            ))
+        else:
+            cache = ("slots", ell_slot_positions(
+                proj_cols, dataset.row_entity, dataset.ell_idx
+            ))
+        object.__setattr__(dataset, "_score_form_cache", cache)
+    return cache
+
+
+def _plane_blocks(dataset: RandomEffectDataset) -> EntityBlocks:
+    """The dataset's entity blocks as logical ``[E, K(, S)]`` planes,
+    assembled from the store on demand and kept on the dataset: for the
+    trial-lanes solve alone, which runs one full-shape program."""
+    blocks = dataset.blocks
+    if not blocks.bucketed:
+        return blocks
+    cached = getattr(dataset, "_plane_blocks_cache", None)
+    if cached is None:
+        cached = EntityBlocks(
+            features=blocks.features.plane(), labels=blocks.labels.plane(),
+            offsets=blocks.offsets.plane(), weights=blocks.weights.plane(),
+            proj_cols=blocks.proj_cols, active_rows=blocks.active_rows.plane(),
         )
-        for s, e, sb in zip(starts, ends, sb_of)
-    ]
-    if len(segments) == 1 and segments[0][2] >= K and segments[0][3] >= S:
-        return None
-    return segments
+        object.__setattr__(dataset, "_plane_blocks_cache", cached)
+    return cached
+
+
+class _BucketAccounts(NamedTuple):
+    """What a train call reports of its buckets, host-known from the data
+    set: each bucket's ``re.bucket`` attributes, the call's totals for the
+    slot, row and cell counters (None with no per-entity statistics) and the
+    store's bytes for its gauge."""
+
+    buckets: Tuple[dict, ...]
+    totals: Optional[dict]
+    store_bytes: int
+
+
+def _bucket_accounts(dataset: RandomEffectDataset, blocks: EntityBlocks) -> _BucketAccounts:
+    """``_BucketAccounts`` of the dataset's stored buckets, made once a
+    dataset (the statistics are immutable)."""
+    cached = getattr(dataset, "_bucket_accounts_cache", None)
+    if cached is not None:
+        return cached
+    chunks = dataset.entity_chunks
+    counts, sdims = dataset.entity_counts, dataset.entity_subspace_dims
+    S = blocks.features.shape[2]
+    if counts is not None:
+        chunk_counts = np.asarray(counts).reshape(chunks, -1)
+        # an entity's real feature cells: its rows times its own subspace
+        chunk_cells = chunk_counts * (
+            np.asarray(sdims).reshape(chunks, -1) if sdims is not None else S
+        )
+    shapes = []
+    for start, end, kb, sb in blocks.features.segments:
+        entities = chunks * (end - start)
+        shape = dict(
+            k=kb, s=sb, entities=entities, slots=entities * kb, chunks=chunks,
+            cells=entities * kb * sb,
+        )
+        if counts is not None:
+            chunk_real = chunk_counts[:, start:end].sum(axis=1)
+            shape["real_rows"] = int(chunk_real.sum())
+            # the chips' balance: real_rows over chunks * this
+            shape["max_chunk_real_rows"] = int(chunk_real.max())
+            shape["real_cells"] = int(chunk_cells[:, start:end].sum())
+        shapes.append(shape)
+    totals = None
+    if counts is not None:
+        totals = {
+            key: sum(shape[key] for shape in shapes)
+            for key in ("slots", "cells", "real_rows", "real_cells")
+        }
+        totals["passive_rows"] = len(dataset.passive_rows)
+    cached = _BucketAccounts(tuple(shapes), totals, blocks.store_bytes)
+    object.__setattr__(dataset, "_bucket_accounts_cache", cached)
+    return cached
+
+
+def _record_accounts(coordinate_id: str, accounts: _BucketAccounts) -> None:
+    """A resident train call's counters, and the store's gauge again (the
+    build set it: a registry attached after the build reads it too)."""
+    record_block_store(coordinate_id, accounts.store_bytes)
+    totals = accounts.totals
+    if totals is None:
+        return
+    registry = obs.current_run().registry
+    slot_counter = registry.counter(
+        "photon_re_block_slots_total",
+        "entity-block row slots handed to the random-effect solver, "
+        "real rows against bucket padding",
+    )
+    slot_counter.labels(coordinate=coordinate_id, kind="real").inc(totals["real_rows"])
+    slot_counter.labels(coordinate=coordinate_id, kind="padded").inc(
+        totals["slots"] - totals["real_rows"]
+    )
+    # host-known from the dataset: the rows this coordinate trains on (the
+    # buckets' real slots) against the rows over the active cap, which it
+    # only scores
+    row_counter = registry.counter(
+        "photon_re_rows_total",
+        "rows of a random-effect coordinate per train call: active "
+        "(in an entity block) against passive (scored, never trained)",
+    )
+    row_counter.labels(coordinate=coordinate_id, kind="active").inc(totals["real_rows"])
+    row_counter.labels(coordinate=coordinate_id, kind="passive").inc(totals["passive_rows"])
+    # the feature cells the buckets hold against the cells the entities' own
+    # rows x subspaces fill: what the S rounding and the widest entity of a
+    # bucket cost, beside the row padding
+    cell_counter = registry.counter(
+        "photon_re_subspace_cells_total",
+        "entity-block feature cells handed to the random-effect solver, "
+        "real (an entity's rows x its own subspace) against bucket padding",
+    )
+    cell_counter.labels(coordinate=coordinate_id, kind="real").inc(totals["real_cells"])
+    cell_counter.labels(coordinate=coordinate_id, kind="padded").inc(
+        totals["cells"] - totals["real_cells"]
+    )
+
+
+def _default_state(dataset: RandomEffectDataset, blocks: EntityBlocks, xp, xdt):
+    """Per bucket, the solver's default state at the bucket's own shape:
+    (zeros, ones) ``[chunks * (end - start), S_b]`` for a cold start and a
+    plain-L2 prior. Made once a dataset (immutable: the solve donates
+    nothing) and placed as the bucket's blocks are, so a sharded solve takes
+    them on the chips that hold its rows; no ``[E, S]`` table of defaults
+    exists."""
+    key = (xp.__name__, str(xdt))
+    cache = getattr(dataset, "_default_state_cache", None)
+    if cache is None:
+        cache = {}
+        object.__setattr__(dataset, "_default_state_cache", cache)
+    if key not in cache:
+        sharded = _chunk_axis(blocks.features, dataset.entity_chunks)
+        place = {}
+        if xp is jnp and sharded is not None:
+            mesh, axis = sharded
+            place = dict(device=NamedSharding(mesh, PartitionSpec(axis)))
+        cache[key] = tuple(
+            (
+                xp.zeros((part.shape[0], sb), xdt, **place),
+                xp.ones((part.shape[0], sb), xdt, **place),
+            )
+            for part, (_, _, _, sb) in zip(blocks.features.parts, blocks.features.segments)
+        )
+    return cache[key]
+
+
+def _state_rows(table, chunks: int, sharded, start: int, end: int, sb: int):
+    """A bucket's rows of an ``[E, S]`` state table (a warm start, a prior),
+    cut to its S_b: host tables (the CPU backend, across processes) and one
+    sorted run by plain slices, several chunks of a device table in ONE
+    program, per device under ``sharded``."""
+    if chunks == 1 or isinstance(table, np.ndarray):
+        return _chunk_rows(table, chunks, start, end, sb)
+    return _chunk_rows_of(
+        (table,), chunks=chunks, start=start, end=end, dims=((sb,),), sharded=sharded
+    )[0]
 
 
 def _contiguous_segments(
@@ -1073,21 +1220,6 @@ def _entity_shard_align(blocks) -> int:
     return 1
 
 
-def _chunk_rows(a, chunks: int, start: int, end: int, *dims: int):
-    """Rows [start, end) of every one of the ``chunks`` equal chunks of
-    ``a``'s leading axis, chunk-major, the trailing axes cut to ``dims``.
-    One chunk: the plain slice. (Slices joined, not a reshape sliced: behind a
-    reshape the TPU compiler re-lays the whole array out before it cuts.)"""
-    cut = tuple(slice(None, d) for d in dims)
-    rows = a.shape[0] // chunks
-    parts = [
-        a[(slice(c * rows + start, c * rows + end),) + cut] for c in range(chunks)
-    ]
-    if chunks == 1:
-        return parts[0]
-    return (np if isinstance(a, np.ndarray) else jnp).concatenate(parts)
-
-
 def _chunk_axis(a, chunks: int):
     """(mesh, axis name) when ``a``'s leading axis is sharded over one mesh
     axis that deals whole chunks to every device (what ``shard_entity_blocks``
@@ -1129,72 +1261,40 @@ def _chunk_rows_of(arrays, *, chunks, start, end, dims, sharded):
     return _per_device(cut, chunks, sharded)(arrays)
 
 
-@partial(jax.jit, static_argnames=("segments", "chunks", "sharded"))
-def _bucket_offsets(active_rows, offsets, residual_scores, *, segments, chunks, sharded):
+def _bucket_offsets(active_parts, offset_parts, residual_scores, *, chunks, sharded):
     """The residual exchange as ONE program: every bucket's solver offsets,
-    one ``[chunks * (end - start), K_b]`` array a segment, rows as
-    ``_chunk_rows`` orders them. Only the slots a bucket solves are gathered:
-    ``active_rows`` and ``offsets`` ([E, K]) are cut to the bucket BEFORE the
-    residual is gathered at those rows, padding slots (-1) masked, and added.
-    Under ``sharded`` every chip gathers for its own chunks from the whole [N]
+    one ``[chunks * (end - start), K_b]`` array a bucket, rows as the store
+    orders them. Only the slots a bucket solves are gathered: ``active_parts``
+    and ``offset_parts`` are the store's own per-bucket arrays, the residual
+    is gathered at those rows, padding slots (-1) masked, and added. Under
+    ``sharded`` every chip gathers for its own chunks from the whole [N]
     residual (all-gathered once on entry when it is row-sharded).
-    ``residual_scores`` None: the blocks' own offsets, cut."""
-
-    def gather(chunks_here, blocks, *residual):
-        active_rows, offsets = blocks
-        chunk_rows = offsets.shape[0] // chunks_here
-
-        def piece(c, start, end, kb):
-            cut = (slice(c * chunk_rows + start, c * chunk_rows + end), slice(None, kb))
-            if not residual:
-                return offsets[cut]
-            rows = active_rows[cut]
-            res = jnp.take(residual[0], jnp.maximum(rows, 0), axis=0) * (rows >= 0)
-            return offsets[cut] + res.astype(offsets.dtype)
-
-        # slices gathered chunk by chunk and joined LAST: joined first, the
-        # TPU compiler re-lays a narrow bucket's index array out row-major
-        # (lane-padded 128 / K_b times) to flatten it for the gather
-        return tuple(
-            jnp.concatenate([piece(c, start, end, kb) for c in range(chunks_here)])
-            for start, end, kb, _ in segments
-        )
-
-    whole = () if residual_scores is None else (residual_scores,)
-    return _per_device(gather, chunks, sharded, len(whole))(
-        (active_rows, offsets), *whole
+    ``residual_scores`` None: the blocks' own offsets, as stored."""
+    if residual_scores is None:
+        return tuple(offset_parts)
+    return _gather_bucket_offsets(
+        tuple(active_parts), tuple(offset_parts), residual_scores,
+        chunks=chunks, sharded=sharded,
     )
 
 
-def _bucket_operands(
-    block_arrays, offsets, state_arrays, chunks, sharded, start, end, kb, sb
-):
-    """A bucket's solver operands: rows [start, end) of every chunk, cut to
-    the bucket's (K_b, S_b). ``block_arrays`` are the [E, K(, S)] features,
-    labels and weights, ``offsets`` the bucket's own from the exchange
-    (``_bucket_offsets``: cut already), ``state_arrays`` the [E, S] w0 and
-    priors (host numpy on the CPU backend and across processes: cut on the
-    host); ``sharded`` is the blocks' ``_chunk_axis``."""
-    dims = ((kb, sb), (kb,), (kb,)) + ((sb,),) * len(state_arrays)
-    arrays = tuple(block_arrays) + tuple(state_arrays)
-    if chunks == 1:
-        # one sorted run: plain eager slices (a chunked cut is one program)
-        cut = (_chunk_rows(a, 1, start, end, *d) for a, d in zip(arrays, dims))
-    else:
-        on_host = [isinstance(a, np.ndarray) for a in arrays]
-        on_device = iter(
-            _chunk_rows_of(
-                tuple(a for a, h in zip(arrays, on_host) if not h),
-                chunks=chunks, start=start, end=end,
-                dims=tuple(d for d, h in zip(dims, on_host) if not h), sharded=sharded,
-            )
-        )
-        cut = (
-            _chunk_rows(a, chunks, start, end, *d) if h else next(on_device)
-            for a, d, h in zip(arrays, dims, on_host)
-        )
-    features, labels, weights, *state = cut
-    return (features, labels, offsets, weights, *state)
+@partial(jax.jit, static_argnames=("chunks", "sharded"))
+def _gather_bucket_offsets(active_parts, offset_parts, residual_scores, *, chunks, sharded):
+    def gather(_chunks_here, blocks, residual):
+        def one(rows, offsets):
+            # on the transposed view: the TPU holds a narrow [n_b, K_b] part
+            # entity-minor (K_b < 128 lanes), where the transpose is free and
+            # the gather's indices are lane-dense; gathered as stored, it
+            # re-laid every such part out row-major, padded to 128 lanes
+            rows = rows.T
+            res = jnp.take(residual, jnp.maximum(rows, 0), axis=0) * (rows >= 0)
+            return (offsets.T + res.astype(offsets.dtype)).T
+
+        return tuple(one(rows, offsets) for rows, offsets in zip(*blocks))
+
+    return _per_device(gather, chunks, sharded, 1)(
+        (active_parts, offset_parts), residual_scores
+    )
 
 
 def _concat_results(parts, S: int, chunks: int = 1, sharded=None) -> SolverResult:

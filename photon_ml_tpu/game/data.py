@@ -7,7 +7,7 @@ RandomEffectDatasetPartitioner.scala (entity sharding), and the
 LinearSubspaceProjector (photon-api .../projector/LinearSubspaceProjector.scala:37-90).
 
 TPU re-design (SURVEY.md §7.3): instead of an RDD of per-entity iterables,
-a random-effect dataset is a set of *dense entity blocks*:
+a random-effect dataset is a set of *dense entity blocks*, LOGICALLY
 
     features  f[E, K, S]   per-entity rows projected into the entity's
     labels    f[E, K]      feature subspace (S = max subspace dim,
@@ -16,8 +16,18 @@ a random-effect dataset is a set of *dense entity blocks*:
     proj_cols i32[E, S]    local dim -> global feature column (-1 pad)
     active_rows i32[E, K]  global sample row of each block cell (-1 pad)
 
-Per-entity local solves then become one vmapped masked solver call — the
-MXU-friendly replacement for the reference's per-entity sequential L-BFGS
+and STORED ragged: the five arrays with a K axis are one array a size bucket
+(:class:`BucketedArray`; ``size_buckets``: the entities of one power-of-two
+row count K_b, at the power-of-two subspace width S_b their largest needs),
+``features f[chunks * (end - start), K_b, S_b]`` and the others at
+``[..., K_b]``, rows chunk-major. No ``E x K x S`` array exists on the host
+or the device: a long-tailed entity law (a few entities at the cap with
+hundreds of columns, most with a handful of rows and columns) costs what its
+buckets hold, not what its largest entity would at every entity. Only the
+``[E, S]`` tables stay planes.
+
+Per-entity local solves then become one vmapped masked solver call a bucket —
+the MXU-friendly replacement for the reference's per-entity sequential L-BFGS
 fan-out (RandomEffectCoordinate.scala:273-329). Entity order doubles as the
 sharding axis: shard dim 0 over the mesh and each device owns a contiguous
 range of block rows. Built for ``m`` devices (``pad_entities_to_multiple``),
@@ -37,7 +47,8 @@ coordinate explains them).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+import os
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -128,17 +139,199 @@ class FixedEffectDataset:
         return self.batch.dim if self.batch is not None else self.host_batch.dim
 
 
+Segment = Tuple[int, int, int, int]  # (start, end, K_b, S_b): rows of ONE chunk
+
+
+def _pow2_ceil(x: np.ndarray) -> np.ndarray:
+    """Exact elementwise 2**ceil(log2(max(x, 1))) for int64 inputs < 2^53
+    (frexp exponents of exactly-represented ints are bit_lengths)."""
+    v = np.maximum(np.asarray(x, dtype=np.int64), 1) - 1
+    return np.int64(1) << np.frexp(v.astype(np.float64))[1].astype(np.int64)
+
+
+def size_buckets(
+    counts: np.ndarray,  # i64[E] active rows per block row
+    subspace_dims: np.ndarray,  # i64[E] real subspace width per block row
+    K: int,
+    S: int,
+    chunks: int,
+    min_dim: int = 8,
+) -> Optional[List[Segment]]:
+    """Chunk-local entity segments with power-of-2-rounded (K, S) block shapes:
+    the shapes the entity blocks are STORED at and the solver runs at.
+
+    Returns [(start, end, K_b, S_b)], or None when bucketing cannot shrink
+    anything (one bucket of the whole [K, S] extent). ``start``/``end`` are
+    rows of ONE chunk: the block rows are ``chunks`` equal chunks, each
+    size-sorted descending and dealt the same size profile (``_entity_plan``),
+    and a bucket is rows [start, end) of EVERY chunk. One set of bounds serves
+    all chunks: the row count at a local position is taken as the largest over
+    the chunks, so an entity a chunk reaches one position early fits the
+    larger K of the bucket before it. With one chunk the segments are plain
+    block-row ranges. Rounding to powers of two (floored at ``min_dim``)
+    bounds the number of distinct compiled solver shapes at O(log^2) while
+    removing the bulk of the padding.
+
+    Fully vectorized (no per-entity Python work: this also runs on every
+    train() call, potentially over millions of entities)."""
+    if len(counts) == 0:
+        return None
+    # per local position, the largest over the chunks
+    counts = np.asarray(counts, dtype=np.int64).reshape(chunks, -1).max(axis=0)
+    sv = np.asarray(subspace_dims, dtype=np.int64).reshape(chunks, -1).max(axis=0)
+    chunk_rows = len(counts)
+
+    kb_of = np.minimum(np.maximum(_pow2_ceil(counts), min_dim), K)
+    bounds = np.flatnonzero(np.diff(kb_of)) + 1  # starts of new equal-K runs
+    starts = np.concatenate([[0], bounds])
+    ends = np.concatenate([bounds, [chunk_rows]])
+
+    sb_of = np.minimum(
+        np.maximum(_pow2_ceil(np.maximum.reduceat(sv, starts)), min_dim), S
+    )
+    segments = [
+        (
+            int(s),
+            int(e),
+            int(kb_of[s]),  # counts non-increasing => max K of the segment
+            int(sb),
+        )
+        for s, e, sb in zip(starts, ends, sb_of)
+    ]
+    if len(segments) == 1 and segments[0][2] >= K and segments[0][3] >= S:
+        return None
+    return segments
+
+
+@jax.tree_util.register_pytree_node_class
+class BucketedArray:
+    """A logical ``[E, K]`` / ``[E, K, S]`` entity-block array stored ragged:
+    ``parts[b]`` holds rows [start_b, end_b) of every one of the ``chunks``
+    chunks of block rows, chunk-major, cut to the bucket's ``[K_b(, S_b)]``:
+    ``[chunks * (end_b - start_b), K_b(, S_b)]``. Outside a bucket's extent
+    the logical array is ``fill`` (0, or -1 for ``active_rows``) and is stored
+    nowhere.
+
+    It answers ``shape`` (the LOGICAL one), ``dtype`` and ``sharding`` as the
+    plane it replaces did, and is a pytree over its parts, so
+    ``shard_entity_blocks`` places every part by the chunk-dealt rule (a
+    part's leading axis over ``data``: chunk c of every bucket on the device
+    that holds chunk c). ``plane()`` / ``np.asarray`` assemble the logical
+    array on demand: for tests, tools and the paths that still want one
+    (trial lanes; ROADMAP.md Design), never on the resident train path, and
+    never one larger than the host's memory (``MemoryError``)."""
+
+    def __init__(self, parts, segments: Sequence[Segment], chunks: int, shape, fill=0):
+        self.parts = tuple(parts)
+        self.segments = tuple(tuple(int(v) for v in seg) for seg in segments)
+        self.chunks = int(chunks)
+        self.shape = tuple(int(v) for v in shape)
+        self.fill = fill
+
+    def tree_flatten(self):
+        return self.parts, (self.segments, self.chunks, self.shape, self.fill)
+
+    @classmethod
+    def tree_unflatten(cls, aux, parts):
+        return cls(parts, *aux)
+
+    def __repr__(self) -> str:
+        return (
+            f"BucketedArray(shape={self.shape}, dtype={self.dtype}, "
+            f"buckets={[tuple(np.shape(p)) for p in self.parts]})"
+        )
+
+    @property
+    def dtype(self):
+        return self.parts[0].dtype
+
+    @property
+    def ndim(self) -> int:
+        return len(self.shape)
+
+    @property
+    def sharding(self):
+        # host-numpy parts carry none: AttributeError, as a host plane's did
+        return self.parts[0].sharding
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes the store holds (the plane's would be prod(shape) * itemsize)."""
+        return int(sum(int(np.prod(np.shape(p))) * p.dtype.itemsize for p in self.parts))
+
+    def plane(self):
+        """The logical array, assembled from the buckets: host numpy from
+        host parts, a device array from device parts."""
+        host = isinstance(self.parts[0], np.ndarray)
+        # (a host that overcommits hands out any size and dies touching it)
+        logical = int(np.prod(self.shape)) * self.dtype.itemsize
+        if logical > os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES"):
+            raise MemoryError(
+                f"the logical {self.shape} plane is {logical / 1e9:.0f} GB ({self.nbytes / 1e9:.2f} GB "
+                "stored): past this host's memory, it is not assembled"
+            )
+        out = (np if host else jnp).full(self.shape, self.fill, self.dtype)
+        chunk_rows = self.shape[0] // self.chunks
+        for part, (start, end, *dims) in zip(self.parts, self.segments):
+            n_b = end - start
+            cut = tuple(slice(None, d) for d in dims[: self.ndim - 1])
+            for c in range(self.chunks):
+                rows = (slice(c * chunk_rows + start, c * chunk_rows + end),) + cut
+                piece = part[c * n_b : (c + 1) * n_b]
+                if host:
+                    out[rows] = piece
+                else:
+                    out = out.at[rows].set(piece)
+        return out
+
+    def __array__(self, dtype=None, copy=None):
+        plane = np.asarray(self.plane())
+        return plane if dtype is None else plane.astype(dtype)
+
+
+def _chunk_rows(a, chunks: int, start: int, end: int, *dims: int):
+    """Rows [start, end) of every one of the ``chunks`` equal chunks of
+    ``a``'s leading axis, chunk-major, the trailing axes cut to ``dims``.
+    One chunk: the plain slice. (Slices joined, not a reshape sliced: behind a
+    reshape the TPU compiler re-lays the whole array out before it cuts.)"""
+    cut = tuple(slice(None, d) for d in dims)
+    rows = a.shape[0] // chunks
+    parts = [
+        a[(slice(c * rows + start, c * rows + end),) + cut] for c in range(chunks)
+    ]
+    if chunks == 1:
+        return parts[0]
+    return (np if isinstance(a, np.ndarray) else jnp).concatenate(parts)
+
+
+def bucket_plane(plane, segments: Sequence[Segment], chunks: int, fill=0) -> BucketedArray:
+    """A ``[E, K(, S)]`` plane cut into its buckets (host slices of a host
+    plane, device slices of a device one). For data sets that arrive as
+    planes (hand-built, the multi-process build; ROADMAP.md Design): the
+    resident build never holds one."""
+    trailing = len(plane.shape) - 1
+    parts = [
+        _chunk_rows(plane, chunks, start, end, *dims[:trailing])
+        for start, end, *dims in segments
+    ]
+    return BucketedArray(parts, segments, chunks, plane.shape, fill)
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class EntityBlocks:
-    """Device-side entity-blocked training data (see module docstring)."""
+    """Entity-blocked training data (see module docstring). The comments give
+    each field's LOGICAL shape; the five with a K axis are stored one array a
+    size bucket (:class:`BucketedArray`) on the resident path, and as plain
+    host planes when the data set is streamed (game/streaming.py slices them)
+    or built across processes (game/data_mp.py; cut at the first train)."""
 
-    features: Array  # f[E, K, S]
-    labels: Array  # f[E, K]
-    offsets: Array  # f[E, K] (base offsets only; residuals added at train time)
-    weights: Array  # f[E, K]; 0 = padding
-    proj_cols: Array  # i32[E, S]; -1 = padding
-    active_rows: Array  # i32[E, K]; -1 = padding
+    features: Union[Array, BucketedArray]  # f[E, K, S]; part b: [chunks * n_b, K_b, S_b]
+    labels: Union[Array, BucketedArray]  # f[E, K]; part b: [chunks * n_b, K_b]
+    offsets: Union[Array, BucketedArray]  # f[E, K] (base offsets only; residuals added at train time)
+    weights: Union[Array, BucketedArray]  # f[E, K]; 0 = padding
+    proj_cols: Array  # i32[E, S] (a plane); -1 = padding
+    active_rows: Union[Array, BucketedArray]  # i32[E, K]; -1 = padding
 
     @property
     def num_entities(self) -> int:
@@ -151,6 +344,45 @@ class EntityBlocks:
     @property
     def subspace_dim(self) -> int:
         return self.features.shape[2]
+
+    @property
+    def bucketed(self) -> bool:
+        return isinstance(self.features, BucketedArray)
+
+    @property
+    def store_bytes(self) -> int:
+        """Bytes of the five K-axis arrays as stored."""
+        return int(
+            sum(
+                a.nbytes
+                for a in (self.features, self.labels, self.offsets, self.weights, self.active_rows)
+            )
+        )
+
+
+def bucket_blocks(
+    blocks: EntityBlocks, segments: Sequence[Segment], chunks: int, cut=None
+) -> EntityBlocks:
+    """``blocks``, handed over as planes, with its five K-axis arrays cut into
+    ``segments``. ``cut(planes, start, end, dims)`` returns one bucket's rows
+    of all five (the coordinate passes one program a bucket, per device under
+    a mesh); the default cuts each plane by plain slices."""
+    planes = (blocks.features, blocks.labels, blocks.offsets, blocks.weights, blocks.active_rows)
+    if cut is None:
+        cut = lambda planes, start, end, dims: tuple(  # noqa: E731
+            _chunk_rows(p, chunks, start, end, *d) for p, d in zip(planes, dims)
+        )
+    pieces = [
+        cut(planes, start, end, ((kb, sb),) + ((kb,),) * 4) for start, end, kb, sb in segments
+    ]
+    features, labels, offsets, weights, active_rows = (
+        BucketedArray([piece[f] for piece in pieces], segments, chunks, plane.shape, fill)
+        for f, (plane, fill) in enumerate(zip(planes, (0, 0, 0, 0, -1)))
+    )
+    return EntityBlocks(
+        features=features, labels=labels, offsets=offsets, weights=weights,
+        proj_cols=blocks.proj_cols, active_rows=active_rows,
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,8 +399,9 @@ class RandomEffectDataset:
     ell_idx: Array  # i32[n, F]
     ell_val: Array  # f[n, F]
     passive_rows: np.ndarray  # i64[*] rows not in any active block (info only)
-    # host-side per-entity stats, used to bucket the solver by block size so
-    # small entities don't pay the padding of the largest. The block rows are
+    # host-side per-entity stats: the size buckets the blocks are STORED and
+    # solved at (size_buckets) follow from them, so small entities pay
+    # neither the rows nor the columns of the largest. The block rows are
     # ``entity_chunks`` equal chunks, each size-sorted descending in itself
     # and dealt the same size profile (_entity_plan): every size bucket has
     # an equal share in every chunk, and so on every chip the chunks shard
@@ -585,6 +818,19 @@ def build_fixed_effect_dataset_from_disk(
     return dataset, dict(index_maps)
 
 
+def record_block_store(coordinate_id: str, store_bytes: int) -> None:
+    """Gauge of the bytes a coordinate's entity-block store holds (the five
+    K-axis arrays as stored, ``EntityBlocks.store_bytes``): set at the build
+    and again by every resident train call, so a registry attached after the
+    build reads it too."""
+    from .. import obs
+
+    obs.current_run().registry.gauge(
+        "photon_re_block_store_bytes",
+        "bytes of a random-effect coordinate's entity blocks as stored, bucket by bucket",
+    ).labels(coordinate=coordinate_id).set(store_bytes)
+
+
 def _pearson_keep_mask(
     feats: np.ndarray,  # f8[E, K, S] zero-padded per-entity features
     labels: np.ndarray,  # f8[E, K]
@@ -664,6 +910,22 @@ def build_random_effect_dataset(
 ) -> RandomEffectDataset:
     """Host-side dataset build (the one-time "shuffle" of SURVEY.md §2.1 P13).
 
+    What is stored: the entity blocks bucket by bucket. For every size bucket
+    ``(start, end, K_b, S_b)`` of ``size_buckets`` (the entities of one
+    power-of-two row count, at the power-of-two subspace width their largest
+    needs; rows [start, end) of every one of the ``pad_entities_to_multiple``
+    chunks), ``blocks.features`` holds one ``f[chunks * (end - start), K_b,
+    S_b]`` array and ``labels`` / ``offsets`` / ``weights`` / ``active_rows``
+    one ``[chunks * (end - start), K_b]`` array each (:class:`BucketedArray`),
+    built at those shapes on the host (float64: twice the store's bytes at
+    the peak) and placed on the device as they are: no ``E x K_max x S_max``
+    array exists at any point, so an entity law with a few wide, long
+    entities and a long tail of small ones costs the sum of its buckets
+    (0.69 GB where the plane is 125 GB: PERF.md §4). The
+    ``[E, S_max]`` tables (``proj_cols``, the model's coefficients) stay
+    planes, and so does a STREAMED data set's host copy (assembled from the
+    buckets: game/streaming.py slices planes).
+
     active_cap: numActiveDataPointsUpperBound — reservoir-cap per entity with
     count/cap weight rescale. active_lower_bound: numActiveDataPointsLowerBound
     — entities with fewer samples are not trained.
@@ -719,17 +981,13 @@ def build_random_effect_dataset(
         rank = np.zeros(n, dtype=np.int64)
         is_active = np.zeros(n, dtype=bool)
 
-    active_rows_np = np.full((E, K), -1, dtype=np.int64)
-    weight_scale = plan.weight_scale
     sel = np.nonzero(is_active)[0]
-    active_rows_np[sorted_entity[sel], rank[sel]] = sorted_rows[sel]
-
     passive = sorted_rows[~is_active & (sorted_entity >= 0)]
 
     # --- ELL features for all rows (scoring path) ----------------------------
     ell_idx_np, ell_val_np = _rows_to_ell(rows, cols, vals, n)
 
-    # --- per-entity subspace projection + dense blocks, fully vectorized -----
+    # --- per-entity subspace projection, fully vectorized --------------------
     # (reference pipeline: RandomEffectDataset.generateLinearSubspaceProjectors
     # + project, RandomEffectDataset.scala:255-360; the reference shuffled
     # per-entity iterables through Spark — here it is one sorted/segmented
@@ -738,13 +996,6 @@ def build_random_effect_dataset(
     ae = sorted_entity[sel]  # block row per active sample        [A]
     ak = rank[sel]  # slot within block                           [A]
     ar = sorted_rows[sel]  # global sample row                    [A]
-
-    labels_b = np.zeros((E, K))
-    offsets_b = np.zeros((E, K))
-    weights_b = np.zeros((E, K))
-    labels_b[ae, ak] = raw.labels[ar]
-    offsets_b[ae, ak] = raw.offsets[ar]
-    weights_b[ae, ak] = raw.weights[ar] * weight_scale[ae]
 
     d_shard = raw.shard_dims[feature_shard]
     fi = ell_idx_np[ar]  # [A, F] global cols of active rows
@@ -763,60 +1014,119 @@ def build_random_effect_dataset(
     proj_cols_np = np.full((E, S), -1, dtype=np.int32)
     proj_cols_np[ent_of_key, pos_within] = col_of_key
 
-    feats = np.zeros((E, K, S), dtype=np.float64)
+    # --- the entity blocks, bucket by bucket ---------------------------------
+    # Every K-axis array is built at its bucket's own [K_b(, S_b)] and no
+    # wider: what a [E, K, S] plane would hold outside the buckets is padding
+    # nothing reads. The buckets are size_buckets' (the shapes the solver runs
+    # at): the K runs follow the row counts alone, so a feature selection that
+    # narrows the subspaces below keeps the runs and only cuts their S_b.
+    entity_counts = np.zeros(E, dtype=np.int64)
+    entity_counts[:E_real] = np.minimum(counts[kept_entities], K)
+    chunks = plan.chunks
+    chunk_rows = E // chunks
+    segments = size_buckets(entity_counts, per_entity_s, K, S, chunks) or [
+        (0, chunk_rows, K, S)
+    ]
+    seg_starts = np.asarray([seg[0] for seg in segments], dtype=np.int64)
+    seg_rows = np.asarray([seg[1] - seg[0] for seg in segments], dtype=np.int64)
+    position = np.arange(E, dtype=np.int64) % chunk_rows
+    bucket_of = np.searchsorted(seg_starts, position, side="right") - 1  # [E]
+    # a block row's row inside its bucket's arrays (chunk-major, as
+    # shard_entity_blocks deals them)
+    local_of = (np.arange(E) // chunk_rows) * seg_rows[bucket_of] + (
+        position - seg_starts[bucket_of]
+    )
+
+    weight_scale = plan.weight_scale
+    sample_bucket = bucket_of[ae]
     aa, ff = np.nonzero(nz)  # active nnz coordinates (row-major, like the
     # assignment order of the loop implementation)
     loc = np.searchsorted(uniq_keys, keys[aa, ff]) - key_starts[ae[aa]]
-    feats[ae[aa], ak[aa], loc] = fv[aa, ff]
-
-    if features_to_samples_ratio is not None:
-        keep = _pearson_keep_mask(
-            feats, labels_b, active_rows_np >= 0, proj_cols_np,
-            features_to_samples_ratio,
-        )
-        # compact kept columns to the front (stable: column order preserved)
-        # and shrink the block S dim to the new max subspace size
-        order = np.argsort(~keep, axis=1, kind="stable")
-        proj_cols_np = np.take_along_axis(
-            np.where(keep, proj_cols_np, -1), order, axis=1
-        )
-        feats = np.take_along_axis(
-            np.where(keep[:, None, :], feats, 0.0), order[:, None, :], axis=2
-        )
-        per_entity_s = keep.sum(axis=1).astype(np.int64)
-        S = max(int(per_entity_s.max()) if E_real else 1, 1)
-        proj_cols_np = proj_cols_np[:, :S]
-        feats = feats[:, :, :S]
-
+    nnz_bucket = sample_bucket[aa]
     fdt = np.dtype(jnp.zeros((), feature_dtype or dtype).dtype)
     sdt = np.dtype(jnp.zeros((), dtype).dtype)
+
+    host_parts = []  # per bucket: (features, labels, offsets, weights, active_rows)
+    for b, (start, end, kb, sb) in enumerate(segments):
+        n_b = chunks * (end - start)
+        in_b = np.flatnonzero(sample_bucket == b)
+        lb, kk, rr = local_of[ae[in_b]], ak[in_b], ar[in_b]
+        active_b = np.full((n_b, kb), -1, dtype=np.int64)
+        labels_b = np.zeros((n_b, kb))
+        offsets_b = np.zeros((n_b, kb))
+        weights_b = np.zeros((n_b, kb))
+        active_b[lb, kk] = rr
+        labels_b[lb, kk] = raw.labels[rr]
+        offsets_b[lb, kk] = raw.offsets[rr]
+        weights_b[lb, kk] = raw.weights[rr] * weight_scale[ae[in_b]]
+        feats_b = np.zeros((n_b, kb, sb), dtype=np.float64)
+        nz_b = np.flatnonzero(nnz_bucket == b)
+        a_b = aa[nz_b]
+        feats_b[local_of[ae[a_b]], ak[a_b], loc[nz_b]] = fv[a_b, ff[nz_b]]
+        host_parts.append([feats_b, labels_b, offsets_b, weights_b, active_b])
+
+    if features_to_samples_ratio is not None:
+        # the selection is per entity: bucket by bucket, on the bucket's own
+        # arrays; kept columns are compacted to the front (stable: column
+        # order preserved) and the S extents shrink to the new subspaces
+        for b, (_, _, _, sb) in enumerate(segments):
+            feats_b, labels_b, _, _, active_b = host_parts[b]
+            # the bucket's block rows, ascending: chunk-major, as its arrays' rows
+            rows_b = np.flatnonzero(bucket_of == b)
+            pc_b = proj_cols_np[rows_b, :sb]
+            keep = _pearson_keep_mask(
+                feats_b, labels_b, active_b >= 0, pc_b, features_to_samples_ratio
+            )
+            order = np.argsort(~keep, axis=1, kind="stable")
+            proj_cols_np[rows_b, :sb] = np.take_along_axis(
+                np.where(keep, pc_b, -1), order, axis=1
+            )
+            host_parts[b][0] = np.take_along_axis(
+                np.where(keep[:, None, :], feats_b, 0.0), order[:, None, :], axis=2
+            )
+            per_entity_s[rows_b] = keep.sum(axis=1)
+        S = max(int(per_entity_s.max()) if E_real else 1, 1)
+        proj_cols_np = proj_cols_np[:, :S]
+        narrowed = size_buckets(entity_counts, per_entity_s, K, S, chunks) or [
+            (0, chunk_rows, K, S)
+        ]
+        for b, (_, _, _, sb) in enumerate(narrowed):
+            host_parts[b][0] = host_parts[b][0][:, :, :sb]
+        segments = narrowed
+
     streamed = False
     if hbm_budget_bytes is not None:
         from .streaming import estimate_block_bytes
 
-        E_b, K_b, S_b = feats.shape
-        streamed = (
-            estimate_block_bytes(E_b, K_b, S_b, fdt.itemsize) > hbm_budget_bytes
+        streamed = estimate_block_bytes(E, K, S, fdt.itemsize) > hbm_budget_bytes
+
+    def stored(field: int, to, shape, fill=0) -> BucketedArray:
+        return BucketedArray(
+            [to(parts[field]) for parts in host_parts], segments, chunks, shape, fill
         )
+
     if streamed:
-        # host-resident blocks: train/score stream slices (game/streaming.py)
+        # host-resident blocks: train/score stream slices of PLANES
+        # (game/streaming.py), assembled here from the buckets
         blocks = EntityBlocks(
-            features=feats.astype(fdt),
-            labels=labels_b.astype(sdt),
-            offsets=offsets_b.astype(sdt),
-            weights=weights_b.astype(sdt),
+            features=stored(0, lambda a: a.astype(fdt), (E, K, S)).plane(),
+            labels=stored(1, lambda a: a.astype(sdt), (E, K)).plane(),
+            offsets=stored(2, lambda a: a.astype(sdt), (E, K)).plane(),
+            weights=stored(3, lambda a: a.astype(sdt), (E, K)).plane(),
             proj_cols=proj_cols_np.astype(np.int32),
-            active_rows=active_rows_np.astype(np.int32),
+            active_rows=stored(4, lambda a: a.astype(np.int32), (E, K), -1).plane(),
         )
     else:
         blocks = EntityBlocks(
-            features=jnp.asarray(feats, feature_dtype or dtype),
-            labels=jnp.asarray(labels_b, dtype),
-            offsets=jnp.asarray(offsets_b, dtype),
-            weights=jnp.asarray(weights_b, dtype),
+            features=stored(0, lambda a: jnp.asarray(a, feature_dtype or dtype), (E, K, S)),
+            labels=stored(1, lambda a: jnp.asarray(a, dtype), (E, K)),
+            offsets=stored(2, lambda a: jnp.asarray(a, dtype), (E, K)),
+            weights=stored(3, lambda a: jnp.asarray(a, dtype), (E, K)),
             proj_cols=jnp.asarray(proj_cols_np),
-            active_rows=jnp.asarray(active_rows_np.astype(np.int32)),
+            active_rows=stored(4, lambda a: jnp.asarray(a.astype(np.int32)), (E, K), -1),
         )
+        record_block_store(coordinate_id, blocks.store_bytes)
+    del host_parts
 
     row_entity = np.where(entity_of_row >= 0, entity_of_row, -1).astype(np.int32)
     kept_ids = uniq[kept_entities].astype(str)
@@ -834,7 +1144,7 @@ def build_random_effect_dataset(
         ell_idx=jnp.asarray(ell_idx_np),
         ell_val=jnp.asarray(ell_val_np, feature_dtype or dtype),
         passive_rows=passive,
-        entity_counts=np.sum(active_rows_np >= 0, axis=1).astype(np.int64),
+        entity_counts=entity_counts,
         entity_subspace_dims=per_entity_s.astype(np.int64),
         entity_chunks=plan.chunks,
         streamed=streamed,

@@ -183,10 +183,13 @@ class GLMProblem:
         initial_model: Optional[GeneralizedLinearModel] = None,
         coordinate: Optional[str] = None,
         nnz: Optional[int] = None,
+        residuals: bool = False,
     ) -> Tuple[GeneralizedLinearModel, SolverResult]:
         """Train; returns (model in ORIGINAL space, solver result).
         ``coordinate`` names the caller's coordinate on the ``fe.solve`` span,
-        ``nnz`` the entries its dataset's build stored (host-known).
+        ``nnz`` the entries its dataset's build stored (host-known),
+        ``residuals`` whether the batch's offsets carry other coordinates'
+        scores (the span's ``offsets``).
 
         Normalization semantics parity (Optimizer.scala:161-185 +
         GeneralizedLinearOptimizationProblem): warm-start coefficients are
@@ -242,6 +245,10 @@ class GLMProblem:
             dim=int(batch.dim),
             slots=getattr(batch.features, "slots", None),
             nnz=nnz,
+            # a solve inside coordinate descent: warm-started from the last
+            # sweep's model, under the other coordinates' scores as offsets
+            warm=initial_model is not None,
+            offsets=bool(residuals),
         ) as sp:
             # with a sink, an L-BFGS or OWL-QN solve adds ``line_search`` (the
             # search it ran: ``margins`` | ``points``) and ``line_search_evals``

@@ -78,6 +78,45 @@ def ell_support_positions(
 
 
 @jax.jit
+def ell_slot_positions(
+    coef_indices: Array,  # i32[E, S] sorted ascending per row, -1 padded
+    entity_rows: Array,  # i32[n], -1 = unseen entity
+    feat_idx: Array,  # i32[n, F]
+):
+    """:func:`ell_support_positions` (the same ``(pos, hit)``, integer for
+    integer) with no ``[n, S]`` array: a bisection over each slot's own
+    entity row of ``coef_indices``, ceil(log2(S + 1)) rounds of one gather a
+    slot each, so the memory follows the rows' slots and not the widest
+    entity's subspace. For data sets whose subspaces are ragged (a few
+    entities with hundreds of columns, rows with a handful of slots): there
+    the gathered ``[n, S]`` supports of the vmapped searchsorted are S / F
+    times the data. The rounds run over ``[F, n]`` views (the row axis minor,
+    as ``[n, F]`` arrays lie on the TPU): over ``[n, F]`` the compiler pads
+    every intermediate's F to 128 lanes."""
+    S = coef_indices.shape[1]
+    rows = jnp.maximum(entity_rows, 0)[None, :]
+    want = feat_idx.T  # [F, n]
+    big = jnp.iinfo(jnp.int32).max
+
+    def support_at(pos):  # the -1 padding reads as a +inf sentinel: sorted
+        v = coef_indices[rows, jnp.minimum(pos, S - 1)]
+        return jnp.where(v < 0, big, v)
+
+    def halve(_, bounds):  # leftmost pos with support[pos] >= feat_idx
+        lo, hi = bounds
+        mid = (lo + hi) // 2
+        right = (lo < hi) & (support_at(mid) < want)
+        return jnp.where(right, mid + 1, lo), jnp.where((lo < hi) & ~right, mid, hi)
+
+    lo = jnp.zeros(want.shape, jnp.int32)
+    lo, _ = jax.lax.fori_loop(
+        0, max(int(S).bit_length(), 1), halve, (lo, jnp.full_like(lo, S))
+    )
+    pos = jnp.clip(lo, 0, S - 1)
+    return pos.T, (support_at(pos) == want).T
+
+
+@jax.jit
 def ell_row_subspace(
     coef_indices: Array,  # i32[E, S] sorted ascending per row, -1 padded
     entity_rows: Array,  # i32[n], -1 = unseen entity
@@ -135,18 +174,35 @@ def score_entity_rows_dense_lanes(
 def score_entity_ell_at(
     coef_values: Array,  # f[E, S]
     entity_rows: Array,  # i32[n], -1 = unseen entity
-    pos: Array,  # i32[n, F] from ell_support_positions
+    pos: Array,  # i32[n, F] from ell_support_positions / ell_slot_positions
     hit: Array,  # bool[n, F]
     feat_val: Array,  # f[n, F]
 ) -> Array:
     """Scoring with the searchsorted already resolved: one 2-D gather of
     coef_values at (entity_row, pos) index pairs plus a masked dot. The
     gather keeps (row, col) pairs instead of a flattened row*S+col index so
-    E*S beyond int32 range cannot overflow."""
+    E*S beyond int32 range cannot overflow; it and the dot run over ``[F, n]``
+    views (the row axis minor: an ``[n, F]`` intermediate is padded to 128
+    lanes on the TPU, 600 MB each at 1.18M rows of 5 slots)."""
     safe_rows = jnp.maximum(entity_rows, 0)
-    w = coef_values[safe_rows[:, None], pos]  # [n, F]
-    scores = jnp.sum(jnp.where(hit, w * feat_val, 0.0), axis=1)
+    w = coef_values[safe_rows[None, :], pos.T]  # [F, n]
+    scores = jnp.sum(jnp.where(hit.T, w * feat_val.T, 0.0), axis=0)
     return jnp.where(entity_rows >= 0, scores, 0.0)
+
+
+@jax.jit
+def score_entity_ell_at_lanes(
+    coef_values: Array,  # f[E, S, L] lane-stacked per-entity coefficients
+    entity_rows: Array,  # i32[n], -1 = unseen entity
+    pos: Array,  # i32[n, F]
+    hit: Array,  # bool[n, F]
+    feat_val: Array,  # f[n, F]
+) -> Array:
+    """Lane-stacked :func:`score_entity_ell_at`: [n, L] scores."""
+    safe_rows = jnp.maximum(entity_rows, 0)
+    w = coef_values[safe_rows[:, None], pos]  # [n, F, L]
+    scores = jnp.sum(jnp.where(hit[:, :, None], w * feat_val[:, :, None], 0.0), axis=1)
+    return jnp.where(entity_rows[:, None] >= 0, scores, 0.0)
 
 
 @dataclasses.dataclass(frozen=True)

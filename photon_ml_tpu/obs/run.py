@@ -205,7 +205,10 @@ def record_solver_metrics(solver: str, result) -> None:
 
     reg = run.registry
     if result.line_search_evals is not None:
-        _record_fe_path(reg, **{name: int(x.sum()) for name, x in zip(counts, extras)})
+        _record_fe_path(
+            reg, iterations=int(iters.sum()),
+            **{name: int(x.sum()) for name, x in zip(counts, extras)},
+        )
     reg.summary(
         "photon_solver_iterations", "iterations per host-level solve"
     ).labels(solver=solver).observe_many(iters.ravel().tolist())
@@ -236,7 +239,7 @@ def record_solver_metrics(solver: str, result) -> None:
 
 def _record_fe_path(reg, line_search_evals: int, orthant_zeroed: Optional[int] = None,
                     nonzeros: Optional[int] = None, matvecs: Optional[int] = None,
-                    rmatvecs: Optional[int] = None) -> None:
+                    rmatvecs: Optional[int] = None, iterations: Optional[int] = None) -> None:
     """What a counting L-BFGS adds to a fixed-effect solve, on the enclosing
     ``fe.solve`` span (``game/problem.py``) and as counters by its coordinate:
     the search it ran (``line_search``: ``margins`` where the solve counted
@@ -251,6 +254,11 @@ def _record_fe_path(reg, line_search_evals: int, orthant_zeroed: Optional[int] =
         return
     solve_span.attrs["line_search"] = "points" if matvecs is None else "margins"
     solve_span.attrs["line_search_evals"] = line_search_evals
+    if iterations is not None:
+        # this solve's own count (``photon_cd_iterations`` is the mean over
+        # every solve of a fit: a warm solve inside coordinate descent and
+        # the cold first one are told apart by the span's ``warm``)
+        solve_span.attrs["iterations"] = iterations
     coordinate = str(solve_span.attrs.get("coordinate"))
     reg.counter(
         "photon_fe_line_search_evals_total",

@@ -138,7 +138,8 @@ def shard_coefficients(w: jnp.ndarray, mesh: Mesh) -> jnp.ndarray:
 
 def shard_entity_blocks(blocks, mesh: Mesh):
     """Shard EntityBlocks on the entity dim over the data axis (P5): each
-    device takes a contiguous range of block rows. A dataset built with
+    device takes a contiguous range of block rows (of every bucket's array
+    its own chunks' rows). A dataset built with
     ``pad_entities_to_multiple`` = the axis size holds one size-sorted chunk
     of equal load per device (game/data.py ``_entity_plan``)."""
     n_data = mesh.shape[DATA_AXIS]
@@ -152,6 +153,17 @@ def shard_entity_blocks(blocks, mesh: Mesh):
         )
 
     def put(a):
+        # the blocks are stored one array a size bucket, chunk-major
+        # (game/data.py BucketedArray, a pytree over its parts): a part's
+        # leading axis over ``data`` puts chunk c of every bucket on the
+        # device that holds chunk c
+        if a.shape[0] % n_data != 0:
+            raise ValueError(
+                f"an entity-block bucket of {a.shape[0]} rows does not divide "
+                f"the data axis ({n_data}); build the dataset with "
+                f"pad_entities_to_multiple={n_data}, which deals every size "
+                f"bucket over the axis in equal shares"
+            )
         spec = P(*([DATA_AXIS] + [None] * (a.ndim - 1)))
         return put_global(a, mesh, spec)
 

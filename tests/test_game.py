@@ -341,7 +341,7 @@ def test_re_score_cached_positions_match_general_path(mixed):
     )
     first = np.asarray(coord.score(model))
     again = np.asarray(coord.score(model))  # cache hit
-    assert getattr(ds, "_score_xsub_cache", None) is not None
+    assert getattr(ds, "_score_form_cache", None) is not None
     np.testing.assert_allclose(first, general, rtol=1e-12, atol=1e-12)
     np.testing.assert_allclose(again, general, rtol=1e-12, atol=1e-12)
 

@@ -1,10 +1,13 @@
 """The residual exchange of ``RandomEffectCoordinate.train`` gathers only the
-slots its buckets solve (``_bucket_offsets``, one program a train call). The
-plain reference is the expression it replaced: the residual gathered into the
-WHOLE [E, K] plane, from which each bucket's rows were then cut. A gather is
-exact, so everything here is compared bit for bit. CPU only; no timing."""
+slots its buckets solve (``_bucket_offsets``, one program a train call), from
+the entity blocks as they are STORED: one array a size bucket. The plain
+reference is the expression it replaced: the residual gathered into the WHOLE
+[E, K] plane (assembled here from the store, ``BucketedArray.plane``), from
+which each bucket's rows were then cut. A gather is exact, so everything here
+is compared bit for bit. CPU only; no timing."""
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -16,13 +19,14 @@ from photon_ml_tpu import obs
 from photon_ml_tpu.game import RandomEffectCoordinate, build_random_effect_dataset
 from photon_ml_tpu.game.coordinate import (
     _bucket_offsets,
-    _bucket_operands,
+    _bucketed_blocks,
     _chunk_axis,
     _chunk_rows,
     _concat_results,
     _size_buckets,
     _train_blocks_packed,
 )
+from photon_ml_tpu.game.data import EntityBlocks, bucket_plane
 from photon_ml_tpu.game.problem import GLMOptimizationConfig
 from photon_ml_tpu.ops.regularization import RegularizationContext
 from photon_ml_tpu.optimize import OptimizerConfig
@@ -55,7 +59,17 @@ def _dataset(chunks, with_counts=True):
     blocks = dataset.blocks
     E, K, _ = blocks.features.shape
     own = 0.25 + np.arange(E * K, dtype=np.float64).reshape(E, K) / (4 * E * K)
-    blocks = dataclasses.replace(blocks, offsets=jnp.asarray(own))
+    blocks = dataclasses.replace(
+        blocks, offsets=bucket_plane(jnp.asarray(own), blocks.features.segments, chunks)
+    )
+    if not with_counts:
+        # a data set handed over as PLANES with no per-entity statistics: one
+        # whole-extent bucket, cut (trivially) at the first train
+        blocks = EntityBlocks(
+            features=blocks.features.plane(), labels=blocks.labels.plane(),
+            offsets=blocks.offsets.plane(), weights=blocks.weights.plane(),
+            proj_cols=blocks.proj_cols, active_rows=blocks.active_rows.plane(),
+        )
     if chunks > 1:
         blocks = shard_entity_blocks(blocks, data_parallel_mesh(chunks))
     dataset = dataclasses.replace(dataset, blocks=blocks)
@@ -80,10 +94,8 @@ def _residual(dataset, chunks):
 def _plane(dataset, residual):
     """The parent's exchange: the whole [E, K] plane of solver offsets."""
     blocks = dataset.blocks
-    res_blocks = jnp.take(residual, jnp.maximum(blocks.active_rows, 0), axis=0) * (
-        blocks.active_rows >= 0
-    )
-    return blocks.offsets + res_blocks.astype(blocks.labels.dtype)
+    active, own = np.asarray(blocks.active_rows), np.asarray(blocks.offsets)
+    return own + np.asarray(residual)[np.maximum(active, 0)] * (active >= 0)
 
 
 def _coordinate(dataset):
@@ -97,12 +109,12 @@ def _coordinate(dataset):
     )
 
 
-def _exchange(dataset, residual, segments):
+def _exchange(dataset, residual):
     chunks = dataset.entity_chunks
+    blocks, _ = _bucketed_blocks(dataset)
     return _bucket_offsets(
-        dataset.blocks.active_rows, dataset.blocks.offsets, residual,
-        segments=tuple(segments), chunks=chunks,
-        sharded=_chunk_axis(dataset.blocks.features, chunks),
+        blocks.active_rows.parts, blocks.offsets.parts, residual,
+        chunks=chunks, sharded=_chunk_axis(blocks.features, chunks),
     )
 
 
@@ -112,13 +124,14 @@ def test_every_bucket_gets_the_planes_bits(chunks):
     residual = _residual(dataset, chunks)
     segments = _size_buckets(dataset)
     assert len(segments) >= 3
-    plane = np.asarray(_plane(dataset, residual))
+    plane = _plane(dataset, residual)
     active = np.asarray(dataset.blocks.active_rows)
     own = np.asarray(dataset.blocks.offsets)
-    got = _exchange(dataset, residual, segments)
+    got = _exchange(dataset, residual)
+    assert dataset.blocks.features.segments == tuple(segments)
     assert len(got) == len(segments)
     gathered = 0
-    for (start, end, kb, _), offsets in zip(segments, got):
+    for b, ((start, end, kb, _), offsets) in enumerate(zip(segments, got)):
         assert offsets.shape == (chunks * (end - start), kb)
         assert offsets.dtype == dataset.blocks.offsets.dtype
         np.testing.assert_array_equal(
@@ -135,7 +148,9 @@ def test_every_bucket_gets_the_planes_bits(chunks):
         assert (_chunk_rows(active, chunks, start, end)[:, kb:] < 0).all()
         gathered += offsets.size
         if chunks > 1:
-            assert offsets.sharding.is_equivalent_to(dataset.blocks.offsets.sharding, 2)
+            assert offsets.sharding.is_equivalent_to(
+                dataset.blocks.offsets.parts[b].sharding, 2
+            )
     E, K, _ = dataset.blocks.features.shape
     assert gathered < E * K // 2
 
@@ -145,7 +160,7 @@ def test_without_a_residual_the_buckets_are_the_blocks_own_offsets(chunks):
     dataset = _dataset(chunks)
     segments = _size_buckets(dataset)
     own = np.asarray(dataset.blocks.offsets)
-    for (start, end, kb, _), offsets in zip(segments, _exchange(dataset, None, segments)):
+    for (start, end, kb, _), offsets in zip(segments, _exchange(dataset, None)):
         np.testing.assert_array_equal(
             np.asarray(offsets), _chunk_rows(own, chunks, start, end, kb)
         )
@@ -157,8 +172,8 @@ def test_without_entity_statistics_the_one_segment_is_the_plane(chunks):
     assert _size_buckets(dataset) is None
     residual = _residual(dataset, chunks)
     E, K, S = dataset.blocks.features.shape
-    (whole,) = _exchange(dataset, residual, [(0, E // chunks, K, S)])
-    np.testing.assert_array_equal(np.asarray(whole), np.asarray(_plane(dataset, residual)))
+    (whole,) = _exchange(dataset, residual)
+    np.testing.assert_array_equal(np.asarray(whole), _plane(dataset, residual))
     # and train() runs that one path: the span says all of the plane is gathered
     run, spans = obs.RunTelemetry(), []
     run.register_listener(_Spans(spans))
@@ -167,7 +182,8 @@ def test_without_entity_statistics_the_one_segment_is_the_plane(chunks):
     (exchange,) = [s for s in spans if s.name == "re.exchange"]
     assert exchange.attrs["slots"] == exchange.attrs["block_slots"] == E * K
     reference = _train_blocks_packed(
-        dataset.blocks.features, dataset.blocks.labels, _plane(dataset, residual),
+        dataset.blocks.features, dataset.blocks.labels,
+        jax.device_put(_plane(dataset, residual), dataset.blocks.offsets.sharding),
         dataset.blocks.weights, *_zero_state(E, S),
         **_coordinate(dataset)._solver_kwargs(),
     )
@@ -210,15 +226,25 @@ def test_train_is_the_packed_solver_fed_the_planes_buckets(chunks):
     E, K, S = blocks.features.shape
     sharded = _chunk_axis(blocks.features, chunks)
     plane = _plane(dataset, residual)
+    # the parent's operands: every bucket cut from the planes, placed as the
+    # store's own part is
+    features, labels, weights = (
+        np.asarray(a) for a in (blocks.features, blocks.labels, blocks.weights)
+    )
     parts = []
-    for start, end, kb, sb in _size_buckets(dataset):
+    for b, (start, end, kb, sb) in enumerate(_size_buckets(dataset)):
+        place = partial(jax.device_put, device=blocks.labels.parts[b].sharding)
+        n_b = chunks * (end - start)
         parts.append(
             _train_blocks_packed(
-                *_bucket_operands(
-                    (blocks.features, blocks.labels, blocks.weights),
-                    _chunk_rows(plane, chunks, start, end, kb), _zero_state(E, S),
-                    chunks, sharded, start, end, kb, sb,
+                jax.device_put(
+                    _chunk_rows(features, chunks, start, end, kb, sb),
+                    blocks.features.parts[b].sharding,
                 ),
+                place(_chunk_rows(labels, chunks, start, end, kb)),
+                place(_chunk_rows(plane, chunks, start, end, kb)),
+                place(_chunk_rows(weights, chunks, start, end, kb)),
+                *_zero_state(n_b, sb),
                 **coordinate._solver_kwargs(),
             )
         )

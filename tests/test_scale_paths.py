@@ -427,14 +427,14 @@ def _same_bucket_shape(ds_a, ds_b, solver):
 
 
 def test_bucket_operands_keep_every_row_on_its_chip():
-    """A bucket's operand takes rows [start, end) of every chunk; under the
-    P(data) sharding each chip contributes its own chunk's rows and holds
-    exactly those of the operand, and the results go back the same way."""
+    """A bucket's stored part holds rows [start, end) of every chunk; under
+    the P(data) sharding each chip holds exactly its own chunk's rows of it,
+    a state table is cut the same way, and the results go back the same way."""
     from photon_ml_tpu.game.coordinate import (
-        _bucket_operands,
         _chunk_axis,
         _concat_results,
         _size_buckets,
+        _state_rows,
     )
     from photon_ml_tpu.optimize import SolverResult
     from photon_ml_tpu.parallel import data_parallel_mesh, shard_entity_blocks
@@ -447,31 +447,36 @@ def test_bucket_operands_keep_every_row_on_its_chip():
     assert sharded == (mesh, "data") and _chunk_axis(ds.blocks.features, m) is None
     E, K, S = blocks.features.shape
     chunk_rows = E // m
-    host = np.asarray(ds.blocks.features)
-    w0 = np.arange(E * S, dtype=np.float64).reshape(E, S)
+    host = np.asarray(ds.blocks.features)  # the logical plane, assembled
+    assert blocks.features.segments == tuple(_size_buckets(ds))
+    w0 = jax.device_put(
+        np.arange(E * S, dtype=np.float64).reshape(E, S),
+        jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("data")),
+    )
     parts = []
-    for start, end, kb, sb in _size_buckets(ds):
-        ready = object()  # the bucket's offsets come cut, from the exchange
-        feats, labels, offsets, _, w0_b = _bucket_operands(
-            (blocks.features, blocks.labels, blocks.weights),
-            ready, (w0,), m, sharded, start, end, kb, sb,
-        )
-        assert offsets is ready
+    for b, (start, end, kb, sb) in enumerate(_size_buckets(ds)):
+        feats, labels = blocks.features.parts[b], blocks.labels.parts[b]
+        w0_b = _state_rows(w0, m, sharded, start, end, sb)
+        w0_host = _state_rows(np.asarray(w0), m, sharded, start, end, sb)
         n_b = end - start
         assert feats.shape == (m * n_b, kb, sb) and labels.shape == (m * n_b, kb)
-        assert isinstance(w0_b, np.ndarray) and w0_b.shape == (m * n_b, sb)
+        assert isinstance(w0_host, np.ndarray) and w0_b.shape == (m * n_b, sb)
         expected = host.reshape(m, chunk_rows, K, S)[:, start:end, :kb, :sb]
         np.testing.assert_array_equal(
             np.asarray(feats), expected.reshape(m * n_b, kb, sb)
         )
-        np.testing.assert_array_equal(
-            w0_b, w0.reshape(m, chunk_rows, S)[:, start:end, :sb].reshape(-1, sb)
-        )
-        home = {s.device: s.index[0] for s in blocks.features.addressable_shards}
+        want_w0 = np.asarray(w0).reshape(m, chunk_rows, S)[:, start:end, :sb]
+        np.testing.assert_array_equal(w0_host, want_w0.reshape(-1, sb))
+        np.testing.assert_array_equal(np.asarray(w0_b), w0_host)
+        home = {s.device: s.index[0] for s in w0.addressable_shards}
         for shard in feats.addressable_shards:
             chunk = home[shard.device].start // chunk_rows
             assert shard.index[0] == slice(chunk * n_b, (chunk + 1) * n_b)
             np.testing.assert_array_equal(np.asarray(shard.data), expected[chunk])
+        for shard in w0_b.addressable_shards:
+            chunk = home[shard.device].start // chunk_rows
+            assert shard.index[0] == slice(chunk * n_b, (chunk + 1) * n_b)
+            np.testing.assert_array_equal(np.asarray(shard.data), want_w0[chunk])
         rows = np.arange(E).reshape(m, chunk_rows)[:, start:end].reshape(-1)
         lane = jnp.asarray(rows, jnp.int32)
         wide = jnp.asarray(np.repeat(rows[:, None], sb, axis=1), jnp.float64)

@@ -107,17 +107,20 @@ def test_the_exchange_gathers_a_buckets_slots_on_its_own_chip(mesh, chunks):
     """The residual exchange over the dealt, P(data)-sharded blocks and the
     row-sharded residual: the only thing that crosses chips is the [N]
     residual, all-gathered once; every bucket's offsets come out sharded as
-    the blocks are; and nothing of a chip's [E/4, K] plane is materialised
-    (6% of its slots are gathered)."""
-    from photon_ml_tpu.game.coordinate import _bucket_offsets
+    the blocks are; and only the buckets' own slots are gathered (6% of the
+    logical [E/4, K] extent of a chip, which is stored nowhere)."""
+    from photon_ml_tpu.game.coordinate import _gather_bucket_offsets
 
     segments = SEGMENTS[chunks]
     assert all(end * chunks <= E for _, end, _, _ in segments)
-    active_rows, offsets = _sharded(mesh, (E, K), jnp.int32), _sharded(mesh, (E, K))
+    # the store's own per-bucket arrays (game/data.py BucketedArray.parts)
+    shapes = [(chunks * (end - start), kb) for start, end, kb, _ in segments]
+    active_parts = tuple(_sharded(mesh, shape, jnp.int32) for shape in shapes)
+    offset_parts = tuple(_sharded(mesh, shape) for shape in shapes)
+    offsets = offset_parts[0]
     residual = _sharded(mesh, (N_ROWS,))
-    compiled = _bucket_offsets.lower(
-        active_rows, offsets, residual,
-        segments=segments, chunks=chunks, sharded=(mesh, "data"),
+    compiled = _gather_bucket_offsets.lower(
+        active_parts, offset_parts, residual, chunks=chunks, sharded=(mesh, "data"),
     ).compile()
     crossing = [
         line.strip() for line in compiled.as_text().splitlines()
@@ -132,6 +135,10 @@ def test_the_exchange_gathers_a_buckets_slots_on_its_own_chip(mesh, chunks):
     ]
     for sharding in compiled.output_shardings:
         assert sharding.is_equivalent_to(offsets.sharding, 2)
+    # nothing the size of a chip's logical [E/4, K] extent, nor a narrow part
+    # re-laid row-major (a [n_b, 8] part padded to 128 lanes is 16 times
+    # itself; the gather runs on the transposed view, which the TPU holds
+    # already)
     chip_plane_bytes = E // CHIPS * K * 4
     assert compiled.memory_analysis().temp_size_in_bytes < chip_plane_bytes // 8 + N_ROWS * 4
 
@@ -182,3 +189,83 @@ def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_g
     assert total < limit_gb * 1e9, total
     # no [n, k] temporary padded to 128 lanes: n * 128 * 4 bytes each
     assert f"[{n},{k}]{{1,0:T(8,128)}}" not in compiled.as_text()
+
+
+# -- the GLMix-over-sparse-ids cell on ONE chip (benchmark/configs/glmix-sparse-user-1chip.json) --
+
+# its store's buckets (K_b, entities, S_b), from the user field's fixed quotas
+GLMIX_SPARSE_BUCKETS = [(256, 556, 439), (128, 480, 256), (64, 884, 256), (32, 1589, 128),
+                        (16, 2747, 64), (8, 271_921, 32)]
+GLMIX_SPARSE = dict(rows=1_179_648, users=278_177, slots=5, s_max=439)
+
+
+@pytest.mark.parametrize("kb,entities,sb", GLMIX_SPARSE_BUCKETS)
+def test_every_bucket_of_the_ragged_store_solves_on_one_chip(topo, kb, entities, sb):
+    """The packed solver at each of the cell's stored bucket shapes: it
+    compiles for the v5e, its arguments are the bucket's own arrays unpadded
+    (the TPU lays ``[E_b, K_b, S_b]`` out entity-minor), and the widest bucket
+    (556 x 256 x 439) holds under 3 GB with its L-BFGS history: the plane all
+    six would have been cut from is 278,177 x 256 x 439 x 4 = 125 GB."""
+    from jax.sharding import SingleDeviceSharding
+
+    from photon_ml_tpu.game.coordinate import _train_blocks_packed
+
+    one = SingleDeviceSharding(topo.devices[0])
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)  # noqa: E731
+    compiled = _train_blocks_packed.lower(
+        s(entities, kb, sb), s(entities, kb), s(entities, kb), s(entities, kb),
+        s(entities, sb), s(entities, sb), s(entities, sb),
+        task="logistic_regression", l2=1.0, l1=0.0, optimizer_type="LBFGS", tolerance=1e-6,
+        max_iterations=30, num_corrections=10, max_cg_iterations=20, max_improvement_failures=5,
+    ).compile()
+    memory = compiled.memory_analysis()
+    cells = entities * kb * sb * 4
+    assert memory.argument_size_in_bytes < 1.05 * (cells + 3 * entities * (kb + sb) * 4) + (1 << 20)
+    total = memory.temp_size_in_bytes + memory.argument_size_in_bytes + memory.output_size_in_bytes
+    assert total < 3e9, total
+
+
+def test_the_slot_score_holds_no_row_by_subspace_array_on_one_chip(topo):
+    """The random-effect score of the cell's 1,179,648 rows (5 slots each)
+    under 278,177 users' subspaces of up to 439 columns: positions by
+    bisection and the score by a gather at (entity, position) pairs, neither
+    holding an [n, 439] array (2.07 GB each; the densified form held two)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from photon_ml_tpu.models.game import ell_slot_positions, score_entity_ell_at
+
+    n, e, f, s_max = (GLMIX_SPARSE[k] for k in ("rows", "users", "slots", "s_max"))
+    one = SingleDeviceSharding(topo.devices[0])
+    s = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    positions = ell_slot_positions.lower(
+        s((e, s_max), jnp.int32), s((n,), jnp.int32), s((n, f), jnp.int32)
+    ).compile()
+    score = score_entity_ell_at.lower(
+        s((e, s_max)), s((n,), jnp.int32), s((n, f), jnp.int32), s((n, f), jnp.bool_), s((n, f))
+    ).compile()
+    row_by_subspace = n * s_max * 4
+    for compiled in (positions, score):
+        assert compiled.memory_analysis().temp_size_in_bytes < row_by_subspace // 8
+        assert f"[{n},{s_max}]" not in compiled.as_text()
+        # nor an [n, F] intermediate padded to 128 lanes (604 MB each)
+        assert f"[{n},{f}]{{1,0:T(8,128)" not in compiled.as_text()
+
+
+def test_the_exchange_re_lays_no_narrow_bucket_on_one_chip(topo):
+    """The residual exchange at the cell's stored bucket shapes: the K = 8
+    bucket's 271,921 x 8 rows re-laid row-major would pad to 128 lanes (139 MB
+    a copy, 0.45 GB of temporaries in all); gathered on the transposed view
+    the program holds no copy of any part."""
+    from jax.sharding import SingleDeviceSharding
+
+    from photon_ml_tpu.game.coordinate import _gather_bucket_offsets
+
+    one = SingleDeviceSharding(topo.devices[0])
+    s = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    shapes = [(entities, kb) for kb, entities, _ in GLMIX_SPARSE_BUCKETS]
+    compiled = _gather_bucket_offsets.lower(
+        tuple(s(shape, jnp.int32) for shape in shapes), tuple(s(shape) for shape in shapes),
+        s((GLMIX_SPARSE["rows"],)), chunks=1, sharded=None,
+    ).compile()
+    narrow = max(entities * 128 * 4 for kb, entities, _ in GLMIX_SPARSE_BUCKETS if kb < 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < narrow // 8
