@@ -37,6 +37,7 @@ from ..optimize import (
     solve_tron,
 )
 from ..optimize.common import abs_tolerances
+from ..optimize.lbfgs import history_account
 
 Array = jax.Array
 
@@ -233,6 +234,13 @@ class GLMProblem:
         # the two-pass objective comes as its steps too, whatever the layout:
         # a plain L-BFGS walks them (one matvec and one rmatvec an iteration)
         margins = margin_fns(obj) if fused is None else None
+        history = {}
+        if solver_config.normalized_type() != OptimizerType.TRON:
+            # how L-BFGS / OWL-QN keeps its correction pairs at this width, and
+            # what they hold on the device: from shapes, as the solver decides
+            history["history"], history["history_bytes"] = history_account(
+                int(batch.dim), solver_config.num_corrections, jnp.dtype(dtype).itemsize
+            )
         with obs.span(
             "fe.solve",
             coordinate=coordinate,
@@ -249,6 +257,7 @@ class GLMProblem:
             # sweep's model, under the other coordinates' scores as offsets
             warm=initial_model is not None,
             offsets=bool(residuals),
+            **history,
         ) as sp:
             # with a sink, an L-BFGS or OWL-QN solve adds ``line_search`` (the
             # search it ran: ``margins`` | ``points``) and ``line_search_evals``

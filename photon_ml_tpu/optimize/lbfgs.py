@@ -5,7 +5,10 @@ Replaces the reference's Breeze-backed LBFGS/OWLQN adapters
 single jit/vmap-safe implementation:
 
 - fixed-size (m, d) correction history with circular indexing (static shapes
-  for XLA; m = numCorrections, default 10);
+  for XLA; m = numCorrections, default 10). A wide one-lane solve keeps it as
+  ``[m, d_pad / 128, 128]`` instead, a pair one contiguous run of tiles
+  (``history_row_width``: the TPU tiles ``[m, d]`` eight rows to a tile, and
+  every read of one row of it is a strided copy);
 - two-loop recursion preconditioned by the gamma = s.y/y.y scaling;
 - weak-Wolfe line search by bisection/expansion (c1=1e-4, c2=0.9) run inside
   ``lax.while_loop`` with masked state so vmapped lanes freeze independently.
@@ -92,7 +95,44 @@ Array = jax.Array
 _C1 = 1e-4  # Armijo (sufficient decrease)
 _C2 = 0.9  # curvature
 
+_LANES = 128
+_TILE = 8 * _LANES  # the TPU's (8, 128) tile of 32-bit elements
+# The TPU lays an ``[m, d]`` history out in (8, 128) tiles: m = 10 rows pad to
+# 16, and row j is 512 bytes of every 4 KB tile, so each ``S[j]`` of the
+# two-loop recursion is first copied out, strided (2.84 ms a 219 MB row at a
+# tenth of the chip's bandwidth, forty times an iteration: PERF.md, PR 39).
+# From this width on a one-lane solve keeps a pair as one contiguous row
+# instead. Below it a row is under a thousand tiles and its copy microseconds,
+# and the programs there are pinned letter for letter (tests/test_glm_enet.py's
+# golden hashes, tests/test_re_build.py's bucketed-against-flat equality).
+HISTORY_ROWS_MIN_DIM = 1 << 20
 
+
+def _round_up(x: int, multiple: int) -> int:
+    return -(-x // multiple) * multiple
+
+
+def history_row_width(w_shape: Tuple[int, ...], batched: bool) -> Optional[int]:
+    """How a solve over coefficients of ``w_shape`` stores its correction
+    pairs, decided on the shape alone: ``d_pad`` (d rounded up to whole tiles)
+    when the history is ``[m, d_pad / 128, 128]``, a pair one contiguous,
+    unpadded run of tiles; None for ``[m, *w_shape]``, which the TPU tiles over
+    (pair, column). Only a one-lane solve at least ``HISTORY_ROWS_MIN_DIM`` wide
+    keeps rows: the packed lanes' ``[m, S, E]`` is entity-minor already."""
+    if batched or len(w_shape) != 1 or w_shape[0] < HISTORY_ROWS_MIN_DIM:
+        return None
+    return _round_up(w_shape[0], _TILE)
+
+
+def history_account(dim: int, num_corrections: int, itemsize: int) -> Tuple[str, int]:
+    """What a host-level solve over ``dim`` coefficients keeps as its history
+    on the TPU, from shapes alone (the ``fe.solve`` span's ``history`` and
+    ``history_bytes``): ``rows`` and 2 m d_pad elements, or ``tiled`` and the
+    (8, 128) tiling's 2 x (m rounded up to 8) x (d rounded up to 128)."""
+    d_pad = history_row_width((dim,), batched=False)
+    if d_pad is not None:
+        return "rows", 2 * num_corrections * d_pad * itemsize
+    return "tiled", 2 * _round_up(num_corrections, 8) * _round_up(dim, _LANES) * itemsize
 
 
 def _pseudo_gradient(w: Array, g: Array, l1: Array) -> Array:
@@ -112,6 +152,10 @@ def _two_loop(
 
     S, Y: [m, d]; rho: [m]; count = #valid pairs; head = index of next write.
     Slot order from newest to oldest: head-1, head-2, ...
+    A history kept by rows (``history_row_width``: S, Y ``[m, d_pad / 128,
+    128]`` for a g of ``[d]``) runs the same recursion over d_pad entries, the
+    tail exact zeros: g is padded once, a pair's row read in place, and the
+    direction's first d entries handed back.
 
     ``unroll=True`` (the batched entity-minor mode) runs two fully-unrolled
     ``lax.scan``s over the history rotated into newest-first order (``roll``
@@ -161,6 +205,17 @@ def _two_loop(
         )
         return r
 
+    d = g.shape[0]
+    by_rows = S.ndim == g.ndim + 2
+    if by_rows:
+        g = jnp.pad(g, (0, S.shape[1] * S.shape[2] - d))
+
+    def pair(H, j):
+        # a row's [R, 128] tiles are its d_pad entries in order: the TPU
+        # re-reads them as [d_pad] for nothing (never reshape H itself: [m,
+        # d_pad] is the tiled layout again)
+        return H[j].reshape(-1) if by_rows else H[j]
+
     def newest_to_oldest(i):
         return (head - 1 - i) % m
 
@@ -168,8 +223,8 @@ def _two_loop(
         q, alphas = carry
         j = newest_to_oldest(i)
         valid = i < count
-        alpha = jnp.where(valid, rho[j] * _vdot(S[j], q), 0.0)
-        q = q - jnp.where(valid, alpha, 0.0) * Y[j]
+        alpha = jnp.where(valid, rho[j] * _vdot(pair(S, j), q), 0.0)
+        q = q - jnp.where(valid, alpha, 0.0) * pair(Y, j)
         return q, alphas.at[i].set(alpha)
 
     q, alphas = jax.lax.fori_loop(
@@ -177,8 +232,8 @@ def _two_loop(
     )
 
     newest = newest_to_oldest(0)
-    ys = _vdot(S[newest], Y[newest])
-    yy = _vdot(Y[newest], Y[newest])
+    ys = _vdot(pair(S, newest), pair(Y, newest))
+    yy = _vdot(pair(Y, newest), pair(Y, newest))
     gamma = jnp.where((count > 0) & (yy > 0), ys / jnp.where(yy > 0, yy, 1.0), 1.0)
     r = gamma * q
 
@@ -187,11 +242,12 @@ def _two_loop(
         idx = m - 1 - i
         j = newest_to_oldest(idx)
         valid = idx < count
-        beta = jnp.where(valid, rho[j] * _vdot(Y[j], r), 0.0)
-        r = r + jnp.where(valid, alphas[idx] - beta, 0.0) * S[j]
+        beta = jnp.where(valid, rho[j] * _vdot(pair(Y, j), r), 0.0)
+        r = r + jnp.where(valid, alphas[idx] - beta, 0.0) * pair(S, j)
         return r
 
-    return jax.lax.fori_loop(0, m, loop2, r)
+    r = jax.lax.fori_loop(0, m, loop2, r)
+    return r[:d] if by_rows else r
 
 
 def _verdict(s, f: Array, dg: Array, max_iters: int, decrease=None, slope=None):
@@ -457,6 +513,15 @@ def _solve(
     # an int32 beside the floats of the carry: it changes none of them
     counted = owlqn or count_evals
     walk = margins is not None
+    d_pad = history_row_width(w0.shape, batched)
+    history_shape = (m,) + w0.shape if d_pad is None else (m, d_pad // _LANES, _LANES)
+
+    def as_pair(v):
+        """s or y as the history stores a pair."""
+        if d_pad is None:
+            return v
+        # zeros in the tail: they add nothing to a dot of the recursion
+        return jnp.pad(v, (0, d_pad - v.shape[0])).reshape(history_shape[1:])
 
     def full_objective(w):
         f, g = value_and_grad(w)
@@ -505,8 +570,8 @@ def _solve(
         reason=jnp.where(
             bad0, int(ConvergenceReason.NUMERICAL_DIVERGENCE), 0
         ).astype(jnp.int32),
-        S=jnp.zeros((m,) + w0.shape, dtype),
-        Y=jnp.zeros((m,) + w0.shape, dtype),
+        S=jnp.zeros(history_shape, dtype),
+        Y=jnp.zeros(history_shape, dtype),
         rho=jnp.zeros((m,) + lanes, dtype),
         count=jnp.asarray(0, jnp.int32) if batched else jnp.zeros(lanes, jnp.int32),
         head=jnp.asarray(0, jnp.int32) if batched else jnp.zeros(lanes, jnp.int32),
@@ -582,8 +647,8 @@ def _solve(
             head = (s.head + 1) % m
             count = jnp.minimum(s.count + 1, m)
         else:
-            S = jnp.where(store, s.S.at[s.head].set(s_vec), s.S)
-            Y = jnp.where(store, s.Y.at[s.head].set(y_vec), s.Y)
+            S = jnp.where(store, s.S.at[s.head].set(as_pair(s_vec)), s.S)
+            Y = jnp.where(store, s.Y.at[s.head].set(as_pair(y_vec)), s.Y)
             rho = jnp.where(
                 store, s.rho.at[s.head].set(1.0 / jnp.where(sy != 0, sy, 1.0)), s.rho
             )

@@ -717,3 +717,130 @@ def test_in_float32_a_margin_walk_lands_where_the_points_search_does(layout, los
     assert len(drifts) >= int(walked.iterations) and max(drifts) <= 1e-5
     fresh_loss = obj32.value_and_grad(walked.coefficients)[0]
     assert float(walked.loss) == pytest.approx(float(fresh_loss), rel=5e-7)
+
+
+# -- the correction history kept by rows (a wide one-lane solve) ----------------------------------
+
+# just over the threshold and not a multiple of 1024: the last tile of a row is part zeros
+WIDE = lbfgs.HISTORY_ROWS_MIN_DIM + 37
+WIDE_PAD = lbfgs.HISTORY_ROWS_MIN_DIM + 1024
+
+
+def _wide_case(mode):
+    """A solve over ``WIDE`` coefficients as ``solve_lbfgs``'s keywords, cheap a
+    pass (a few thousand stored entries, or one elementwise expression), that
+    runs well past m = 10 iterations: the sparse fixed effect's own solve (a
+    logistic GLM over an ELL batch, its L-BFGS walking margins), an OWL-QN solve
+    and a box solve of a double well started where it is concave, so that the
+    first steps improve the objective under s.y < 0 and their pairs are refused."""
+    from photon_ml_tpu.ops import batch_from_coo, get_loss
+    from photon_ml_tpu.ops.glm import margin_fns, vg_fn
+    from photon_ml_tpu.optimize.common import MarginFns
+
+    rng = np.random.default_rng(41)
+    d = WIDE
+    tol = jnp.asarray(1e-9)
+    common = dict(loss_abs_tol=tol, grad_abs_tol=tol, count_evals=True)
+    if mode == "margins":
+        n, k = 1500, 5  # 161 columns all over the width, the last ones in the row's last tile
+        columns = np.append(rng.choice(d - 1, size=160, replace=False), d - 2)
+        r = np.repeat(np.arange(n), k)
+        c = np.concatenate([rng.choice(columns, size=(n, k - 1)), np.full((n, 1), d - 1)], axis=1).reshape(-1)
+        v = np.concatenate([3.0 * rng.normal(size=(n, k - 1)), np.ones((n, 1))], axis=1).reshape(-1)
+        y = (rng.uniform(size=n) < 0.4).astype(float)
+        batch = batch_from_coo(r, c, v, y, d, dtype=jnp.float64, layout="ell")
+        obj = GLMObjective(loss=get_loss("logistic"), batch=batch, l2=0.3)
+        return dict(value_and_grad=vg_fn(obj), w0=jnp.zeros(d), margins=MarginFns(*margin_fns(obj)), **common)
+    if mode == "owlqn":
+        scale = jnp.asarray(np.exp(rng.uniform(0.0, np.log(100.0), size=d)))
+        target = jnp.asarray(rng.normal(size=d))
+
+        def shifted_bowl(w):
+            r = w - target
+            return 0.5 * jnp.sum(scale * r * r), scale * r
+
+        return dict(value_and_grad=shifted_bowl, w0=jnp.zeros(d), l1_weight=0.5, **common)
+    scale = jnp.asarray(np.exp(rng.uniform(np.log(0.05), 0.0, size=d)))
+
+    def double_well(w):  # minima at -1 and 1, concave inside |w| < 0.577
+        return jnp.sum(scale * (0.25 * w ** 4 - 0.5 * w ** 2)), scale * (w ** 3 - w)
+
+    w0 = jnp.asarray(rng.uniform(0.05, 0.3, size=d) * rng.choice([-1.0, 1.0], size=d))
+    box = (jnp.full(d, -0.9), jnp.full(d, 2.0))  # the lower bound holds the coordinates that go left
+    return dict(value_and_grad=double_well, w0=w0, box_constraints=box, **common)
+
+
+@pytest.fixture
+def fresh_solver_programs():
+    """The history's layout is read while ``_solve`` traces: a test that moves
+    the threshold must not be answered from, nor leave behind, a traced program."""
+    lbfgs._solve.clear_cache()
+    yield
+    lbfgs._solve.clear_cache()
+
+
+@pytest.mark.parametrize("mode", ["margins", "owlqn", "box"])
+def test_a_history_kept_by_rows_takes_the_tiled_historys_steps(mode, monkeypatch, fresh_solver_programs):
+    """One algorithm, two storages: over the threshold a solve keeps each pair
+    as one ``[d_pad / 128, 128]`` row; the same solve with the threshold out of
+    reach keeps ``[m, d]``. The recursion's terms are the same and the tail's
+    are exact zeros, so in float64 the two take the same iterations, trials,
+    losses and coefficients, through the circular cursor's wrap (every case
+    runs past m iterations) and, in the box case, a refused pair."""
+    case = _wide_case(mode)
+    m = 10
+    tiles = f"f64[{m},{WIDE_PAD // 128},128]"
+
+    def traced():
+        return str(jax.make_jaxpr(lambda w: solve_lbfgs(**{**case, "w0": w}).coefficients)(case["w0"]))
+
+    assert lbfgs.history_row_width((WIDE,), False) == WIDE_PAD
+    assert tiles in traced()
+    by_rows = solve_lbfgs(**case)
+
+    monkeypatch.setattr(lbfgs, "HISTORY_ROWS_MIN_DIM", 1 << 62)
+    lbfgs._solve.clear_cache()
+    program = traced()
+    assert tiles not in program and f"f64[{m},{WIDE}]" in program
+    tiled = solve_lbfgs(**case)
+
+    assert int(by_rows.iterations) == int(tiled.iterations) > m + 5
+    assert int(by_rows.line_search_evals) == int(tiled.line_search_evals) > int(tiled.iterations) + 1
+    assert int(by_rows.reason) == int(tiled.reason) != ConvergenceReason.NOT_CONVERGED
+    k = int(tiled.iterations) + 1
+    for name in ("loss_history", "grad_norm_history"):
+        np.testing.assert_allclose(
+            np.asarray(getattr(by_rows, name)[:k]), np.asarray(getattr(tiled, name)[:k]), rtol=1e-12, err_msg=name)
+    np.testing.assert_allclose(
+        np.asarray(by_rows.coefficients), np.asarray(tiled.coefficients), rtol=0,
+        atol=1e-12 * float(jnp.max(jnp.abs(tiled.coefficients))))
+    if mode == "margins":
+        assert int(by_rows.matvecs) == int(by_rows.rmatvecs) == int(tiled.matvecs) == int(by_rows.iterations) + 1
+    if mode == "box":
+        # the first step was kept (the loss fell) and its pair was not: s.y < 0
+        first = solve_lbfgs(**case, max_iterations=1)
+        vg = case["value_and_grad"]
+        s = first.coefficients - case["w0"]
+        assert float(jnp.vdot(s, vg(first.coefficients)[1] - vg(case["w0"])[1])) < 0
+        assert float(tiled.loss_history[1]) == float(first.loss) < float(tiled.loss_history[0])
+
+
+@pytest.mark.parametrize("shape, batched, d_pad", [
+    ((lbfgs.HISTORY_ROWS_MIN_DIM,), False, lbfgs.HISTORY_ROWS_MIN_DIM),  # the threshold itself, whole tiles
+    ((WIDE,), False, WIDE_PAD),
+    ((54_686_453,), False, 427_240 * 128),  # the sparse cells' fixed effect
+    ((lbfgs.HISTORY_ROWS_MIN_DIM - 1,), False, None),
+    ((1024,), False, None),  # the dense cells' fixed effect under OWL-QN
+    ((32,), False, None),  # a vmapped per-entity lane
+    ((32, 271_921), True, None),  # the packed lanes, entity-minor
+    ((WIDE, 4), True, None),  # lambda lanes over a wide fixed effect
+])
+def test_only_a_wide_one_lane_solve_keeps_its_history_by_rows(shape, batched, d_pad):
+    assert lbfgs.history_row_width(shape, batched) == d_pad
+    if len(shape) == 1:
+        # what the span says is what the solver decides, and the bytes are the TPU's
+        layout, held = lbfgs.history_account(shape[0], 10, 4)
+        if d_pad is None:
+            assert (layout, held) == ("tiled", 2 * 16 * (-(-shape[0] // 128) * 128) * 4)
+        else:
+            assert (layout, held) == ("rows", 2 * 10 * d_pad * 4)

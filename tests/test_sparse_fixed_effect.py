@@ -442,6 +442,37 @@ def test_a_solve_that_cannot_walk_says_points_and_is_handed_no_margin_functions(
     assert not _series(run.registry, "photon_fe_feature_passes_total")
 
 
+@pytest.mark.parametrize("case", ["wide", "narrow", "owlqn", "tron"])
+def test_the_solve_span_says_how_the_history_is_kept(case):
+    """``history`` and ``history_bytes`` on ``fe.solve`` are host-known, from
+    the one function the solver decides by: ``rows`` (2 m d_pad elements) for
+    an L-BFGS or OWL-QN solve at least ``HISTORY_ROWS_MIN_DIM`` wide, ``tiled``
+    (the TPU's 16 padded rows of d rounded up to 128) under it; TRON keeps no
+    history and says nothing."""
+    from photon_ml_tpu.optimize import lbfgs
+
+    d = lbfgs.HISTORY_ROWS_MIN_DIM + 37 if case == "wide" else 403
+    batch = _ragged(604, d).to_batch(SHARD, dtype=jnp.float32, layout="ell")
+    config = _config(0.5, OptimizerType.TRON if case == "tron" else OptimizerType.LBFGS, max_iterations=3)
+    if case == "owlqn":
+        config = GLMOptimizationConfig(optimizer=config.optimizer, regularization=RegularizationContext("L1"),
+                                       reg_weight=0.5)
+    run, sink = obs.RunTelemetry(), _Spans()
+    run.register_listener(sink)
+    with obs.use_run(run):
+        GLMProblem(task="logistic_regression", config=config).run(batch, coordinate="global")
+    span, = [s for s in sink.spans if s.name == "fe.solve"]
+    assert span.attrs["dim"] == d
+    if case == "tron":
+        assert "history" not in span.attrs and "history_bytes" not in span.attrs
+    elif case == "wide":
+        d_pad = lbfgs.HISTORY_ROWS_MIN_DIM + 1024
+        assert (span.attrs["history"], span.attrs["history_bytes"]) == ("rows", 2 * 10 * d_pad * 4)
+        assert span.attrs["line_search"] == "margins"
+    else:
+        assert (span.attrs["history"], span.attrs["history_bytes"]) == ("tiled", 2 * 16 * 512 * 4)
+
+
 def _ell_estimator():
     return GameEstimator(
         task="logistic_regression",

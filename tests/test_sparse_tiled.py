@@ -114,6 +114,35 @@ def test_sharded_solve_equals_replicated_solve(rng):
     )
 
 
+def test_a_sharded_solve_wide_enough_for_a_history_by_rows_equals_the_one_device_solve(rng):
+    """The history's layout is decided on the coefficients' shape, not their
+    sharding (``lbfgs.history_row_width``): a column-sharded fixed effect past
+    the threshold keeps ``[m, d_pad / 128, 128]`` too, d_pad a multiple of
+    1024 and not of the shards' width. Same coefficients as the one-device
+    solve, through the circular cursor's wrap, and the result still sharded."""
+    from photon_ml_tpu.optimize import lbfgs
+
+    n, d, k = 400, lbfgs.HISTORY_ROWS_MIN_DIM + 37, 6
+    pool = np.append(rng.choice(d - 1, size=60, replace=False), d - 1)  # the last column among them
+    keys = np.unique(np.repeat(np.arange(n), k).astype(np.int64) * d + rng.choice(pool, size=n * k))
+    rows, cols, vals = keys // d, keys % d, rng.normal(size=len(keys))
+    y = (rng.uniform(size=n) < 0.4).astype(np.float64)
+    cfg = OptimizerConfig(tolerance=1e-10, max_iterations=200)
+
+    one = batch_from_coo(rows, cols, vals, y, d, dtype=jnp.float64, layout="coo")
+    reference = optimize(GLMObjective(loss=LOGISTIC, batch=one, l2=0.5).value_and_grad, jnp.zeros(d, jnp.float64), cfg)
+
+    mesh = make_mesh(n_data=2, n_model=4)
+    tb = tiled_sparse_batch(rows, cols, vals, y, d, mesh, dtype=jnp.float64)
+    assert lbfgs.history_row_width((tb.features.dim,), False) % (tb.features.dim // 4) != 0
+    w0 = replicated_coefficients(np.zeros(tb.features.dim), mesh, jnp.float64)
+    sharded = optimize(GLMObjective(loss=LOGISTIC, batch=tb, l2=0.5).value_and_grad, w0, cfg)
+
+    assert int(sharded.iterations) == int(reference.iterations) > 10
+    assert sharded.coefficients.sharding.is_equivalent_to(w0.sharding, 1)
+    np.testing.assert_allclose(np.asarray(sharded.coefficients)[:d], np.asarray(reference.coefficients), atol=1e-10)
+
+
 def test_tiled_objective_value_grad_parity(rng):
     n, d, k = 128, 97, 3
     rows, cols, vals = _random_coo(rng, n, d, k)

@@ -146,22 +146,71 @@ def test_the_exchange_gathers_a_buckets_slots_on_its_own_chip(mesh, chunks):
 # -- the sparse cell's solver on ONE chip (benchmark/configs/logistic-sparse-1chip.json) --------
 
 
-@pytest.mark.parametrize("layout,limit_gb", [("ell", 11.0), ("coo", 10.6)])
+def history_row_copies(text, m, d):
+    """The operations of a compiled solve that read a correction history
+    (``[m, ...]`` of at least d columns a pair) and write a row-length array
+    without reading one: copies of a pair out of the history. The recursion's
+    own work reads q or r beside the pair (the dot, the axpy) or writes the
+    history (the update). On PR 39's parent this names the four that ran forty
+    times an iteration: two ``dynamic-slice_reduce_fusion`` ("reduced" over the
+    axis of length one) and two ``multiply_reduce_fusion`` (the same, scaled)."""
+
+    def f32_shapes(types):
+        return [tuple(int(x) for x in dims.split(",") if x)
+                for kind, dims in re.findall(r"\b(\w+)\[([\d,]*)\]", types) if kind == "f32"]
+
+    def is_history(shape):
+        return len(shape) >= 2 and shape[0] == m and int(np.prod(shape[1:])) >= d
+
+    def is_row(shape):
+        return bool(shape) and not is_history(shape) and d <= int(np.prod(shape)) < 2 * d
+
+    fusions = set(re.findall(r"calls=%([\w.\-]+)", text))
+    found, shapes, in_fusion = [], {}, False
+    for line in text.splitlines():
+        header = re.match(r"^(?:ENTRY )?%([\w.\-]+) \((.*)\) -> (.*) \{$", line)
+        if header:
+            name, params, result = header.groups()
+            in_fusion, shapes = name in fusions, {}
+            if in_fusion:
+                read = [s for p in re.findall(r"[\w.\-]+: (\w+\[[\d,]*\])", params) for s in f32_shapes(p)]
+                if any(map(is_history, read)) and any(map(is_row, f32_shapes(result))) and not any(map(is_row, read)):
+                    found.append(name)
+            continue
+        op = re.match(r"^\s+(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\((.*)", line)
+        if not op or in_fusion:
+            continue
+        # an unfused slice, copy or unit-axis reduce of a row, in a loop's body
+        name, result, kind, rest = op.groups()
+        shapes[name] = f32_shapes(result)
+        operands = re.findall(r"%([\w.\-]+)", rest.split("),")[0])
+        if kind in ("dynamic-slice", "slice", "copy", "reduce") and any(map(is_row, shapes[name])) and any(
+                is_history(s) for o in operands for s in shapes.get(o, [])):
+            found.append(name)
+    return found
+
+
+@pytest.mark.parametrize("layout,limit_gb", [("ell", 8.2), ("coo", 8.3)])
 def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_gb):
     """``jit__solve`` at one chip's whole share of the sparse deployment
     (2,359,296 rows x 12 slots into 54,686,453 columns, plain L-BFGS, m = 10;
-    the cell runs half those rows). The TPU tiles a
-    ``[10, d]`` history as (8, 128): 16 rows, 3.5 GB each, 7 GB the two, and
-    that is the floor. What this pins is the rest: with the ELL sums written
-    over ``[n, k]`` intermediates the compiler padded each to 128 lanes (1.2 GB,
-    three alive at once: 14.2 GB of temporaries, no room on a 16 GB chip); over
-    ``[k, n]`` (the row axis minor, as the arrays lie on the device) nothing is
-    padded and the solve held 10.66 GB in all (9.67 GB of it temporaries), as over sorted COO (10.47 GB).
+    the cell runs half those rows). The TPU tiles a ``[10, d]`` history as
+    (8, 128): 16 rows, 3.5 GB each, and every ``S[j]`` of the two-loop recursion
+    was a strided copy of a row out of it (four 219 MB copies a step, forty an
+    iteration: ``history_row_copies``). Since PR 39 a solve this wide keeps a
+    pair as one row of ``[10, 427240, 128]`` (``lbfgs.history_row_width``): no
+    padded row (2.19 GB each, 4.37 GB the two), a step of either loop the dot
+    and the axpy with the row's slice fused into them, and 6.16 GB of
+    temporaries where the tiled history held 8.78. What this also pins is the
+    rest: with the ELL sums written over ``[n, k]`` intermediates the compiler
+    padded each to 128 lanes (1.2 GB, three alive at once: 14.2 GB of
+    temporaries, no room on a 16 GB chip); over ``[k, n]`` (the row axis minor,
+    as the arrays lie on the device) nothing is padded, as over sorted COO.
     Since PR 37 the solve walks margins (what ``GLMProblem.run`` hands a two-pass
-    objective's L-BFGS): 8.78 GB of temporaries under either layout, the search's
-    loop carrying scalars; the objective comes as an argument once for every
-    margin step that reads it (the same buffers, counted each time: 10.39 /
-    10.50 GB as counted here)."""
+    objective's L-BFGS), the search's loop carrying scalars; the objective comes
+    as an argument once for every margin step that reads it (the same buffers,
+    counted each time: 7.77 / 7.88 GB as counted here, 10.39 / 10.50 over the
+    tiled history)."""
     from jax.sharding import SingleDeviceSharding
 
     from photon_ml_tpu.ops.features import FeatureMatrix, LabeledBatch
@@ -186,9 +235,15 @@ def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_g
     ).compile()
     memory = compiled.memory_analysis()
     total = memory.temp_size_in_bytes + memory.argument_size_in_bytes + memory.output_size_in_bytes
+    assert memory.temp_size_in_bytes < 6.5e9, memory.temp_size_in_bytes
     assert total < limit_gb * 1e9, total
+    text = compiled.as_text()
     # no [n, k] temporary padded to 128 lanes: n * 128 * 4 bytes each
-    assert f"[{n},{k}]{{1,0:T(8,128)}}" not in compiled.as_text()
+    assert f"[{n},{k}]{{1,0:T(8,128)}}" not in text
+    # the history lies by rows, nowhere tiled over (pair, column), and nothing copies a row out of it
+    rows = lbfgs.history_row_width((d,), False) // 128
+    assert f"f32[10,{d}]" not in text and f"f32[10,{rows},128]{{2,1,0:T(8,128)}}" in text
+    assert history_row_copies(text, 10, d) == []
 
 
 # -- the GLMix-over-sparse-ids cell on ONE chip (benchmark/configs/glmix-sparse-user-1chip.json) --
