@@ -37,7 +37,7 @@ from ..optimize import (
     solve_tron,
 )
 from ..optimize.common import abs_tolerances
-from ..optimize.lbfgs import history_account
+from ..optimize.lbfgs import history_account, history_row_width, state_partition, state_shards
 
 Array = jax.Array
 
@@ -92,6 +92,45 @@ def _fusion_mode(batch: LabeledBatch):
     if mode == "interpret":
         return "interpret", mesh
     return ("compiled", mesh) if jax.default_backend() == "tpu" else none
+
+
+def splits_state(layout: str, kind: OptimizerType, dim: int, data_shards: int) -> bool:
+    """THE rule by which a fixed effect's coefficient-length solver state is
+    split over the chips, from what the host knows: an ELL batch whose rows
+    are sharded over a data axis of more than one device, a solver that keeps
+    a history (L-BFGS, OWL-QN), and coefficients wide enough for it to keep
+    that history by rows (``lbfgs.history_row_width``: d >= 2^20). At d =
+    187.8M the history alone is 15 GB whole: split, each chip holds its
+    quarter and gathers the vector for a pass (PERF.md, PR 40). The planner
+    names the split by this rule; ``state_sharding`` applies it to a batch."""
+    return (
+        layout == "ell"
+        and kind != OptimizerType.TRON
+        and data_shards > 1
+        and history_row_width((int(dim),), False) is not None
+    )
+
+
+def state_sharding(batch: LabeledBatch, solver_config: OptimizerConfig):
+    """The data axis's ``NamedSharding`` of the coefficients where
+    ``splits_state`` holds for ``batch`` (an ELL batch whose rows are
+    sharded over the data axis); None otherwise (every one-chip solve, a
+    narrow or TRON solve on a mesh, a tiled batch, whose own rule splits w
+    over the model axis)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ..parallel.mesh import DATA_AXIS
+
+    f = batch.features
+    idx = getattr(f, "idx", None)  # the ELL layout's; a tiled batch has none
+    rows = getattr(idx, "sharding", None)
+    if isinstance(idx, jax.core.Tracer) or not isinstance(rows, NamedSharding):
+        return None
+    spec = tuple(rows.spec)
+    shards = rows.mesh.shape.get(DATA_AXIS, 1) if spec and spec[0] == DATA_AXIS else 1
+    if not splits_state(f.layout, solver_config.normalized_type(), batch.dim, shards):
+        return None
+    return NamedSharding(rows.mesh, PartitionSpec(DATA_AXIS))
 
 
 def _pad_dim(v: Array, dim: int, fill: float) -> Array:
@@ -149,6 +188,7 @@ class GLMProblem:
         batch: LabeledBatch,
         fused: Optional[str] = None,
         fused_mesh=None,
+        state=None,
     ) -> GLMObjective:
         norm = self._norm_for(batch)
         prior_mean = prior_precision = None
@@ -176,7 +216,28 @@ class GLMProblem:
             prior_precision=prior_precision,
             fused=fused,
             fused_mesh=fused_mesh,
+            state_sharding=state,
         )
+
+    def solve_objective(
+        self, batch: LabeledBatch, solver_config: Optional[OptimizerConfig] = None
+    ) -> Tuple[GLMObjective, object]:
+        """The objective ``run`` hands the solver for ``batch``, and the
+        sharding of the solver's coefficient-length state (``state_sharding``;
+        None: not split). A split state runs over d_pad columns
+        (``lbfgs.history_row_width``: zeros past d, no row holds them and the
+        ridge keeps them 0), so that every chip's part of every
+        coefficient-length array is whole rows of its history."""
+        state = state_sharding(batch, solver_config or self.config.solver_config())
+        if state is not None:
+            from ..parallel.mesh import DATA_AXIS
+
+            wide = history_row_width((int(batch.dim),), False, state.mesh.shape[DATA_AXIS])
+            batch = dataclasses.replace(
+                batch, features=dataclasses.replace(batch.features, dim=wide)
+            )
+        fused, fused_mesh = _fusion_mode(batch)
+        return self.objective(batch, fused=fused, fused_mesh=fused_mesh, state=state), state
 
     def run(
         self,
@@ -203,8 +264,10 @@ class GLMProblem:
             from ..ops.glm import check_full_variance_dim
 
             check_full_variance_dim(batch.dim)
-        fused, fused_mesh = _fusion_mode(batch)
-        obj = self.objective(batch, fused=fused, fused_mesh=fused_mesh)
+        solver_config = self.config.solver_config()
+        dim = int(batch.dim)
+        obj, state = self.solve_objective(batch, solver_config)
+        batch, fused = obj.batch, obj.fused
         dtype = batch.labels.dtype
         if initial_model is not None:
             w0 = jnp.asarray(initial_model.coefficients.means, dtype)
@@ -227,20 +290,38 @@ class GLMProblem:
             from ..parallel.sparse import MODEL_AXIS
 
             w0 = reshard(jnp.asarray(w0, dtype), mesh, PartitionSpec(MODEL_AXIS))
+        elif state is not None:
+            # the same for a row-sharded batch over the data axis: the solver
+            # keeps its state as w0 is split (lbfgs.state_partition)
+            from ..parallel.multihost import reshard
+
+            w0 = reshard(jnp.asarray(w0, dtype), state.mesh, state.spec)
 
         from ..ops.glm import hvp_fn, margin_fns, vg_fn
 
-        solver_config = self.config.solver_config()
         # the two-pass objective comes as its steps too, whatever the layout:
         # a plain L-BFGS walks them (one matvec and one rmatvec an iteration)
         margins = margin_fns(obj) if fused is None else None
         history = {}
         if solver_config.normalized_type() != OptimizerType.TRON:
             # how L-BFGS / OWL-QN keeps its correction pairs at this width, and
-            # what they hold on the device: from shapes, as the solver decides
+            # what they hold on EACH device: from shapes, as the solver decides
+            # (the solver splits its state as w0 is split: over the data axis
+            # by the rule above, over the model axis for a tiled batch)
+            placed = state_partition(w0)
+            shards = state_shards(placed)
+            itemsize = jnp.dtype(dtype).itemsize
             history["history"], history["history_bytes"] = history_account(
-                int(batch.dim), solver_config.num_corrections, jnp.dtype(dtype).itemsize
+                dim, solver_config.num_corrections, itemsize, shards
             )
+            history["state_sharding"] = "replicated" if placed is None else str(placed.spec[0])
+            history["state_shards"] = shards
+            if state is not None:
+                # what one gather's all-gather and one scatter-add's
+                # reduce-scatter move into / out of each chip: the chip's
+                # missing (shards - 1) / shards of a d_pad vector
+                # (obs.record_solver_metrics counts them by the solve's passes)
+                history["collective_bytes"] = (shards - 1) * (int(batch.dim) // shards) * itemsize
         with obs.span(
             "fe.solve",
             coordinate=coordinate,
@@ -250,7 +331,7 @@ class GLMProblem:
             l2_weight=float(obj.l2),
             # what one pass touches, from shapes and the build: no fetch
             layout=getattr(batch.features, "layout", None),
-            dim=int(batch.dim),
+            dim=dim,
             slots=getattr(batch.features, "slots", None),
             nnz=nnz,
             # a solve inside coordinate descent: warm-started from the last
@@ -277,6 +358,15 @@ class GLMProblem:
                 means = self._norm_for(batch).model_to_original_space(means)
                 sp.sync(means)
             # variances stay in transformed space in the reference as well
+        if state is not None:
+            # back to the batch's own d (the d_pad tail is exact zeros), whole
+            # on every chip as the model a driver holds: a score then gathers
+            # from it for its own rows with no collective
+            from jax.sharding import PartitionSpec
+            from ..parallel.multihost import reshard
+
+            means = reshard(means[:dim], state.mesh, PartitionSpec())
+            variances = None if variances is None else variances[:dim]
 
         model = model_for_task(
             self.task, Coefficients(means=means, variances=variances)

@@ -273,6 +273,18 @@ def _record_fe_path(reg, line_search_evals: int, orthant_zeroed: Optional[int] =
         )
         passes.labels(coordinate=coordinate, kind="matvec").inc(matvecs)
         passes.labels(coordinate=coordinate, kind="rmatvec").inc(rmatvecs)
+        per_pass = solve_span.attrs.get("collective_bytes")
+        if per_pass:
+            # a state split over the chips (game/problem.py state_sharding):
+            # every gather reads the vector all-gathered, every scatter-add's
+            # sum is reduce-scattered, each moving ``collective_bytes`` a chip
+            moved = reg.counter(
+                "photon_fe_collective_bytes_total",
+                "bytes a chip moves in the all-gathers and reduce-scatters of "
+                "fixed-effect solves whose coefficient-length state is sharded",
+            )
+            moved.labels(coordinate=coordinate, kind="all_gather").inc(matvecs * per_pass)
+            moved.labels(coordinate=coordinate, kind="reduce_scatter").inc(rmatvecs * per_pass)
     if nonzeros is None:
         return
     solve_span.attrs["nonzeros"] = nonzeros

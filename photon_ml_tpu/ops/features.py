@@ -64,6 +64,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 Array = jax.Array
 
@@ -177,6 +178,48 @@ class FeatureMatrix:
         return jnp.zeros(self.dim, dtype=contrib.dtype).at[self.coo_cols].add(
             contrib, indices_are_sorted=True
         )
+
+    def matvec_gathered(self, w: Array, sharding) -> Array:
+        """``matvec`` of an ELL matrix whose rows are sharded over the axis
+        ``sharding`` (a ``NamedSharding`` of a ``[d]`` vector) splits ``w``
+        over: each device all-gathers ``w`` once and gathers for its own rows.
+        Written under ``shard_map``: left to GSPMD, a gather or scatter whose
+        operand and indices are split over one axis all-gathers the INDICES
+        (a [k, n] array a pass) and runs every row on every device."""
+        axis = sharding.spec[0]
+
+        def local(w_part, idx, val):
+            whole = jax.lax.all_gather(w_part, axis, tiled=True)
+            return jnp.sum(val.T * jnp.take(whole, idx.T, axis=0), axis=0)
+
+        return jax.shard_map(
+            local, mesh=sharding.mesh, out_specs=P(axis),
+            in_specs=(P(axis), P(axis, None), P(axis, None)),
+        )(w, self.idx, self.val)
+
+    def rmatvec_scattered(self, c: Array, sharding) -> Array:
+        """``rmatvec`` of a row-sharded ELL matrix, split as ``sharding``
+        splits the result: each device scatter-adds its own rows into a local
+        ``[d]`` target, and the targets are reduce-scattered, every device
+        keeping the sum over all rows of the part it owns. ``dim`` must be
+        whole rows of 128 on every device (``lbfgs.history_row_width``): the
+        sum is reduce-scattered as ``[d / 128, 128]``, which the v5e's
+        compiler runs as its reduce-scatter fusion, where a ``[d]`` vector
+        becomes an all-reduce of all d and a slice (compiled for a v5e 2x2,
+        PERF.md, PR 40)."""
+        axis = sharding.spec[0]
+        dim = self.dim
+
+        def local(c_part, idx, val):
+            contrib = c_part[None, :] * val.T
+            g = jnp.zeros(dim, dtype=contrib.dtype).at[idx.T.reshape(-1)].add(contrib.reshape(-1))
+            rows = jax.lax.psum_scatter(g.reshape(-1, 128), axis, scatter_dimension=0, tiled=True)
+            return rows.reshape(-1)
+
+        return jax.shard_map(
+            local, mesh=sharding.mesh, out_specs=P(axis),
+            in_specs=(P(axis), P(axis, None), P(axis, None)),
+        )(c, self.idx, self.val)
 
     def rmatmat(self, c: Array) -> Array:
         """x^T @ c -> [d, L] for lane-stacked per-row weights c[n, L]: the

@@ -111,9 +111,27 @@ class GLMObjective:
     fused_mesh: Optional[object] = dataclasses.field(
         default=None, metadata=dict(static=True)
     )
+    # The coefficients' sharding where the batch's rows AND the solver's
+    # coefficient-length state are split over the data axis (GLMProblem.run's
+    # rule, ``problem.state_sharding``): a gather reads the vector all-gathered,
+    # once a pass, and the scatter-add's local [d] sum is reduce-scattered onto
+    # the part each device owns. None = the vector is whole wherever it is read.
+    state_sharding: Optional[object] = dataclasses.field(
+        default=None, metadata=dict(static=True)
+    )
 
     def _norm(self) -> NormalizationContext:
         return self.norm if self.norm is not None else identity_normalization()
+
+    def _matvec(self, coef: Array) -> Array:
+        if self.state_sharding is None:
+            return self.batch.features.matvec(coef)
+        return self.batch.features.matvec_gathered(coef, self.state_sharding)
+
+    def _rmatvec(self, c: Array) -> Array:
+        if self.state_sharding is None:
+            return self.batch.features.rmatvec(c)
+        return self.batch.features.rmatvec_scattered(c, self.state_sharding)
 
     def _reg_delta(self, coef: Array) -> Array:
         return coef if self.prior_mean is None else coef - self.prior_mean
@@ -161,13 +179,13 @@ class GLMObjective:
     def margins(self, coef: Array) -> Array:
         """z = X eff(coef) + shift(coef) + offsets: the gather."""
         eff, mshift = self._norm().effective_coefficients(coef)
-        return self.batch.features.matvec(eff) + mshift + self.batch.offsets
+        return self._matvec(eff) + mshift + self.batch.offsets
 
     def direction_margins(self, direction: Array) -> Array:
         """u with ``margins(w + t p) = margins(w) + t u``: ``margins(p)``
         without the offsets."""
         eff, mshift = self._norm().effective_coefficients(direction)
-        return self.batch.features.matvec(eff) + mshift
+        return self._matvec(eff) + mshift
 
     def value_and_slope(
         self, z: Array, u: Array, t: Array, coef: Array, direction: Array
@@ -192,7 +210,7 @@ class GLMObjective:
         loss, dz = self.loss.loss_and_dz(z, b.labels)
         wdz = b.weights * dz
         value = jnp.sum(b.weights * loss)
-        raw_grad = b.features.rmatvec(wdz)
+        raw_grad = self._rmatvec(wdz)
         wdz_sum = jnp.sum(wdz) if self._norm().shifts is not None else None
         return self._finish_value_grad(coef, value, raw_grad, wdz_sum)
 
@@ -225,7 +243,7 @@ class GLMObjective:
                 hv = hv - norm.shifts * csum
         else:
             c = self._d2z_weights(coef) * self.direction_margins(v)
-            hv = b.features.rmatvec(c)
+            hv = self._rmatvec(c)
             if norm.shifts is not None:
                 hv = hv - norm.shifts * jnp.sum(c)
         if norm.factors is not None:

@@ -8,7 +8,9 @@ single jit/vmap-safe implementation:
   for XLA; m = numCorrections, default 10). A wide one-lane solve keeps it as
   ``[m, d_pad / 128, 128]`` instead, a pair one contiguous run of tiles
   (``history_row_width``: the TPU tiles ``[m, d]`` eight rows to a tile, and
-  every read of one row of it is a strided copy);
+  every read of one row of it is a strided copy). A w0 split over a mesh axis
+  splits the history along its columns the same way (``state_partition``),
+  each device's part whole rows;
 - two-loop recursion preconditioned by the gamma = s.y/y.y scaling;
 - weak-Wolfe line search by bisection/expansion (c1=1e-4, c2=0.9) run inside
   ``lax.while_loop`` with masked state so vmapped lanes freeze independently.
@@ -71,11 +73,13 @@ a converged or diverged lambda lane freezes at its last committed iterate
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import obs
 from .common import (
@@ -112,27 +116,56 @@ def _round_up(x: int, multiple: int) -> int:
     return -(-x // multiple) * multiple
 
 
-def history_row_width(w_shape: Tuple[int, ...], batched: bool) -> Optional[int]:
+def history_row_width(w_shape: Tuple[int, ...], batched: bool, shards: int = 1) -> Optional[int]:
     """How a solve over coefficients of ``w_shape`` stores its correction
-    pairs, decided on the shape alone: ``d_pad`` (d rounded up to whole tiles)
-    when the history is ``[m, d_pad / 128, 128]``, a pair one contiguous,
-    unpadded run of tiles; None for ``[m, *w_shape]``, which the TPU tiles over
-    (pair, column). Only a one-lane solve at least ``HISTORY_ROWS_MIN_DIM`` wide
-    keeps rows: the packed lanes' ``[m, S, E]`` is entity-minor already."""
+    pairs, decided on the shape and the state's shard count alone: ``d_pad``
+    (d rounded up to whole tiles, and to whole rows of 128 on each of
+    ``shards`` devices that split the state) when the history is ``[m, d_pad /
+    128, 128]``, a pair one contiguous, unpadded run of tiles; None for ``[m,
+    *w_shape]``, which the TPU tiles over (pair, column). Only a one-lane solve
+    at least ``HISTORY_ROWS_MIN_DIM`` wide keeps rows: the packed lanes' ``[m,
+    S, E]`` is entity-minor already."""
     if batched or len(w_shape) != 1 or w_shape[0] < HISTORY_ROWS_MIN_DIM:
         return None
-    return _round_up(w_shape[0], _TILE)
+    return _round_up(w_shape[0], math.lcm(_TILE, _LANES * shards))
 
 
-def history_account(dim: int, num_corrections: int, itemsize: int) -> Tuple[str, int]:
+def history_account(
+    dim: int, num_corrections: int, itemsize: int, shards: int = 1
+) -> Tuple[str, int]:
     """What a host-level solve over ``dim`` coefficients keeps as its history
-    on the TPU, from shapes alone (the ``fe.solve`` span's ``history`` and
-    ``history_bytes``): ``rows`` and 2 m d_pad elements, or ``tiled`` and the
-    (8, 128) tiling's 2 x (m rounded up to 8) x (d rounded up to 128)."""
-    d_pad = history_row_width((dim,), batched=False)
+    on EACH of the ``shards`` devices its state is split over, from shapes
+    alone (the ``fe.solve`` span's ``history`` and ``history_bytes``): ``rows``
+    and 2 m d_pad / shards elements, or ``tiled`` and the (8, 128) tiling's 2 x
+    (m rounded up to 8) x (a shard's columns rounded up to 128)."""
+    d_pad = history_row_width((dim,), False, shards)
     if d_pad is not None:
-        return "rows", 2 * num_corrections * d_pad * itemsize
-    return "tiled", 2 * _round_up(num_corrections, 8) * _round_up(dim, _LANES) * itemsize
+        return "rows", 2 * num_corrections * (d_pad // shards) * itemsize
+    width = _round_up(-(-dim // shards), _LANES)
+    return "tiled", 2 * _round_up(num_corrections, 8) * width * itemsize
+
+
+def state_partition(w) -> Optional[NamedSharding]:
+    """The sharding a one-lane solve's coefficient-length state takes: ``w``'s
+    own where ``w`` is a placed ``[d]`` array split along its axis over more
+    than one device of a mesh (a fixed effect's state sharded over the data
+    axis by ``GLMProblem.run``, or a tiled one's over the model axis); None
+    for a vector whole on one device or on each (every one-chip solve)."""
+    sharding = getattr(w, "sharding", None)
+    if isinstance(w, jax.core.Tracer) or not isinstance(sharding, NamedSharding):
+        return None
+    if jnp.ndim(w) != 1 or state_shards(sharding) <= 1:
+        return None
+    return sharding
+
+
+def state_shards(sharding: Optional[NamedSharding]) -> int:
+    """Devices a ``[d]`` vector under ``sharding`` is split over (1: none)."""
+    spec = tuple(sharding.spec) if sharding is not None else ()
+    if not spec or spec[0] is None:
+        return 1
+    axes = spec[0] if isinstance(spec[0], tuple) else (spec[0],)
+    return math.prod(sharding.mesh.shape[a] for a in axes)
 
 
 def _pseudo_gradient(w: Array, g: Array, l1: Array) -> Array:
@@ -487,6 +520,7 @@ class _LBFGSState(NamedTuple):
         "has_box",
         "batched",
         "count_evals",
+        "state_sharding",
     ),
 )
 def _solve(
@@ -504,6 +538,7 @@ def _solve(
     batched: bool = False,
     count_evals: bool = False,
     margins: Optional[MarginFns] = None,  # solve_lbfgs: plain one-lane L-BFGS only
+    state_sharding: Optional[NamedSharding] = None,  # solve_lbfgs: ``state_partition(w0)``
 ) -> SolverResult:
     m = num_corrections
     dtype = w0.dtype
@@ -513,8 +548,18 @@ def _solve(
     # an int32 beside the floats of the carry: it changes none of them
     counted = owlqn or count_evals
     walk = margins is not None
-    d_pad = history_row_width(w0.shape, batched)
+    d_pad = history_row_width(w0.shape, batched, state_shards(state_sharding))
     history_shape = (m,) + w0.shape if d_pad is None else (m, d_pad // _LANES, _LANES)
+
+    def pinned(H):
+        """The history split as the coefficients are, along its columns (each
+        device's part whole rows: ``history_row_width``), never replicated."""
+        if state_sharding is None:
+            return H
+        spec = (None, state_sharding.spec[0]) + (None,) * (H.ndim - 2)
+        return jax.lax.with_sharding_constraint(
+            H, NamedSharding(state_sharding.mesh, PartitionSpec(*spec))
+        )
 
     def as_pair(v):
         """s or y as the history stores a pair."""
@@ -570,8 +615,8 @@ def _solve(
         reason=jnp.where(
             bad0, int(ConvergenceReason.NUMERICAL_DIVERGENCE), 0
         ).astype(jnp.int32),
-        S=jnp.zeros(history_shape, dtype),
-        Y=jnp.zeros(history_shape, dtype),
+        S=pinned(jnp.zeros(history_shape, dtype)),
+        Y=pinned(jnp.zeros(history_shape, dtype)),
         rho=jnp.zeros((m,) + lanes, dtype),
         count=jnp.asarray(0, jnp.int32) if batched else jnp.zeros(lanes, jnp.int32),
         head=jnp.asarray(0, jnp.int32) if batched else jnp.zeros(lanes, jnp.int32),
@@ -654,8 +699,8 @@ def _solve(
             )
             head = jnp.where(store & ~keep, (s.head + 1) % m, s.head)
             count = jnp.where(store & ~keep, jnp.minimum(s.count + 1, m), s.count)
-            S = jnp.where(keep, s.S, S)
-            Y = jnp.where(keep, s.Y, Y)
+            S = pinned(jnp.where(keep, s.S, S))
+            Y = pinned(jnp.where(keep, s.Y, Y))
             rho = jnp.where(keep, s.rho, rho)
 
         evals = zeroed = None
@@ -792,6 +837,10 @@ def solve_lbfgs(
     (``SolverResult.line_search_evals``); the default leaves the plain
     program as it was, counter-free (the random effects' packed solves).
 
+    A one-lane ``w0`` placed split over a mesh axis (``state_partition``)
+    keeps every coefficient-length array of the solve, the history included,
+    split as it is.
+
     ``margins`` are the same objective as its steps (``MarginFns``: its
     margins are affine in w). A solve that can walk them does
     (:func:`walks_margins`) and never calls ``value_and_grad``; any other
@@ -820,6 +869,7 @@ def solve_lbfgs(
         MarginFns(*map(as_partial, margins))
         if walks_margins(margins, l1_weight, box_constraints, batched)
         else None,
+        None if batched else state_partition(w0),
     )
     obs.record_solver_metrics("lbfgs", result)
     return result

@@ -510,6 +510,31 @@ def _re_geometry(cc, axes, n_processes) -> Dict[str, object]:
     return geom
 
 
+def _state_geometry(cc, axes, dim) -> Dict[str, object]:
+    """Where a row-sharded fixed effect's solver state is split over the data
+    axis as well (``game/problem.py`` ``splits_state``; ``auto`` is ELL past
+    4,096 columns): the shard count, the columns the solve runs over and each
+    chip's share of them, and each chip's L-BFGS history. Empty where the rule
+    does not fire or ``dim`` is unknown."""
+    if dim is None or cc.layout not in ("auto", "ell"):
+        return {}
+    from ..game.problem import splits_state
+    from ..optimize.lbfgs import history_account, history_row_width
+
+    solver = cc.config.solver_config()
+    shards = axes[DATA_AXIS]
+    if not splits_state("ell", solver.normalized_type(), dim, shards):
+        return {}
+    d_pad = history_row_width((int(dim),), False, shards)
+    _, held = history_account(int(dim), solver.num_corrections, 4, shards)
+    return {
+        "state_shards": shards,
+        "state_columns": d_pad,
+        "state_columns_per_chip": d_pad // shards,
+        "history_bytes_per_chip": held,
+    }
+
+
 # -- the planner -------------------------------------------------------------
 
 
@@ -575,6 +600,10 @@ def resolve(
                 sharding = "row-sharded"
             dim = (dims or {}).get(cc.feature_shard)
             geometry = _fe_geometry(cc, axes, n_processes, dim)
+            state = _state_geometry(cc, axes, dim) if sharding == "row-sharded" else None
+            if state:
+                sharding = "row-sharded, state-sharded"
+                geometry.update(state)
         residency = "streamed" if streamed else "resident"
         if streamed:
             notes = notes + (

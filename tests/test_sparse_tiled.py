@@ -114,12 +114,15 @@ def test_sharded_solve_equals_replicated_solve(rng):
     )
 
 
-def test_a_sharded_solve_wide_enough_for_a_history_by_rows_equals_the_one_device_solve(rng):
-    """The history's layout is decided on the coefficients' shape, not their
-    sharding (``lbfgs.history_row_width``): a column-sharded fixed effect past
-    the threshold keeps ``[m, d_pad / 128, 128]`` too, d_pad a multiple of
-    1024 and not of the shards' width. Same coefficients as the one-device
-    solve, through the circular cursor's wrap, and the result still sharded."""
+@pytest.mark.parametrize("data, model", [(2, 4), (1, 8), (4, 2)])
+def test_a_sharded_solve_wide_enough_for_a_history_by_rows_equals_the_one_device_solve(rng, data, model):
+    """A column-sharded fixed effect past the threshold keeps ``[m, d_pad /
+    128, 128]`` too, split as its coefficients are (``lbfgs.state_partition``:
+    over the model axis), d_pad rounded to whole tiles AND to whole rows of
+    128 on every shard (``history_row_width`` with the shard count: PR 40; PR
+    39's d_pad fell inside a row at some shards' edges). Same coefficients as
+    the one-device solve, through the circular cursor's wrap, and the result
+    still sharded."""
     from photon_ml_tpu.optimize import lbfgs
 
     n, d, k = 400, lbfgs.HISTORY_ROWS_MIN_DIM + 37, 6
@@ -132,10 +135,12 @@ def test_a_sharded_solve_wide_enough_for_a_history_by_rows_equals_the_one_device
     one = batch_from_coo(rows, cols, vals, y, d, dtype=jnp.float64, layout="coo")
     reference = optimize(GLMObjective(loss=LOGISTIC, batch=one, l2=0.5).value_and_grad, jnp.zeros(d, jnp.float64), cfg)
 
-    mesh = make_mesh(n_data=2, n_model=4)
+    mesh = make_mesh(n_data=data, n_model=model)
     tb = tiled_sparse_batch(rows, cols, vals, y, d, mesh, dtype=jnp.float64)
-    assert lbfgs.history_row_width((tb.features.dim,), False) % (tb.features.dim // 4) != 0
     w0 = replicated_coefficients(np.zeros(tb.features.dim), mesh, jnp.float64)
+    assert lbfgs.state_shards(lbfgs.state_partition(w0)) == model
+    d_pad = lbfgs.history_row_width((tb.features.dim,), False, model)
+    assert d_pad % 1024 == 0 and (d_pad // model) % 128 == 0
     sharded = optimize(GLMObjective(loss=LOGISTIC, batch=tb, l2=0.5).value_and_grad, w0, cfg)
 
     assert int(sharded.iterations) == int(reference.iterations) > 10

@@ -324,3 +324,44 @@ def test_the_exchange_re_lays_no_narrow_bucket_on_one_chip(topo):
     ).compile()
     narrow = max(entities * 128 * 4 for kb, entities, _ in GLMIX_SPARSE_BUCKETS if kb < 128)
     assert compiled.memory_analysis().temp_size_in_bytes < narrow // 8
+
+
+# -- the Criteo cell on one 2x2 host (benchmark/configs/logistic-criteo-4chip.json) -------------
+
+
+def test_the_criteo_solve_splits_its_state_over_the_four_chips(topo, mesh):
+    """``jit__solve`` at the cell's shapes (2^21 rows x 40 slots into
+    187,767,413 columns, plain L-BFGS, m = 10), rows and state split over the
+    2x2 (``game/problem.py`` ``state_sharding``). Its history alone is 15.02 GB
+    whole; here each chip holds ``[10, 366734, 128]``, its quarter, and 6.19 GB
+    of temporaries in all. Per pass the vector is all-gathered once for the
+    gather and the scatter-add's local [d_pad] sum leaves the pass through the
+    TPU's reduce-scatter fusion (``all-reduce-scatter``; a ``[d]`` psum_scatter
+    would be an all-reduce of all d and a slice): no d-length all-reduce."""
+    from photon_ml_tpu.ops.features import FeatureMatrix, LabeledBatch
+    from photon_ml_tpu.ops.glm import GLMObjective, margin_fns, vg_fn
+    from photon_ml_tpu.ops.losses import get_loss
+    from photon_ml_tpu.optimize import lbfgs
+    from photon_ml_tpu.optimize.common import MarginFns, as_partial
+
+    n, k, d = 1 << 21, 40, 187_767_413
+    d_pad = lbfgs.history_row_width((d,), False, CHIPS)
+    vec, rep = NamedSharding(mesh, PartitionSpec("data")), NamedSharding(mesh, PartitionSpec())
+    s = lambda shape, sh, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)  # noqa: E731
+    rows = NamedSharding(mesh, PartitionSpec("data", None))
+    batch = LabeledBatch(features=FeatureMatrix(dim=d_pad, idx=s((n, k), rows, jnp.int32), val=s((n, k), rows)),
+                         labels=s((n,), vec), offsets=s((n,), vec), weights=s((n,), vec))
+    objective = GLMObjective(loss=get_loss("logistic_regression"), batch=batch, l2=1783.0, state_sharding=vec)
+    compiled = lbfgs._solve.lower(
+        as_partial(vg_fn(objective)), s((d_pad,), vec), s((), rep), s((), rep), 100, 10, None, 25, False,
+        s((d_pad,), vec), s((d_pad,), vec), False, True, MarginFns(*margin_fns(objective)), vec,
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 6.5e9, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    assert f"f32[10,{d_pad // CHIPS // 128},128]" in text and f"f32[10,{d_pad // 128},128]" not in text
+    gathers = re.findall(r"= f32\[4,1,(\d+)\]\S* all-gather\(", text)
+    assert gathers == [str(d_pad // CHIPS)] * 2  # the loop's pass and the first margins
+    assert len(re.findall(r"calls=%all-reduce-scatter", text)) == 2
+    assert not re.search(rf"f32\[{d_pad}\]\S* all-reduce\(", text)
+    assert not re.search(r"all-gather\(%\S+\), channel_id=\d+, replica_groups=\S+, dimensions=\{1\}", text)
