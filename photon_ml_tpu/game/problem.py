@@ -342,9 +342,12 @@ class GLMProblem:
         ) as sp:
             # with a sink, an L-BFGS or OWL-QN solve adds ``line_search`` (the
             # search it ran: ``margins`` | ``points``) and ``line_search_evals``
-            # here, OWL-QN ``nonzeros`` too (obs.record_solver_metrics)
+            # here, OWL-QN ``nonzeros`` too (obs.record_solver_metrics); every
+            # host-level solve adds ``start`` (``optimize``: a walking solve
+            # from no model starts from the state at zero)
             result = optimize(
-                vg_fn(obj), w0, solver_config, hvp=hvp_fn(obj), margins=margins
+                vg_fn(obj), w0, solver_config, hvp=hvp_fn(obj), margins=margins,
+                from_zero=initial_model is None,
             )
             sp.sync(result)
 

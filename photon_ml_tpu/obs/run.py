@@ -266,7 +266,12 @@ def _record_fe_path(reg, line_search_evals: int, orthant_zeroed: Optional[int] =
     ).labels(coordinate=coordinate).inc(line_search_evals)
     if matvecs is not None:
         # a search over points is not counted here: its evaluations are the
-        # counter above, and what one reads of X is its objective's to say
+        # counter above, and what one reads of X is its objective's to say.
+        # A walking solve's tolerance pass is the state at zero, one
+        # scatter-add (``optimize``); unless the solve started from it
+        # (``start=zero_state``, counted in its own), it is one pass more
+        if solve_span.attrs.get("start") == "evaluated":
+            rmatvecs += 1
         passes = reg.counter(
             "photon_fe_feature_passes_total",
             "passes over the feature matrix made by fixed-effect L-BFGS solves that walk margins",

@@ -181,6 +181,11 @@ class GLMObjective:
         eff, mshift = self._norm().effective_coefficients(coef)
         return self._matvec(eff) + mshift + self.batch.offsets
 
+    def zero_margins(self) -> Array:
+        """``margins(0)`` with no gather: at zero coefficients eff and shift
+        are 0 (normalized or not), so the margins are the offsets."""
+        return self.batch.offsets
+
     def direction_margins(self, direction: Array) -> Array:
         """u with ``margins(w + t p) = margins(w) + t u``: ``margins(p)``
         without the offsets."""
@@ -466,15 +471,19 @@ def _grad_from_margins(obj: "GLMObjective", z: Array, coef: Array):
     return obj.grad_from_margins(z, coef)
 
 
+def _zero_margins(obj: "GLMObjective") -> Array:
+    return obj.zero_margins()
+
+
 def margin_fns(obj: GLMObjective):
     """The two-pass objective's steps for a search that walks margins, in the
     order of ``optimize.common.MarginFns`` (margins, direction_margins,
-    value_and_slope, grad_from_margins), each jit-cache-stable as ``vg_fn``
-    is. For the two-pass path: the fused kernels read X once for value and
-    gradient, and a solve over them keeps ``vg_fn``."""
+    value_and_slope, grad_from_margins, zero_margins), each jit-cache-stable
+    as ``vg_fn`` is. For the two-pass path: the fused kernels read X once for
+    value and gradient, and a solve over them keeps ``vg_fn``."""
     return tuple(
         jax.tree_util.Partial(fn, obj)
-        for fn in (_margins, _direction_margins, _value_and_slope, _grad_from_margins)
+        for fn in (_margins, _direction_margins, _value_and_slope, _grad_from_margins, _zero_margins)
     )
 
 

@@ -45,6 +45,20 @@ class MarginFns(NamedTuple):
     # (z, u, t, w, p) -> F(w + t p), dF/dt: row-length sums, no features
     value_and_slope: Callable[[Array, Array, Array, Array, Array], Tuple[Array, Array]]
     grad_from_margins: Callable[[Array, Array], Tuple[Array, Array]]  # (z, w) -> F, grad (one rmatvec)
+    zero_margins: Callable[[], Array]  # () -> margins(0), with no matvec (a GLM's offsets)
+
+
+class SolveStart(NamedTuple):
+    """Where a solve that walks margins starts, handed to it as operands: the
+    objective, its gradient and the margins at w0, and the passes over the
+    features taking them made (int32; the solve counts its own on top): one
+    of each at w0, a scatter-add alone where w0 is zero."""
+
+    f: Array
+    g: Array
+    z: Array
+    matvecs: Array
+    rmatvecs: Array
 
 
 class ConvergenceReason(enum.IntEnum):
@@ -127,9 +141,10 @@ class SolverResult:
     orthant_zeroed: Optional[Array] = None
     nonzeros: Optional[Array] = None
     # a counting L-BFGS solve whose search walked margins alone (i32): the
-    # passes over the features it made, ``direction_margins`` and the first
-    # ``margins`` / ``grad_from_margins``. None where every evaluation is a
-    # pass of each kind (``line_search_evals`` then counts them)
+    # passes over the features its start and its iterations made
+    # (``SolveStart``: a start from the state at zero is that state's one
+    # rmatvec, no matvec). None where every evaluation is a pass of each kind
+    # (``line_search_evals`` then counts them)
     matvecs: Optional[Array] = None
     rmatvecs: Optional[Array] = None
 
@@ -200,10 +215,39 @@ def as_partial(fn):
     return jax.tree_util.Partial(fn)
 
 
+def as_margin_fns(margins) -> MarginFns:
+    """``margins`` (any sequence in ``MarginFns`` order) with each function a
+    jit-cache-stable ``Partial`` (``as_partial``)."""
+    return MarginFns(*map(as_partial, margins))
+
+
+def _start(f0: Array, g0: Array, z0: Array, matvecs: int, rmatvecs: int) -> SolveStart:
+    return SolveStart(f0, g0, z0, jnp.asarray(matvecs, jnp.int32), jnp.asarray(rmatvecs, jnp.int32))
+
+
+def margin_start(margins: MarginFns, w0: Array) -> SolveStart:
+    """The start of a walking solve at ``w0``: one matvec, one rmatvec."""
+    z0 = margins.margins(w0)
+    f0, g0 = margins.grad_from_margins(z0, w0)
+    return _start(f0, g0, z0, 1, 1)
+
+
 @jax.jit
-def _abs_tolerances_impl(value_and_grad, zero_like: Array, tolerance: Array):
-    f0, g0 = value_and_grad(jnp.zeros_like(zero_like))
-    return jnp.abs(f0) * tolerance, _norm(g0) * tolerance
+def _abs_tolerances_impl(
+    value_and_grad, zero_like: Array, tolerance: Array,
+    margins: Optional[MarginFns] = None, start_at: Optional[Array] = None,
+):
+    if margins is None:
+        f0, g0 = value_and_grad(jnp.zeros_like(zero_like))
+        return jnp.abs(f0) * tolerance, _norm(g0) * tolerance
+    # a GLM's margins are X eff(w) + shift(w) + offsets, and at w = 0 both
+    # eff and shift are 0: the state at zero is one scatter-add, no gather
+    zeros = jnp.zeros_like(zero_like)
+    z0 = margins.zero_margins()
+    f0, g0 = margins.grad_from_margins(z0, zeros)
+    # a solve from zero starts from this very state
+    start = _start(f0, g0, z0, 0, 1) if start_at is None else margin_start(margins, start_at)
+    return jnp.abs(f0) * tolerance, _norm(g0) * tolerance, start
 
 
 def abs_tolerances(
@@ -213,6 +257,19 @@ def abs_tolerances(
     (Optimizer.scala:65-69 + :171)."""
     return _abs_tolerances_impl(
         as_partial(value_and_grad), zero_like, jnp.asarray(tolerance, zero_like.dtype)
+    )
+
+
+def walking_tolerances(
+    margins: MarginFns, w0: Array, tolerance: float, from_zero: bool
+) -> Tuple[Array, Array, SolveStart]:
+    """``abs_tolerances`` of a solve that walks margins, and its start, in
+    one program: the state at zero from ``zero_margins`` (no matvec); a solve
+    ``from_zero`` (w0 is zeros, as the caller knows) starts from that state,
+    any other from margins(w0) and its gradient."""
+    return _abs_tolerances_impl(
+        None, w0, jnp.asarray(tolerance, w0.dtype), as_margin_fns(margins),
+        None if from_zero else w0,
     )
 
 

@@ -29,8 +29,9 @@ from .common import (
     SolverResult,
     ValueAndGradFn,
     abs_tolerances,
+    walking_tolerances,
 )
-from .lbfgs import solve_lbfgs
+from .lbfgs import solve_lbfgs, walks_margins
 from .tron import solve_tron
 
 Array = jnp.ndarray
@@ -42,23 +43,44 @@ def optimize(
     config: OptimizerConfig,
     hvp: Optional[HvpFn] = None,
     margins: Optional[MarginFns] = None,
+    from_zero: bool = False,
 ) -> SolverResult:
     """``hvp`` is TRON's; ``margins`` are the objective as its steps, for an
-    L-BFGS whose search can walk them (``lbfgs.walks_margins``)."""
+    L-BFGS whose search can walk them (``lbfgs.walks_margins``). ``from_zero``
+    says w0 is zeros, as the caller knows (no model to start from): a walking
+    solve then starts from the state at zero that sets its tolerances."""
     host_level = not isinstance(w0, jax.core.Tracer)
+    kind = config.normalized_type()
+    l1_weight = config.l1_weight if kind == OptimizerType.OWLQN else 0.0
+    # a walking solve's tolerance pass takes the margins at zero as the
+    # offsets (no matvec) and hands the solve its start; every other solve
+    # runs the pass at zero over ``value_and_grad`` and evaluates its own start
+    walk = kind != OptimizerType.TRON and walks_margins(
+        margins, l1_weight, config.box_constraints, batched=False
+    )
+
+    def tolerances():
+        if walk:
+            return walking_tolerances(margins, w0, config.tolerance, from_zero)
+        return (*abs_tolerances(value_and_grad, w0, config.tolerance), None)
+
     if not host_level:
         # traced inside a jitted train function: nothing host-level to time
         # (the rule obs.record_solver_metrics follows)
-        loss_tol, grad_tol = abs_tolerances(value_and_grad, w0, config.tolerance)
+        loss_tol, grad_tol, start = tolerances()
     else:
         # one more objective pass at zero coefficients, before every solve
-        # (the enclosing fe.solve span says whose solve this is)
+        # (the enclosing fe.solve span says whose solve this is): a scatter-add
+        # alone where the solve walks margins (``matvec`` False)
         solve_span = obs.current_span()
         coordinate = solve_span.attrs.get("coordinate") if solve_span else None
-        with obs.span("fe.tolerances", coordinate=coordinate) as sp:
-            loss_tol, grad_tol = abs_tolerances(value_and_grad, w0, config.tolerance)
+        if solve_span is not None and solve_span.name == "fe.solve":
+            # host-known: whether the solve starts from the state at zero or
+            # from a start evaluated at w0 (its own, or the tolerance pass's)
+            solve_span.attrs["start"] = "zero_state" if walk and from_zero else "evaluated"
+        with obs.span("fe.tolerances", coordinate=coordinate, matvec=not walk) as sp:
+            loss_tol, grad_tol, start = tolerances()
             sp.sync(loss_tol, grad_tol)
-    kind = config.normalized_type()
 
     if kind in (OptimizerType.LBFGS, OptimizerType.LBFGSB, OptimizerType.OWLQN):
         box = config.box_constraints
@@ -69,13 +91,14 @@ def optimize(
             grad_tol,
             max_iterations=config.max_iterations,
             num_corrections=config.num_corrections,
-            l1_weight=config.l1_weight if kind == OptimizerType.OWLQN else 0.0,
+            l1_weight=l1_weight,
             box_constraints=box,
             max_line_search_iterations=config.max_line_search_iterations,
             # a host-level solve counts its passes, sink or no sink (one
             # program either way); obs.record_solver_metrics reads the count
             count_evals=host_level,
             margins=margins,
+            start=start,
         )
     if kind == OptimizerType.TRON:
         if hvp is None:

@@ -24,8 +24,12 @@ single jit/vmap-safe implementation:
   phi(t), phi'(t) from row-length sums over z + t u, and the gradient is taken
   once, at the step kept, from z + t u: ONE matvec and ONE rmatvec an
   iteration however many lengths are tried, and no d-length array in the
-  search's state. z is carried from step to step (z + t u), computed from w
-  only at the solve's first evaluation; its drift is rounding (PERF.md, PR 37).
+  search's state. z is carried from step to step (z + t u), from the
+  solve's start; its drift is rounding (PERF.md). A walking solve is
+  HANDED its start (``common.SolveStart``: f, g and z at w0), so it makes no
+  pass before its first iteration: ``optimize`` takes it in the program that
+  sets the tolerances, where a solve from zero starts from the state at zero
+  itself (its margins are the offsets: no matvec).
   Which search runs is decided on what the solve is handed
   (``walks_margins``): margin functions, no l1 weight, no box, one lane.
   ``host_driver`` (streamed rows) mirrors the ``points`` search;
@@ -86,12 +90,15 @@ from .common import (
     ConvergenceReason,
     MarginFns,
     SolverResult,
+    SolveStart,
     ValueAndGradFn,
     _norm,
     _vdot,
+    as_margin_fns,
     as_partial,
     check_convergence,
     finite_state,
+    margin_start,
 )
 
 Array = jax.Array
@@ -539,6 +546,7 @@ def _solve(
     count_evals: bool = False,
     margins: Optional[MarginFns] = None,  # solve_lbfgs: plain one-lane L-BFGS only
     state_sharding: Optional[NamedSharding] = None,  # solve_lbfgs: ``state_partition(w0)``
+    start: Optional[SolveStart] = None,  # with ``margins``: f, g, z at w0 (operands)
 ) -> SolverResult:
     m = num_corrections
     dtype = w0.dtype
@@ -578,9 +586,8 @@ def _solve(
         w0 = jnp.clip(w0, box[0], box[1])  # start feasible
     z0 = None
     if margins is not None:
-        # the solve's one margins(w): every later z is z + t u
-        z0 = margins.margins(w0)
-        f0, g0 = margins.grad_from_margins(z0, w0)
+        # handed to the solve: every later z is z + t u
+        f0, g0, z0 = start.f, start.g, start.z
     else:
         f0, g0 = full_objective(w0)
     lanes = jnp.shape(f0)  # () single problem / [E] entity-minor batch
@@ -625,8 +632,8 @@ def _solve(
         evals=jnp.ones(lanes, jnp.int32) if counted else None,
         zeroed=jnp.zeros(lanes, jnp.int32) if owlqn else None,
         z=z0,
-        matvecs=jnp.ones(lanes, jnp.int32) if walk and counted else None,
-        rmatvecs=jnp.ones(lanes, jnp.int32) if walk and counted else None,
+        matvecs=start.matvecs if walk and counted else None,
+        rmatvecs=start.rmatvecs if walk and counted else None,
     )
 
     def cond(s: _LBFGSState):
@@ -791,6 +798,12 @@ def _solve(
     )
 
 
+@jax.jit
+def _evaluated_start(margins: MarginFns, w0: Array) -> SolveStart:
+    """A walking solve's start at w0 where none is handed to it."""
+    return margin_start(margins, w0)
+
+
 def walks_margins(margins, l1_weight: float, box_constraints, batched: bool) -> bool:
     """Whether a solve's line search walks margins (``_margin_search``) or
     evaluates points (``_line_search``). Only plain one-lane L-BFGS can walk:
@@ -818,6 +831,7 @@ def solve_lbfgs(
     batched: bool = False,
     count_evals: bool = False,
     margins: Optional[MarginFns] = None,
+    start: Optional[SolveStart] = None,
 ) -> SolverResult:
     """Minimize f(w) (+ l1*||w||_1 when ``l1_weight`` > 0) starting at w0.
 
@@ -845,13 +859,20 @@ def solve_lbfgs(
     margins are affine in w). A solve that can walk them does
     (:func:`walks_margins`) and never calls ``value_and_grad``; any other
     solve never sees them. A counting walk also reports its passes over the
-    features (``SolverResult.matvecs`` / ``rmatvecs``).
+    features (``SolverResult.matvecs`` / ``rmatvecs``). A walking solve
+    starts from ``start`` where one is handed (``common.walking_tolerances``),
+    else from one evaluated here at w0 (one matvec, one rmatvec).
     """
     has_box = box_constraints is not None
     zero = jnp.zeros_like(w0)
     lower, upper = box_constraints if has_box else (zero, zero)
     # the solver KIND is chosen here, from a host value; the weight is data
     l1 = jnp.asarray(l1_weight, w0.dtype) if float(l1_weight) > 0.0 else None
+    walk = walks_margins(margins, l1_weight, box_constraints, batched)
+    if walk:
+        margins = as_margin_fns(margins)
+        if start is None:
+            start = _evaluated_start(margins, w0)
     result = _solve(
         as_partial(value_and_grad),
         w0,
@@ -866,10 +887,9 @@ def solve_lbfgs(
         upper,
         batched,
         count_evals,
-        MarginFns(*map(as_partial, margins))
-        if walks_margins(margins, l1_weight, box_constraints, batched)
-        else None,
+        margins if walk else None,
         None if batched else state_partition(w0),
+        start if walk else None,
     )
     obs.record_solver_metrics("lbfgs", result)
     return result

@@ -719,6 +719,73 @@ def test_in_float32_a_margin_walk_lands_where_the_points_search_does(layout, los
     assert float(walked.loss) == pytest.approx(float(fresh_loss), rel=5e-7)
 
 
+# -- the state at zero from the offsets, and a solve handed its start --------------------------------
+
+
+def _same_solve(a, b):
+    assert (int(a.iterations), int(a.line_search_evals), int(a.reason)) == (
+        int(b.iterations), int(b.line_search_evals), int(b.reason))
+    for x, y in ((a.loss_history, b.loss_history), (a.grad_norm_history, b.grad_norm_history),
+                 (a.coefficients, b.coefficients), (a.gradient, b.gradient)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("variant", ["offsets_padded", "normalized", "prior"])
+@pytest.mark.parametrize("loss_name", ["logistic", "poisson", "squared", "smoothed_hinge"])
+@pytest.mark.parametrize("layout", ["ell", "coo", "dense"])
+def test_a_walking_solve_from_zero_starts_from_the_state_its_tolerances_read(layout, loss_name, variant):
+    """The tolerance pass of a solve that walks margins takes the margins at
+    zero as the offsets (X 0 = 0, and at zero normalization's factors and
+    shifts vanish) and hands a solve from zero that state as its start: the
+    same state as ``value_and_grad(0)``, the same tolerances, and the same
+    solve, iteration for iteration and bit for bit, as one that evaluates its
+    start itself; a warm solve is handed margins(w0) and its gradient from the
+    same program and is the solve it was. Only the passes counted move: a
+    cold solve makes no matvec and one rmatvec for its start."""
+    from photon_ml_tpu.ops.glm import margin_fns, vg_fn
+    from photon_ml_tpu.optimize.common import MarginFns, walking_tolerances
+
+    obj, zeros = _margin_case(layout, loss_name, variant)
+    steps = MarginFns(*margin_fns(obj))
+    f0, g0 = jax.jit(lambda o, w: o.value_and_grad(w))(obj, zeros)
+    loss_tol, grad_tol, start = walking_tolerances(steps, zeros, 1e-9, from_zero=True)
+    np.testing.assert_array_equal(np.asarray(start.z), np.asarray(obj.batch.offsets))
+    assert float(start.f) == float(f0)
+    np.testing.assert_array_equal(np.asarray(start.g), np.asarray(g0))
+    assert (int(start.matvecs), int(start.rmatvecs)) == (0, 1)
+    assert (float(loss_tol), float(grad_tol)) == tuple(map(float, abs_tolerances(vg_fn(obj), zeros, 1e-9)))
+
+    config = OptimizerConfig(tolerance=1e-9)
+    evaluated = solve_lbfgs(vg_fn(obj), zeros, loss_tol, grad_tol, count_evals=True, margins=steps)
+    cold = optimize(vg_fn(obj), zeros, config, margins=steps, from_zero=True)
+    _same_solve(cold, evaluated)
+    assert int(cold.iterations) > 3
+    assert (int(cold.matvecs), int(cold.rmatvecs)) == (int(cold.iterations), int(cold.iterations) + 1)
+    assert int(evaluated.matvecs) == int(evaluated.rmatvecs) == int(evaluated.iterations) + 1
+
+    w0 = jnp.asarray(0.2 * np.random.default_rng(9).normal(size=zeros.shape), zeros.dtype)
+    evaluated = solve_lbfgs(vg_fn(obj), w0, loss_tol, grad_tol, count_evals=True, margins=steps)
+    warm = optimize(vg_fn(obj), w0, config, margins=steps)
+    _same_solve(warm, evaluated)
+    assert int(warm.matvecs) == int(warm.rmatvecs) == int(warm.iterations) + 1
+
+
+def test_in_float32_the_margins_at_zero_are_the_offsets_bit_for_bit():
+    """The precision the chip runs: z(0) is the offsets, bit for bit, and the
+    margins a solve that evaluates its own start gathers (X 0 + 0 + offsets)
+    read the same."""
+    from photon_ml_tpu.ops.glm import margin_fns
+    from photon_ml_tpu.optimize.common import MarginFns, walking_tolerances
+
+    obj, zeros = _margin_case("ell", "logistic", "offsets_padded", dtype=jnp.float32)
+    steps = MarginFns(*margin_fns(obj))
+    *_, start = walking_tolerances(steps, zeros, 1e-7, from_zero=True)
+    offsets = np.asarray(obj.batch.offsets)
+    assert start.z.dtype == jnp.float32 and np.count_nonzero(offsets) > 200
+    np.testing.assert_array_equal(np.asarray(start.z).view(np.int32), offsets.view(np.int32))
+    np.testing.assert_array_equal(np.asarray(steps.margins(zeros)), offsets)
+
+
 # -- the correction history kept by rows (a wide one-lane solve) ----------------------------------
 
 # just over the threshold and not a multiple of 1024: the last tile of a row is part zeros

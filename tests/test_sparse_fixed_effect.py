@@ -336,13 +336,8 @@ def _ragged(n, d, seed=37):
     return raw
 
 
-def test_a_walking_solve_passes_over_the_features_once_an_iteration_each_way(monkeypatch):
-    """The twin of the points path's count above: here the objective's
-    evaluations are not passes. ``matvec`` and ``rmatvec`` count their own
-    executions: one each at the start, one each an iteration, however many
-    step lengths the searches tried."""
-    from photon_ml_tpu.ops.glm import margin_fns, vg_fn
-
+def _count_passes(monkeypatch):
+    """Make ``FeatureMatrix.matvec`` / ``rmatvec`` note every execution."""
     executed = {"matvec": [], "rmatvec": []}
     for name in executed:
         real = getattr(FeatureMatrix, name)
@@ -352,6 +347,17 @@ def test_a_walking_solve_passes_over_the_features_once_an_iteration_each_way(mon
             return real(self, x)
 
         monkeypatch.setattr(FeatureMatrix, name, counted)
+    return executed
+
+
+def test_a_walking_solve_passes_over_the_features_once_an_iteration_each_way(monkeypatch):
+    """The twin of the points path's count above: here the objective's
+    evaluations are not passes. ``matvec`` and ``rmatvec`` count their own
+    executions: one each at the start, one each an iteration, however many
+    step lengths the searches tried."""
+    from photon_ml_tpu.ops.glm import margin_fns, vg_fn
+
+    executed = _count_passes(monkeypatch)
     batch = _ragged(601, 401).to_batch(SHARD, dtype=jnp.float32, layout="ell")
     obj = GLMObjective(loss=get_loss("logistic_regression"), batch=batch, l2=0.1)
     tol = jnp.asarray(1e-7, jnp.float32)
@@ -362,13 +368,70 @@ def test_a_walking_solve_passes_over_the_features_once_an_iteration_each_way(mon
     assert int(r.line_search_evals) > int(r.iterations) + 1  # trials judged, not passes
 
 
+@pytest.mark.parametrize("warm", [False, True], ids=["from-no-model", "warm"])
+def test_a_walking_solve_from_no_model_gathers_nothing_before_its_first_iteration(monkeypatch, warm):
+    """What ``GLMProblem.run`` executes around a walking solve, counted by the
+    passes themselves: the tolerance pass is the state at zero, one scatter-add
+    and no gather (its margins are the offsets); a solve from no model starts
+    from it, so it runs ``iterations`` matvecs and ``iterations`` + 1 rmatvecs
+    in all; a warm one is handed margins(w0) and its gradient from the same
+    program, one more of each. The sink's counter and spans say the same."""
+    # a shape of its own each: a program compiled before the stub went in counts nothing
+    raw = _ragged(608 if warm else 606, 408 if warm else 406)
+    batch = raw.to_batch(SHARD, dtype=jnp.float32, layout="ell")
+    problem = GLMProblem(task="logistic_regression", config=_config(0.1))
+    initial = None
+    if warm:
+        initial, _ = GLMProblem(task="logistic_regression", config=_config(0.1, max_iterations=3)).run(batch)
+    executed = _count_passes(monkeypatch)
+    run, sink = obs.RunTelemetry(), _Spans()
+    run.register_listener(sink)
+    with obs.use_run(run):
+        _, result = problem.run(batch, initial_model=initial, coordinate="global")
+    jax.effects_barrier()
+    iterations = int(result.iterations)
+    want = (iterations + 1, iterations + 2) if warm else (iterations, iterations + 1)
+    assert (len(executed["matvec"]), len(executed["rmatvec"])) == want and iterations > 4
+    passes = {m["labels"]["kind"]: m["value"]
+              for m in _series(run.registry, "photon_fe_feature_passes_total", coordinate="global")}
+    assert (passes["matvec"], passes["rmatvec"]) == want
+    # the solve's own count: its start's passes and its iterations'
+    assert (int(result.matvecs), int(result.rmatvecs)) == (want[0], iterations + 1)
+    solve, = [s for s in sink.spans if s.name == "fe.solve"]
+    tolerances, = [s for s in sink.spans if s.name == "fe.tolerances"]
+    assert solve.attrs["start"] == ("evaluated" if warm else "zero_state") and solve.attrs["warm"] is warm
+    assert tolerances.attrs["matvec"] is False and tolerances.parent_id == solve.span_id
+
+
+def test_a_fit_from_no_model_and_then_warm_compiles_one_walking_solve():
+    """Coordinate descent over one fixed effect: the first sweep's solve starts
+    from the state at zero, the second warm from the first's model. Both are
+    handed their start, so both run the one compiled ``_solve``."""
+    from photon_ml_tpu.optimize import lbfgs
+
+    raw = _ragged(607, 407)
+    est = GameEstimator(
+        task="logistic_regression",
+        coordinate_configs=[CoordinateConfig(name="global", feature_shard=SHARD, config=_config(0.1), layout="ell")],
+        n_cd_iterations=2, dtype=jnp.float32)
+    datasets = est.prepare_datasets(raw)
+    programs = lbfgs._solve._cache_size()
+    run, sink = obs.RunTelemetry(), _Spans()
+    run.register_listener(sink)
+    with obs.use_run(run):
+        est.fit(None, datasets=datasets)
+    assert lbfgs._solve._cache_size() == programs + 1
+    solves = [(s.attrs["warm"], s.attrs["start"]) for s in sink.spans if s.name == "fe.solve"]
+    assert solves == [(False, "zero_state"), (True, "evaluated")]
+
+
 class _CountingSteps:
     """``MarginFns`` of a quadratic that note every time one of them is traced."""
 
     def __init__(self):
         self.traced = []
 
-    def fns(self):
+    def fns(self, shape):
         def note(name, out):
             def fn(*args):
                 self.traced.append(name)
@@ -378,7 +441,8 @@ class _CountingSteps:
         return (note("margins", lambda w: w), note("direction_margins", lambda p: p),
                 note("value_and_slope", lambda z, u, t, w, p: (0.5 * jnp.sum((z + t * u) ** 2, axis=0),
                                                                  jnp.sum((z + t * u) * u, axis=0))),
-                note("grad_from_margins", lambda z, w: (0.5 * jnp.sum(z * z, axis=0), z)))
+                note("grad_from_margins", lambda z, w: (0.5 * jnp.sum(z * z, axis=0), z)),
+                note("zero_margins", lambda: jnp.zeros(shape)))
 
 
 @pytest.mark.parametrize("mode", ["plain", "owlqn", "box", "batched"])
@@ -394,7 +458,7 @@ def test_only_a_plain_one_lane_solve_walks_the_margin_functions(mode):
     tol = jnp.full(shape[1:], 1e-9)
     vg = lambda w: (0.5 * jnp.sum(w * w, axis=0), w)  # noqa: E731
     steps = _CountingSteps()
-    with_steps = solve_lbfgs(vg, w0, tol, tol, margins=steps.fns(), **kwargs)
+    with_steps = solve_lbfgs(vg, w0, tol, tol, margins=steps.fns(shape), **kwargs)
     if mode == "plain":
         assert set(steps.traced) == {"margins", "direction_margins", "value_and_slope", "grad_from_margins"}
         np.testing.assert_allclose(np.asarray(with_steps.coefficients), 0.0, atol=1e-8)
@@ -437,6 +501,9 @@ def test_a_solve_that_cannot_walk_says_points_and_is_handed_no_margin_functions(
     span, = [s for s in sink.spans if s.name == "fe.solve"]
     # TRON has no line search and its span no such attribute
     assert span.attrs.get("line_search") == (None if case == "tron" else "points")
+    # a tolerance pass over the features, and a start the solve evaluates
+    tolerances, = [s for s in sink.spans if s.name == "fe.tolerances"]
+    assert span.attrs["start"] == "evaluated" and tolerances.attrs["matvec"] is True
     assert len(built) == (0 if case == "fused" else 1) and not walked
     # only a solve that walked margins counts passes of its own
     assert not _series(run.registry, "photon_fe_feature_passes_total")
@@ -484,8 +551,10 @@ def _ell_estimator():
 def test_a_fit_over_an_ell_shard_compiles_one_solver_and_walks_margins():
     """What the benchmark's sparse cell requires of a first fit
     (``benchmark/jobs/fit_sparse.py`` ``solver_programs``), and what a sink
-    sees of the walk: ``line_search=margins`` on ``fe.solve``, iterations + 1
-    passes of each kind, riding the one fetch a sink already made."""
+    sees of the walk: ``line_search=margins`` on ``fe.solve``, and for this
+    solve from no model iterations matvecs and iterations + 1 rmatvecs (the
+    state at zero's scatter-add its start), riding the one fetch a sink
+    already made."""
     import logging
 
     from photon_ml_tpu.optimize import lbfgs
@@ -518,8 +587,8 @@ def test_a_fit_over_an_ell_shard_compiles_one_solver_and_walks_margins():
     assert span.attrs["line_search_evals"] == int(b.line_search_evals)
     passes = {m["labels"]["kind"]: m["value"]
               for m in _series(run.registry, "photon_fe_feature_passes_total", coordinate="global")}
-    assert passes == {"matvec": int(b.iterations) + 1, "rmatvec": int(b.iterations) + 1}
-    assert int(b.matvecs) == int(b.rmatvecs) == int(b.iterations) + 1 < int(b.line_search_evals)
+    assert passes == {"matvec": int(b.iterations), "rmatvec": int(b.iterations) + 1}
+    assert int(b.matvecs) + 1 == int(b.rmatvecs) == int(b.iterations) + 1 < int(b.line_search_evals)
     # one fetch for the solve's counts, two int32 wider than it was; none with no sink
     fetches = [s for s in sink.spans if s.name == "fetch" and s.attrs["site"] == "solver.lbfgs"]
     history = np.asarray(b.grad_norm_history).nbytes

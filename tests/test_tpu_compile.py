@@ -210,29 +210,11 @@ def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_g
     objective's L-BFGS), the search's loop carrying scalars; the objective comes
     as an argument once for every margin step that reads it (the same buffers,
     counted each time: 7.77 / 7.88 GB as counted here, 10.39 / 10.50 over the
-    tiled history)."""
-    from jax.sharding import SingleDeviceSharding
-
-    from photon_ml_tpu.ops.features import FeatureMatrix, LabeledBatch
-    from photon_ml_tpu.ops.glm import GLMObjective, margin_fns, vg_fn
-    from photon_ml_tpu.ops.losses import get_loss
+    tiled history). It is handed its start (f, g, z at w0) as operands."""
     from photon_ml_tpu.optimize import lbfgs
-    from photon_ml_tpu.optimize.common import MarginFns, as_partial
 
-    n, k, d = 2_359_296, 12, 54_686_453
-    one = SingleDeviceSharding(topo.devices[0])
-    s = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
-    if layout == "ell":
-        features = FeatureMatrix(dim=d, idx=s((n, k), jnp.int32), val=s((n, k)))
-    else:
-        features = FeatureMatrix(dim=d, coo_cols=s((n * k,), jnp.int32), coo_rows=s((n * k,), jnp.int32),
-                                 coo_vals=s((n * k,)), coo_n_rows=n)
-    batch = LabeledBatch(features=features, labels=s((n,)), offsets=s((n,)), weights=s((n,)))
-    objective = GLMObjective(loss=get_loss("logistic_regression"), batch=batch, l2=1000.0)
-    compiled = lbfgs._solve.lower(
-        as_partial(vg_fn(objective)), s((d,)), s(()), s(()), 100, 10, None, 25, False, s((d,)), s((d,)),
-        False, True, MarginFns(*margin_fns(objective)),
-    ).compile()
+    n, k, d = 2_359_296, SPARSE_SLOTS, SPARSE_DIM
+    compiled = _sparse_solve(topo, n, layout)
     memory = compiled.memory_analysis()
     total = memory.temp_size_in_bytes + memory.argument_size_in_bytes + memory.output_size_in_bytes
     assert memory.temp_size_in_bytes < 6.5e9, memory.temp_size_in_bytes
@@ -244,6 +226,70 @@ def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_g
     rows = lbfgs.history_row_width((d,), False) // 128
     assert f"f32[10,{d}]" not in text and f"f32[10,{rows},128]{{2,1,0:T(8,128)}}" in text
     assert history_row_copies(text, 10, d) == []
+
+
+SPARSE_SLOTS, SPARSE_DIM = 12, 54_686_453
+
+
+def _sparse_solve(topo, n, layout="ell"):
+    """The walking ``jit__solve`` of the sparse configuration over ``n`` rows on
+    one v5e, compiled, handed its start as ``GLMProblem.run`` hands it."""
+    from jax.sharding import SingleDeviceSharding
+
+    from photon_ml_tpu.ops.features import FeatureMatrix, LabeledBatch
+    from photon_ml_tpu.ops.glm import GLMObjective, margin_fns, vg_fn
+    from photon_ml_tpu.ops.losses import get_loss
+    from photon_ml_tpu.optimize import lbfgs
+    from photon_ml_tpu.optimize.common import MarginFns, as_partial
+
+    k, d = SPARSE_SLOTS, SPARSE_DIM
+    one = SingleDeviceSharding(topo.devices[0])
+    s = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    if layout == "ell":
+        features = FeatureMatrix(dim=d, idx=s((n, k), jnp.int32), val=s((n, k)))
+    else:
+        features = FeatureMatrix(dim=d, coo_cols=s((n * k,), jnp.int32), coo_rows=s((n * k,), jnp.int32),
+                                 coo_vals=s((n * k,)), coo_n_rows=n)
+    batch = LabeledBatch(features=features, labels=s((n,)), offsets=s((n,)), weights=s((n,)))
+    objective = GLMObjective(loss=get_loss("logistic_regression"), batch=batch, l2=1000.0)
+    return lbfgs._solve.lower(
+        as_partial(vg_fn(objective)), s((d,)), s(()), s(()), 100, 10, None, 25, False, s((d,)), s((d,)),
+        False, True, MarginFns(*margin_fns(objective)), None, _start(s, d, n),
+    ).compile()
+
+
+def _start(s, d, n, vec=None, scalar=None):
+    """A walking solve's start as operands (``common.SolveStart``): f, g at
+    w0 split as w0 is, z as the rows are."""
+    from photon_ml_tpu.optimize.common import SolveStart
+
+    if vec is None:
+        return SolveStart(s(()), s((d,)), s((n,)), s((), jnp.int32), s((), jnp.int32))
+    return SolveStart(s((), scalar), s((d,), vec), s((n,), vec), s((), scalar, jnp.int32), s((), scalar, jnp.int32))
+
+
+# ``jit__solve`` at the sparse cell's own shape as it was when it evaluated its own start,
+# compiled for the v5e (``memory_analysis()``): temporaries and arguments, bytes
+SPARSE_CELL_SOLVE_TEMP, SPARSE_CELL_SOLVE_ARGS = 6_142_588_928, 695_326_720
+
+
+def test_the_sparse_cells_solve_is_handed_its_start_for_no_more_memory(topo):
+    """``jit__solve`` at ``fit-sparse``'s 1,179,648 rows: handed its start
+    (``common.SolveStart``: g0 219 MB, z0 4.7 MB among its arguments) instead
+    of evaluating it, it holds no more temporaries than when it took its own
+    first margins and gradient (6.14 GB), and one gather and one
+    scatter-add of the features, the loop's."""
+    n, k, d = 1_179_648, SPARSE_SLOTS, SPARSE_DIM
+    compiled = _sparse_solve(topo, n)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= SPARSE_CELL_SOLVE_TEMP, memory.temp_size_in_bytes
+    # the start's g0 and z0 come in; the first margins' copy of the batch no longer does
+    assert (d + n) * 4 < memory.argument_size_in_bytes < SPARSE_CELL_SOLVE_ARGS + (d + n) * 4
+    text = compiled.as_text()
+    # one gather over the [k, n] slots and one scatter-add into [d], in the
+    # loop's body (a solve that evaluated its start had a second of each)
+    assert len(re.findall(rf"= f32\[{k},{n}\]\S* gather\(", text)) == 1
+    assert len(re.findall(rf"= f32\[{d}\]\S* scatter\(", text)) == 1
 
 
 # -- the GLMix-over-sparse-ids cell on ONE chip (benchmark/configs/glmix-sparse-user-1chip.json) --
@@ -355,13 +401,14 @@ def test_the_criteo_solve_splits_its_state_over_the_four_chips(topo, mesh):
     compiled = lbfgs._solve.lower(
         as_partial(vg_fn(objective)), s((d_pad,), vec), s((), rep), s((), rep), 100, 10, None, 25, False,
         s((d_pad,), vec), s((d_pad,), vec), False, True, MarginFns(*margin_fns(objective)), vec,
+        _start(s, d_pad, n, vec, rep),
     ).compile()
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 6.5e9, memory.temp_size_in_bytes
     text = compiled.as_text()
     assert f"f32[10,{d_pad // CHIPS // 128},128]" in text and f"f32[10,{d_pad // 128},128]" not in text
     gathers = re.findall(r"= f32\[4,1,(\d+)\]\S* all-gather\(", text)
-    assert gathers == [str(d_pad // CHIPS)] * 2  # the loop's pass and the first margins
-    assert len(re.findall(r"calls=%all-reduce-scatter", text)) == 2
+    assert gathers == [str(d_pad // CHIPS)]  # the loop's pass: the solve is handed its start
+    assert len(re.findall(r"calls=%all-reduce-scatter", text)) == 1
     assert not re.search(rf"f32\[{d_pad}\]\S* all-reduce\(", text)
     assert not re.search(r"all-gather\(%\S+\), channel_id=\d+, replica_groups=\S+, dimensions=\{1\}", text)
