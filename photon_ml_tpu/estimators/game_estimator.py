@@ -268,6 +268,9 @@ class GameEstimator(EventEmitter):
             int(val_raw.n_rows),
             tuple(self.evaluator_specs or ["RMSE"]),
             jnp.dtype(self.dtype),
+            # a mesh's models are placed on the mesh: its batches are built
+            # for no one device (no local column map, ops/features.py)
+            self.mesh is None,
             tuple(
                 (
                     cc.name, cc.feature_shard, cc.random_effect_type,
@@ -338,7 +341,7 @@ class GameEstimator(EventEmitter):
                     return model.score_ell_rows(erow, _idx, _val)
 
             else:
-                batch = val_raw.to_batch(cc.feature_shard, dtype=self.dtype)
+                batch = val_raw.to_batch(cc.feature_shard, dtype=self.dtype, mesh=self.mesh)
                 uploaded = batch
 
                 def fn(model, _batch=batch):

@@ -338,7 +338,12 @@ class FixedEffectCoordinate(Coordinate):
                     local, ds.mesh, PartitionSpec(DATA_AXIS)
                 )
             return scores
-        with obs.span("fe.score", coordinate=self.coordinate_id) as sp:
+        feats = self.dataset.batch.features
+        with obs.span(
+            "fe.score", coordinate=self.coordinate_id,
+            gather=getattr(feats, "gather", "global"),
+            gather_columns=getattr(feats, "gather_columns", feats.dim),
+        ) as sp:
             scores = self._score_resident(model)
             sp.sync(scores)
         return scores

@@ -588,6 +588,7 @@ def build_fixed_effect_dataset(
         est = estimate_fe_batch_bytes(
             n, d, eff_layout, ell_width=width,
             feature_itemsize=fdt.itemsize, scalar_itemsize=sdt.itemsize,
+            one_device=mesh is None,
         )
         if est > hbm_budget_bytes:
             if eff_layout == "dense":

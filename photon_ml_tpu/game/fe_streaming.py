@@ -60,7 +60,7 @@ from .. import obs
 from ..utils.transfer import logged_fetch
 from ..utils.futures import PrefetchQueue
 from . import pipeline
-from ..ops.features import FeatureMatrix, LabeledBatch
+from ..ops.features import LOCAL_MAP_MIN_DIM, FeatureMatrix, LabeledBatch
 from ..ops.glm import (
     finalize_hessian_vector,
     finalize_value_grad,
@@ -84,6 +84,7 @@ def estimate_fe_batch_bytes(
     ell_width: int = 0,
     feature_itemsize: int = 4,
     scalar_itemsize: int = 4,
+    one_device: bool = False,
 ) -> int:
     """Device bytes of an in-HBM fixed-effect LabeledBatch of this shape
     (features + labels/offsets/weights). The streamed-vs-resident decision in
@@ -91,11 +92,17 @@ def estimate_fe_batch_bytes(
 
     ``scalar_itemsize`` is the labels/offsets/weights itemsize (8 for an
     x64-configured dataset); callers derive both itemsizes from the actual
-    dtypes, like the RE estimator."""
+    dtypes, like the RE estimator. ``one_device``: the batch is built for one
+    device, so an ELL batch at least ``LOCAL_MAP_MIN_DIM`` wide also holds its
+    local column map (ops/features.py): ``idx_local``, one more index a slot,
+    and ``cols``, at most one index a slot or a column."""
     if layout == "dense":
         feat = n_rows * dim * feature_itemsize
     elif layout == "ell":
         feat = n_rows * ell_width * (feature_itemsize + _ELL_INDEX_ITEMSIZE)
+        if one_device and dim >= LOCAL_MAP_MIN_DIM:
+            slots = n_rows * ell_width
+            feat += (slots + min(slots, dim)) * _ELL_INDEX_ITEMSIZE
     else:
         raise ValueError(
             f"estimate_fe_batch_bytes: layout must be dense|ell, got {layout!r}"

@@ -266,6 +266,9 @@ class GLMProblem:
             check_full_variance_dim(batch.dim)
         solver_config = self.config.solver_config()
         dim = int(batch.dim)
+        # what the margins gather from (``local``: the held columns' table)
+        gather = dict(gather=getattr(batch.features, "gather", "global"),
+                      gather_columns=getattr(batch.features, "gather_columns", dim))
         obj, state = self.solve_objective(batch, solver_config)
         batch, fused = obj.batch, obj.fused
         dtype = batch.labels.dtype
@@ -338,6 +341,7 @@ class GLMProblem:
             # sweep's model, under the other coordinates' scores as offsets
             warm=initial_model is not None,
             offsets=bool(residuals),
+            **gather,
             **history,
         ) as sp:
             # with a sink, an L-BFGS or OWL-QN solve adds ``line_search`` (the

@@ -122,6 +122,10 @@ class RawDataset:
         'tiled' ((data x model)-mesh-tiled sparse, huge d sharded; requires
         ``mesh`` — see parallel/sparse.py).
 
+        mesh: the mesh the batch will be placed on; with none the batch stays
+        on one device, and a wide ELL batch brings its local column map
+        (ops/features.py ``LOCAL_MAP_MIN_DIM``).
+
         feature_dtype: optional narrower storage type for the FEATURE matrix
         only (dense/ell/coo layouts; e.g. bfloat16 halves the HBM traffic of
         the objective sweeps on TPU). Labels/offsets/weights stay ``dtype``.
@@ -154,6 +158,7 @@ class RawDataset:
                 dtype=dtype,
                 layout="coo" if layout == "coo" else "ell",
                 feature_dtype=feature_dtype,
+                one_device=mesh is None,
             )
         if layout == "tiled":
             if mesh is None:

@@ -36,9 +36,28 @@ ELL for every d above the dense limit. The older conclusion stands: one chip's
 sparse throughput is bound by serialized random access (no HBM cache, no
 vectorized VMEM gather before SparseCore), and the design answer is to
 *divide* that cost across devices by (data x model) tiling, not to chase a
-magic kernel. A Pallas route was measured and rejected: tpu.dynamic_gather
-only shuffles within one (8, 128) vreg, so large-table gathers cannot
-vectorize on this generation.
+magic kernel for the whole vector: tpu.dynamic_gather only shuffles within
+one (8, 128) vreg, and a 219 MB table lives in HBM.
+
+The margins need not read the whole vector, though: the rows of one chip hold
+few of its columns. A one-device ELL batch at least ``LOCAL_MAP_MIN_DIM`` wide
+keeps a local column map (``cols``, the sorted held columns; ``idx_local``,
+each slot's position among them), and ``matvec`` gathers ``w[cols]`` once and
+the slots from that table. ``fit-sparse``'s rows hold 1,712,040 of the 54.7M
+columns, a 6.85 MB table (2.9M, 11.6 MB, at the whole one-chip share of
+2,359,296 rows). What a slot costs from each (a stand-alone probe on a TPU
+v5 lite at the cell's 14.16M slots, every variant's margins bit for bit the
+global gather's):
+
+    XLA take from the 219 MB vector, with the multiply and row sum   18.9 ns  268.1 ms
+    w[cols] (1.71M sorted unique columns)                              --      31.2 ms
+    XLA take from the 6.85 MB table                                   8.9 ns  126.2 ms
+    the Pallas kernel, the table in VMEM (ops/pallas_gather.py)       4.1 ns   57.5 ms
+    the whole matvec through the kernel                               5.9 ns   84.0 ms
+
+So a slot's price does fall with the table, and further in VMEM, which a
+6.85 MB table fits. The scatter-adds (``rmatvec``, ``sq_rmatvec``,
+``rmatmat``) and ``to_dense`` keep the global ``idx``.
 
 The ELL sums are written over ``[k, n]`` views (``idx.T``, ``val.T``: the row
 axis minor, which is how the TPU lays a tall ``[n, k]`` array out anyway). Over
@@ -68,6 +87,11 @@ from jax.sharding import PartitionSpec as P
 
 Array = jax.Array
 
+# An ELL batch built for one device at least this wide carries a local column
+# map (``cols``, ``idx_local``): its margins gather from the held columns'
+# table, not from the whole coefficient vector (module docstring).
+LOCAL_MAP_MIN_DIM = 1 << 20
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -79,12 +103,19 @@ class FeatureMatrix:
     ``coo_rows``, ``coo_vals``) is set. ``dim`` is the feature-space
     dimension d (static so jitted shapes are known); ``coo_n_rows`` is the
     static row count for the COO layout (not derivable from array shapes).
+
+    An ELL matrix may carry a local column map: ``cols`` (the sorted unique
+    columns its slots hold, padding included) and ``idx_local`` (each slot's
+    position in ``cols``). ``matvec`` then gathers the margins from
+    ``w[cols]``; every other operation reads the global ``idx``.
     """
 
     dim: int = dataclasses.field(metadata=dict(static=True))
     dense: Optional[Array] = None
     idx: Optional[Array] = None
     val: Optional[Array] = None
+    cols: Optional[Array] = None  # i32[d_loc], sorted unique columns of idx
+    idx_local: Optional[Array] = None  # i32[n, k]: idx == cols[idx_local]
     coo_cols: Optional[Array] = None  # i32[m], sorted ascending (pad: dim-1)
     coo_rows: Optional[Array] = None  # i32[m] (pad: 0)
     coo_vals: Optional[Array] = None  # f[m] (pad: 0)
@@ -107,6 +138,10 @@ class FeatureMatrix:
             self.coo_rows is None or self.coo_vals is None
         ):
             raise ValueError("COO layout requires coo_cols, coo_rows and coo_vals")
+        if (self.cols is None) != (self.idx_local is None):
+            raise ValueError("a local column map needs both cols and idx_local")
+        if self.cols is not None and self.idx is None:
+            raise ValueError("a local column map belongs to the ELL layout")
 
     @property
     def layout(self) -> str:
@@ -138,12 +173,35 @@ class FeatureMatrix:
             return self.idx.shape[0] * self.idx.shape[1]
         return self.coo_cols.shape[0]
 
+    @property
+    def gather(self) -> str:
+        """Where ``matvec`` reads coefficients from: ``local`` (the table of
+        the held columns) or ``global`` (the whole vector). Host-known."""
+        return "global" if self.cols is None else "local"
+
+    @property
+    def gather_columns(self) -> int:
+        """The length of the vector ``matvec`` gathers from. Host-known."""
+        return self.dim if self.cols is None else self.cols.shape[0]
+
     def matvec(self, w: Array) -> Array:
         """x @ w -> [n]."""
         if self.dense is not None:
             return self.dense @ w
         if self.idx is not None:
-            return jnp.sum(self.val.T * jnp.take(w, self.idx.T, axis=0), axis=0)
+            if self.cols is None:
+                return jnp.sum(self.val.T * jnp.take(w, self.idx.T, axis=0), axis=0)
+            # Pallas loads with the first batch that has a map, as the GLM
+            # kernels load with their first fused call (ops/glm.py)
+            from . import pallas_gather
+
+            table = jnp.take(w, self.cols, axis=0, indices_are_sorted=True, unique_indices=True)
+            how = pallas_gather.route(table.shape[0], self.idx_local.shape[1], table.dtype)
+            if how is None:
+                words = jnp.take(table, self.idx_local.T, axis=0)
+            else:
+                words = pallas_gather.gather(table, self.idx_local, interpret=how == "interpret")
+            return jnp.sum(self.val.T * words, axis=0)
         wv = jnp.take(w, self.coo_cols) * self.coo_vals
         return jnp.zeros(self.coo_n_rows, dtype=wv.dtype).at[self.coo_rows].add(wv)
 
@@ -289,6 +347,8 @@ class FeatureMatrix:
             dim=self.dim,
             idx=jax.lax.dynamic_slice_in_dim(self.idx, start, size),
             val=jax.lax.dynamic_slice_in_dim(self.val, start, size),
+            cols=self.cols,
+            idx_local=None if self.cols is None else jax.lax.dynamic_slice_in_dim(self.idx_local, start, size),
         )
 
 
@@ -376,6 +436,20 @@ def sorted_coo_matrix(
     )
 
 
+def _local_column_map(idx: np.ndarray, dim: int):
+    """The sorted columns ``idx`` holds and each slot's position among them,
+    by a mask over the columns: at ``fit-sparse``'s 14.16M slots 0.63 s on a
+    v5e machine's host, where ``np.unique(idx, return_inverse=True)`` took
+    2.66 s. Only the pages of ``pos`` that a held column falls in are
+    written."""
+    seen = np.zeros(dim, bool)
+    seen[idx] = True
+    held = np.flatnonzero(seen).astype(np.int32)
+    pos = np.empty(dim, np.int32)
+    pos[held] = np.arange(len(held), dtype=np.int32)
+    return held, pos[idx]
+
+
 def batch_from_coo(
     rows: np.ndarray,
     cols: np.ndarray,
@@ -388,11 +462,14 @@ def batch_from_coo(
     dtype=jnp.float32,
     layout: str = "ell",
     feature_dtype=None,
+    one_device: bool = False,
 ) -> LabeledBatch:
     """Build a sparse batch from COO triplets (host-side, numpy).
 
     layout='ell' gives the row-major padded layout (moderate d);
     layout='coo' gives column-sorted COO (huge d; see module docstring).
+    ``one_device``: the batch stays on one device, so an ELL batch at least
+    ``LOCAL_MAP_MIN_DIM`` wide also gets its local column map.
     ``feature_dtype`` (e.g. bfloat16) stores ONLY the feature VALUES in a
     narrower type — indices, labels/offsets/weights and all solver state
     stay wide; elementwise products promote back to ``dtype`` on the fly.
@@ -418,8 +495,12 @@ def batch_from_coo(
         keep = within < k
         idx[r_s[keep], within[keep]] = c_s[keep]
         val[r_s[keep], within[keep]] = v_s[keep]
+        local = {}
+        if one_device and dim >= LOCAL_MAP_MIN_DIM:
+            held, idx_local = _local_column_map(idx, dim)
+            local = dict(cols=jnp.asarray(held, np.int32), idx_local=jnp.asarray(idx_local, np.int32))
         feats = FeatureMatrix(
-            dim=dim, idx=jnp.asarray(idx, np.int32), val=jnp.asarray(val, vdt)
+            dim=dim, idx=jnp.asarray(idx, np.int32), val=jnp.asarray(val, vdt), **local
         )
     return LabeledBatch(
         features=feats,
@@ -446,10 +527,10 @@ def pad_batch(batch: LabeledBatch, target_rows: int) -> LabeledBatch:
             dense=jnp.concatenate([f.dense, jnp.zeros((extra, f.dim), f.dense.dtype)]),
         )
     elif f.idx is not None:
+        pad2 = lambda a: jnp.concatenate([a, jnp.zeros((extra, a.shape[1]), a.dtype)])
         feats = FeatureMatrix(
-            dim=f.dim,
-            idx=jnp.concatenate([f.idx, jnp.zeros((extra, f.idx.shape[1]), f.idx.dtype)]),
-            val=jnp.concatenate([f.val, jnp.zeros((extra, f.val.shape[1]), f.val.dtype)]),
+            dim=f.dim, idx=pad2(f.idx), val=pad2(f.val), cols=f.cols,
+            idx_local=None if f.cols is None else pad2(f.idx_local),
         )
     else:
         # COO: padded rows have no nnz; only the static row count grows

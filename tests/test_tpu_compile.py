@@ -191,7 +191,7 @@ def history_row_copies(text, m, d):
 
 
 @pytest.mark.parametrize("layout,limit_gb", [("ell", 8.2), ("coo", 8.3)])
-def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_gb):
+def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_gb, monkeypatch):
     """``jit__solve`` at one chip's whole share of the sparse deployment
     (2,359,296 rows x 12 slots into 54,686,453 columns, plain L-BFGS, m = 10;
     the cell runs half those rows). The TPU tiles a ``[10, d]`` history as
@@ -210,11 +210,14 @@ def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_g
     objective's L-BFGS), the search's loop carrying scalars; the objective comes
     as an argument once for every margin step that reads it (the same buffers,
     counted each time: 7.77 / 7.88 GB as counted here, 10.39 / 10.50 over the
-    tiled history). It is handed its start (f, g, z at w0) as operands."""
+    tiled history). It is handed its start (f, g, z at w0) as operands. The ELL
+    batch brings its local column map (2,899,743 held columns at these rows)
+    and its margins gather through the Pallas table gather: 7.69 GB, the
+    table's 11.6 MB more arguments than with the global gather."""
     from photon_ml_tpu.optimize import lbfgs
 
     n, k, d = 2_359_296, SPARSE_SLOTS, SPARSE_DIM
-    compiled = _sparse_solve(topo, n, layout)
+    compiled = _sparse_solve(topo, n, layout, monkeypatch)
     memory = compiled.memory_analysis()
     total = memory.temp_size_in_bytes + memory.argument_size_in_bytes + memory.output_size_in_bytes
     assert memory.temp_size_in_bytes < 6.5e9, memory.temp_size_in_bytes
@@ -229,11 +232,17 @@ def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_g
 
 
 SPARSE_SLOTS, SPARSE_DIM = 12, 54_686_453
+# the columns the sparse configuration's rows hold (its generator's fixed
+# quotas, benchmark/data_sparse.py), by rows: the cell's and the whole share's
+SPARSE_HELD = {1_179_648: 1_712_040, 2_359_296: 2_899_743}
 
 
-def _sparse_solve(topo, n, layout="ell"):
+def _sparse_solve(topo, n, layout="ell", monkeypatch=None):
     """The walking ``jit__solve`` of the sparse configuration over ``n`` rows on
-    one v5e, compiled, handed its start as ``GLMProblem.run`` hands it."""
+    one v5e, compiled, handed its start as ``GLMProblem.run`` hands it. An ELL
+    batch carries its local column map, as a one-device build gives it, and
+    (the backend asked while tracing is the described chip's) its margins
+    gather through the Pallas table gather."""
     from jax.sharding import SingleDeviceSharding
 
     from photon_ml_tpu.ops.features import FeatureMatrix, LabeledBatch
@@ -246,7 +255,9 @@ def _sparse_solve(topo, n, layout="ell"):
     one = SingleDeviceSharding(topo.devices[0])
     s = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
     if layout == "ell":
-        features = FeatureMatrix(dim=d, idx=s((n, k), jnp.int32), val=s((n, k)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        features = FeatureMatrix(dim=d, idx=s((n, k), jnp.int32), val=s((n, k)),
+                                 cols=s((SPARSE_HELD[n],), jnp.int32), idx_local=s((n, k), jnp.int32))
     else:
         features = FeatureMatrix(dim=d, coo_cols=s((n * k,), jnp.int32), coo_rows=s((n * k,), jnp.int32),
                                  coo_vals=s((n * k,)), coo_n_rows=n)
@@ -273,23 +284,51 @@ def _start(s, d, n, vec=None, scalar=None):
 SPARSE_CELL_SOLVE_TEMP, SPARSE_CELL_SOLVE_ARGS = 6_142_588_928, 695_326_720
 
 
-def test_the_sparse_cells_solve_is_handed_its_start_for_no_more_memory(topo):
+def test_the_sparse_cells_solve_is_handed_its_start_for_no_more_memory(topo, monkeypatch):
     """``jit__solve`` at ``fit-sparse``'s 1,179,648 rows: handed its start
     (``common.SolveStart``: g0 219 MB, z0 4.7 MB among its arguments) instead
     of evaluating it, it holds no more temporaries than when it took its own
     first margins and gradient (6.14 GB), and one gather and one
-    scatter-add of the features, the loop's."""
+    scatter-add of the features, the loop's. That gather is the table's
+    (``w[cols]``, its 1,712,040 held columns), beside the Pallas kernel
+    that reads the slots' words from it in VMEM: the table's 6.85 MB come in
+    beside the start, and ``idx_local`` takes the place of ``idx`` in the
+    margins' copy of the batch (769.9 MB of arguments against 763.1 with the
+    global gather)."""
     n, k, d = 1_179_648, SPARSE_SLOTS, SPARSE_DIM
-    compiled = _sparse_solve(topo, n)
+    held = SPARSE_HELD[n]
+    compiled = _sparse_solve(topo, n, monkeypatch=monkeypatch)
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= SPARSE_CELL_SOLVE_TEMP, memory.temp_size_in_bytes
-    # the start's g0 and z0 come in; the first margins' copy of the batch no longer does
-    assert (d + n) * 4 < memory.argument_size_in_bytes < SPARSE_CELL_SOLVE_ARGS + (d + n) * 4
+    # the start's g0 and z0 and the map's table come in; the first margins' copy of the batch no longer does
+    assert (d + n) * 4 < memory.argument_size_in_bytes < SPARSE_CELL_SOLVE_ARGS + (d + n + held) * 4
     text = compiled.as_text()
-    # one gather over the [k, n] slots and one scatter-add into [d], in the
-    # loop's body (a solve that evaluated its start had a second of each)
-    assert len(re.findall(rf"= f32\[{k},{n}\]\S* gather\(", text)) == 1
+    # one gather of the table, one kernel over the [k, n] slots and one
+    # scatter-add into [d], in the loop's body (a solve that evaluated its
+    # start had a second of each); XLA gathers no slot
+    assert len(re.findall(rf"= f32\[{held}\]\S* gather\(", text)) == 1
+    assert not re.findall(rf"= f32\[{k},{n}\]\S* gather\(", text)
+    assert text.count("tpu_custom_call") == 1 and "ell_table_gather" in text
     assert len(re.findall(rf"= f32\[{d}\]\S* scatter\(", text)) == 1
+
+
+@pytest.mark.parametrize("table_len,rows,slots", [
+    (1_712_040, 8192, 12), (2_899_743, 2_359_296, 12), ((64 << 20) // 4, 2048, 1), (200_000, 8192, 64)])
+def test_the_table_gather_fits_vmem_and_smem_at_its_gates_edges(topo, table_len, rows, slots):
+    """The Pallas table gather (``ops/pallas_gather.py``) compiles for the v5e
+    at the sparse cell's table (its validation rows and the whole share's), at
+    ``MAX_TABLE_BYTES`` of table in VMEM and at ``MAX_SLOTS`` slots a row, whose
+    indices fill half of the 1 MiB of SMEM a step (128 slots ran out of it)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from photon_ml_tpu.ops import pallas_gather
+
+    assert table_len * 4 <= pallas_gather.MAX_TABLE_BYTES and slots <= pallas_gather.MAX_SLOTS
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = pallas_gather.gather.lower(
+        jax.ShapeDtypeStruct((table_len,), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((rows, slots), jnp.int32, sharding=one)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 # -- the GLMix-over-sparse-ids cell on ONE chip (benchmark/configs/glmix-sparse-user-1chip.json) --
