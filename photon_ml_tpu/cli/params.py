@@ -50,7 +50,7 @@ def parse_coordinate(spec: str) -> CoordinateConfig:
     features.to.samples.ratio=0.5,layout=auto,feature.dtype=bfloat16,
     hbm.budget.mb=4096``
 
-    ``feature.dtype=bfloat16``: narrow feature storage (dense/ell/coo fixed
+    ``feature.dtype=bfloat16``: narrow feature storage (dense/ell fixed
     effects and RE entity blocks; solver state stays wide).
     ``hbm.budget.mb``: out-of-core training under an HBM cap. Random
     effects: entity blocks above the budget stay host-resident and stream
@@ -84,7 +84,7 @@ def parse_coordinate(spec: str) -> CoordinateConfig:
         variance_type=kv.pop("variance", "NONE").upper(),
     )
     layout = kv.pop("layout", "auto").lower()
-    if layout not in ("auto", "dense", "ell", "sparse", "coo", "tiled"):
+    if layout not in ("auto", "dense", "ell", "sparse", "tiled"):
         raise ValueError(f"unknown layout {layout!r} in coordinate {name!r}")
     fdt_name = kv.pop("feature.dtype", "").lower()
     if fdt_name not in ("", "float32", "bfloat16"):

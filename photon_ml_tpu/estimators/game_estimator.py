@@ -71,7 +71,7 @@ class CoordinateConfig:
     # Pearson feature selection: keep ceil(ratio * n_rows) features per entity
     # (numFeaturesToSamplesRatioUpperBound, RandomEffectDataset.scala:553-565)
     features_to_samples_ratio: Optional[float] = None
-    # fixed-effect batch layout: auto|dense|ell|coo|tiled ('tiled' shards the
+    # fixed-effect batch layout: auto|dense|ell|sparse|tiled ('tiled' shards the
     # coefficient dim over the estimator mesh's model axis — the huge-d path)
     layout: str = "auto"
     # optional narrower storage type for the dense feature matrix only (e.g.

@@ -464,7 +464,7 @@ class RandomEffectCoordinate(Coordinate):
         # test_scale_paths with 8 virtual devices).
         multiproc = jax.process_count() > 1
         if multiproc or jax.default_backend() == "cpu":
-            xp, xdt = np, np.dtype(jnp.zeros((), dtype).dtype)
+            xp, xdt = np, np.dtype(dtype)
             # explicit logged fetch: warm-start/prior projections may land on
             # device; the CD sweep runs under transfer_guard, which rejects
             # a bare np.asarray on device arrays
@@ -530,11 +530,11 @@ class RandomEffectCoordinate(Coordinate):
                     blocks.weights.parts[b], *state, **solver_kwargs,
                 )
                 sp.sync(part)
-            if obs.active() and not multiproc:
-                # (across processes a bucket's lanes are not all addressable
-                # from here; the trackers count them after the collect)
-                self._record_lane_iterations(part)
             parts.append(part)
+        if obs.active() and not multiproc:
+            # (across processes a bucket's lanes are not all addressable
+            # from here; the trackers count them after the collect)
+            self._record_lane_iterations(parts)
         _record_accounts(self.coordinate_id, accounts)
         with obs.span("re.collect", coordinate=self.coordinate_id) as sp:
             results = (
@@ -571,14 +571,16 @@ class RandomEffectCoordinate(Coordinate):
         object.__setattr__(model, "_support_layout_of", weakref.ref(self.dataset))
         return model, results
 
-    def _record_lane_iterations(self, part: SolverResult) -> None:
+    def _record_lane_iterations(self, parts: list) -> None:
         """A lockstep bucket runs until its slowest lane stops: ``useful`` is
         the iterations its lanes needed, ``issued`` what the bucket ran for
-        all of them. Called only with a sink attached, after the bucket's
-        fence: the fetch is the iterations array as it stands (a reduction
-        on the device would be one more program, in traced runs alone)."""
-        iters = np.asarray(logged_fetch("re.bucket_iterations", part.iterations))
-        if iters.size == 0:
+        all of them. Called only with a sink attached, after the buckets'
+        fences: ONE fetch of every bucket's iterations array as it stands (a
+        reduction on the device would be one more program, in traced runs
+        alone; a fetch a bucket was most of a small call's own host time)."""
+        fetched = logged_fetch("re.bucket_iterations", [p.iterations for p in parts])
+        buckets = [np.asarray(iters) for iters in fetched if np.size(iters)]
+        if not buckets:
             return
         counter = obs.current_run().registry.counter(
             "photon_re_lane_iterations_total",
@@ -586,10 +588,10 @@ class RandomEffectCoordinate(Coordinate):
             "lanes) against issued (lanes x the bucket's slowest lane)",
         )
         counter.labels(coordinate=self.coordinate_id, kind="useful").inc(
-            int(iters.sum())
+            sum(int(iters.sum()) for iters in buckets)
         )
         counter.labels(coordinate=self.coordinate_id, kind="issued").inc(
-            int(iters.size) * int(iters.max())
+            sum(int(iters.size) * int(iters.max()) for iters in buckets)
         )
 
     def _solver_kwargs(self) -> dict:

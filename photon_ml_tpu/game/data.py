@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.features import FeatureMatrix, LabeledBatch
+from ..ops.features import FeatureMatrix, LabeledBatch, feature_bytes
 from ..io.data import RawDataset
 
 Array = jax.Array
@@ -66,8 +66,8 @@ class HostRowBatch:
     (out-of-core) path: the row axis slices trivially for both supported
     layouts (dense ``[n, d]`` and ELL ``idx/val [n, F]``), which is what lets
     game/fe_streaming.py stage budget-sized row windows through the chip.
-    COO (column-sorted) and tiled (mesh) layouts are NOT row-sliceable and
-    are refused upstream (GameEstimator)."""
+    The tiled (mesh) layout is NOT row-sliceable and is refused upstream
+    (GameEstimator)."""
 
     dim: int
     labels: np.ndarray  # f[n] solve dtype
@@ -87,9 +87,10 @@ class HostRowBatch:
 
     def feature_row_nbytes(self) -> int:
         if self.dense is not None:
-            return self.dim * self.dense.dtype.itemsize
-        return self.ell_idx.shape[1] * (
-            self.ell_val.dtype.itemsize + self.ell_idx.dtype.itemsize
+            return feature_bytes(1, self.dim, "dense", itemsize=self.dense.dtype.itemsize)
+        return feature_bytes(
+            1, self.dim, "ell", width=self.ell_idx.shape[1],
+            itemsize=self.ell_val.dtype.itemsize,
         )
 
 
@@ -560,7 +561,7 @@ def build_fixed_effect_dataset(
     host numpy (dense or ELL rows) and training/scoring stream row slices
     (game/fe_streaming.py). Under a mesh / multi-process topology ``raw`` is
     this host's row slice, so the budget governs the PER-HOST stream (the
-    planner's streamed+sharded routing); the coo/tiled layouts are refused by
+    planner's streamed+sharded routing); the tiled layout is refused by
     the execution planner before this point."""
     d = raw.shard_dims[feature_shard]
     if hbm_budget_bytes is not None:

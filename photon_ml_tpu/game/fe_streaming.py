@@ -60,7 +60,7 @@ from .. import obs
 from ..utils.transfer import logged_fetch
 from ..utils.futures import PrefetchQueue
 from . import pipeline
-from ..ops.features import LOCAL_MAP_MIN_DIM, FeatureMatrix, LabeledBatch
+from ..ops.features import FeatureMatrix, LabeledBatch, feature_bytes
 from ..ops.glm import (
     finalize_hessian_vector,
     finalize_value_grad,
@@ -71,10 +71,6 @@ from ..ops.losses import PointwiseLoss
 from ..ops.normalization import NormalizationContext, identity_normalization
 
 Array = jax.Array
-
-# ELL index planes are int32 (io/data.py builds them that way); derived so a
-# future widening keeps the HBM estimate honest
-_ELL_INDEX_ITEMSIZE = int(np.dtype(np.int32).itemsize)
 
 
 def estimate_fe_batch_bytes(
@@ -93,20 +89,12 @@ def estimate_fe_batch_bytes(
     ``scalar_itemsize`` is the labels/offsets/weights itemsize (8 for an
     x64-configured dataset); callers derive both itemsizes from the actual
     dtypes, like the RE estimator. ``one_device``: the batch is built for one
-    device, so an ELL batch at least ``LOCAL_MAP_MIN_DIM`` wide also holds its
-    local column map (ops/features.py): ``idx_local``, one more index a slot,
-    and ``cols``, at most one index a slot or a column."""
-    if layout == "dense":
-        feat = n_rows * dim * feature_itemsize
-    elif layout == "ell":
-        feat = n_rows * ell_width * (feature_itemsize + _ELL_INDEX_ITEMSIZE)
-        if one_device and dim >= LOCAL_MAP_MIN_DIM:
-            slots = n_rows * ell_width
-            feat += (slots + min(slots, dim)) * _ELL_INDEX_ITEMSIZE
-    else:
-        raise ValueError(
-            f"estimate_fe_batch_bytes: layout must be dense|ell, got {layout!r}"
-        )
+    device, where a wide ELL batch also holds its local column map: the
+    feature bytes are ``ops.features.feature_bytes``."""
+    feat = feature_bytes(
+        n_rows, dim, layout, width=ell_width, itemsize=feature_itemsize,
+        one_device=one_device,
+    )
     return int(feat + 3 * n_rows * scalar_itemsize)
 
 

@@ -117,8 +117,7 @@ class RawDataset:
         """Build a device LabeledBatch for one feature shard.
 
         layout: 'auto' (dense when d <= 4096, else ELL) | 'dense' |
-        'ell' (alias 'sparse': row-major padded sparse, moderate d) |
-        'coo' (column-sorted COO, huge d single-device) |
+        'ell' (alias 'sparse': row-major padded sparse) |
         'tiled' ((data x model)-mesh-tiled sparse, huge d sharded; requires
         ``mesh`` — see parallel/sparse.py).
 
@@ -127,7 +126,7 @@ class RawDataset:
         (ops/features.py ``LOCAL_MAP_MIN_DIM``).
 
         feature_dtype: optional narrower storage type for the FEATURE matrix
-        only (dense/ell/coo layouts; e.g. bfloat16 halves the HBM traffic of
+        only (dense/ell layouts; e.g. bfloat16 halves the HBM traffic of
         the objective sweeps on TPU). Labels/offsets/weights stay ``dtype``.
         """
         import jax.numpy as jnp
@@ -152,11 +151,10 @@ class RawDataset:
                 x, self.labels, self.offsets, self.weights, dtype=dtype,
                 feature_dtype=feature_dtype,
             )
-        if layout in ("ell", "sparse", "coo"):
+        if layout in ("ell", "sparse"):
             return batch_from_coo(
                 rows, cols, vals, self.labels, d, self.offsets, self.weights,
                 dtype=dtype,
-                layout="coo" if layout == "coo" else "ell",
                 feature_dtype=feature_dtype,
                 one_device=mesh is None,
             )
@@ -171,7 +169,7 @@ class RawDataset:
             )
         raise ValueError(
             f"unknown batch layout {layout!r}: expected "
-            "auto|dense|ell|sparse|coo|tiled"
+            "auto|dense|ell|sparse|tiled"
         )
 
 

@@ -9,11 +9,9 @@ offset, weight). On TPU the batch is a struct-of-arrays in one of two layouts:
   padding (idx=0, val=0). Margins are a gather + row-sum; gradient
   accumulation is a scatter-add (segment sum). Right layout for wide, sparse
   feature spaces where densification is impossible.
-- ``sorted COO``: flat ``(coo_cols, coo_rows, coo_vals)`` triplets sorted by
-  column. The gradient scatter-add runs with ``indices_are_sorted``, and the
-  column axis partitions contiguously for model-axis sharding (see
-  parallel/sparse.py); it is the layout a column-sharded solve needs, not
-  the faster one on one chip.
+
+(A column-sharded solve builds its own tiles from the triplets:
+parallel/sparse.py.)
 
 What a sparse pass costs, measured on a v5e at twice the rows of the
 benchmark's sparse cell (2,359,296 rows x 12 one-hot slots = 28.3M slots into
@@ -37,7 +35,8 @@ sparse throughput is bound by serialized random access (no HBM cache, no
 vectorized VMEM gather before SparseCore), and the design answer is to
 *divide* that cost across devices by (data x model) tiling, not to chase a
 magic kernel for the whole vector: tpu.dynamic_gather only shuffles within
-one (8, 128) vreg, and a 219 MB table lives in HBM.
+one (8, 128) vreg, and a 219 MB table lives in HBM. The sorted COO layout,
+measured 1.8-1.9x slower than ELL above, has been removed.
 
 The margins need not read the whole vector, though: the rows of one chip hold
 few of its columns. A one-device ELL batch at least ``LOCAL_MAP_MIN_DIM`` wide
@@ -92,17 +91,43 @@ Array = jax.Array
 # table, not from the whole coefficient vector (module docstring).
 LOCAL_MAP_MIN_DIM = 1 << 20
 
+# ELL index planes (``idx``, ``idx_local``, ``cols``) are int32
+_INDEX_ITEMSIZE = int(np.dtype(np.int32).itemsize)
+
+
+def carries_local_map(dim: int, one_device: bool) -> bool:
+    """Whether ``batch_from_coo`` gives an ELL batch of this width its local
+    column map."""
+    return one_device and dim >= LOCAL_MAP_MIN_DIM
+
+
+def feature_bytes(
+    n_rows: int, dim: int, layout: str, width: int = 0, itemsize: int = 4,
+    one_device: bool = False,
+) -> int:
+    """Device bytes of the feature matrix ``batch_from_dense`` (``dense``) or
+    ``batch_from_coo`` (``ell``, ``width`` slots a row) builds for ``n_rows``
+    rows, its values ``itemsize`` bytes each. An ELL batch that carries its
+    local column map also holds ``idx_local``, one more index a slot, and
+    ``cols``, at most one index a slot or a column."""
+    if layout == "dense":
+        return n_rows * dim * itemsize
+    if layout != "ell":
+        raise ValueError(f"feature_bytes: layout must be dense|ell, got {layout!r}")
+    slots = n_rows * width
+    nbytes = slots * (itemsize + _INDEX_ITEMSIZE)
+    if carries_local_map(dim, one_device):
+        nbytes += (slots + min(slots, dim)) * _INDEX_ITEMSIZE
+    return nbytes
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class FeatureMatrix:
-    """A batch of feature vectors: dense ``[n, d]``, padded-sparse (ELL), or
-    column-sorted COO.
+    """A batch of feature vectors: dense ``[n, d]`` or padded-sparse (ELL).
 
-    Exactly one of ``dense`` / (``idx``, ``val``) / (``coo_cols``,
-    ``coo_rows``, ``coo_vals``) is set. ``dim`` is the feature-space
-    dimension d (static so jitted shapes are known); ``coo_n_rows`` is the
-    static row count for the COO layout (not derivable from array shapes).
+    Exactly one of ``dense`` / (``idx``, ``val``) is set. ``dim`` is the
+    feature-space dimension d (static so jitted shapes are known).
 
     An ELL matrix may carry a local column map: ``cols`` (the sorted unique
     columns its slots hold, padding included) and ``idx_local`` (each slot's
@@ -116,28 +141,12 @@ class FeatureMatrix:
     val: Optional[Array] = None
     cols: Optional[Array] = None  # i32[d_loc], sorted unique columns of idx
     idx_local: Optional[Array] = None  # i32[n, k]: idx == cols[idx_local]
-    coo_cols: Optional[Array] = None  # i32[m], sorted ascending (pad: dim-1)
-    coo_rows: Optional[Array] = None  # i32[m] (pad: 0)
-    coo_vals: Optional[Array] = None  # f[m] (pad: 0)
-    coo_n_rows: int = dataclasses.field(default=0, metadata=dict(static=True))
 
     def __post_init__(self):
-        n_set = (
-            (self.dense is not None)
-            + (self.idx is not None)
-            + (self.coo_cols is not None)
-        )
-        if n_set != 1:
-            raise ValueError(
-                "exactly one of dense / (idx, val) / (coo_cols, coo_rows, coo_vals)"
-                " must be provided"
-            )
+        if (self.dense is None) == (self.idx is None):
+            raise ValueError("exactly one of dense / (idx, val) must be provided")
         if self.idx is not None and self.val is None:
             raise ValueError("ELL layout requires both idx and val")
-        if self.coo_cols is not None and (
-            self.coo_rows is None or self.coo_vals is None
-        ):
-            raise ValueError("COO layout requires coo_cols, coo_rows and coo_vals")
         if (self.cols is None) != (self.idx_local is None):
             raise ValueError("a local column map needs both cols and idx_local")
         if self.cols is not None and self.idx is None:
@@ -145,11 +154,7 @@ class FeatureMatrix:
 
     @property
     def layout(self) -> str:
-        if self.dense is not None:
-            return "dense"
-        if self.idx is not None:
-            return "ell"
-        return "coo"
+        return "dense" if self.dense is not None else "ell"
 
     @property
     def is_dense(self) -> bool:
@@ -157,21 +162,14 @@ class FeatureMatrix:
 
     @property
     def n_rows(self) -> int:
-        if self.dense is not None:
-            return self.dense.shape[0]
-        if self.idx is not None:
-            return self.idx.shape[0]
-        return self.coo_n_rows
+        return (self.dense if self.dense is not None else self.idx).shape[0]
 
     @property
     def slots(self) -> int:
         """Entries the layout stores, padding included: what one pass over the
-        matrix touches (n*d dense, n*k ELL, m COO). Host-known from shapes."""
-        if self.dense is not None:
-            return self.dense.shape[0] * self.dense.shape[1]
-        if self.idx is not None:
-            return self.idx.shape[0] * self.idx.shape[1]
-        return self.coo_cols.shape[0]
+        matrix touches (n*d dense, n*k ELL). Host-known from shapes."""
+        n, width = (self.dense if self.dense is not None else self.idx).shape
+        return n * width
 
     @property
     def gather(self) -> str:
@@ -188,22 +186,19 @@ class FeatureMatrix:
         """x @ w -> [n]."""
         if self.dense is not None:
             return self.dense @ w
-        if self.idx is not None:
-            if self.cols is None:
-                return jnp.sum(self.val.T * jnp.take(w, self.idx.T, axis=0), axis=0)
-            # Pallas loads with the first batch that has a map, as the GLM
-            # kernels load with their first fused call (ops/glm.py)
-            from . import pallas_gather
+        if self.cols is None:
+            return jnp.sum(self.val.T * jnp.take(w, self.idx.T, axis=0), axis=0)
+        # Pallas loads with the first batch that has a map, as the GLM
+        # kernels load with their first fused call (ops/glm.py)
+        from . import pallas_gather
 
-            table = jnp.take(w, self.cols, axis=0, indices_are_sorted=True, unique_indices=True)
-            how = pallas_gather.route(table.shape[0], self.idx_local.shape[1], table.dtype)
-            if how is None:
-                words = jnp.take(table, self.idx_local.T, axis=0)
-            else:
-                words = pallas_gather.gather(table, self.idx_local, interpret=how == "interpret")
-            return jnp.sum(self.val.T * words, axis=0)
-        wv = jnp.take(w, self.coo_cols) * self.coo_vals
-        return jnp.zeros(self.coo_n_rows, dtype=wv.dtype).at[self.coo_rows].add(wv)
+        table = jnp.take(w, self.cols, axis=0, indices_are_sorted=True, unique_indices=True)
+        how = pallas_gather.route(table.shape[0], self.idx_local.shape[1], table.dtype)
+        if how is None:
+            words = jnp.take(table, self.idx_local.T, axis=0)
+        else:
+            words = pallas_gather.gather(table, self.idx_local, interpret=how == "interpret")
+        return jnp.sum(self.val.T * words, axis=0)
 
     def matmat(self, w: Array) -> Array:
         """x @ w -> [n, L] for lane-stacked coefficients w[d, L].
@@ -213,29 +208,17 @@ class FeatureMatrix:
         separate matvec dispatches."""
         if self.dense is not None:
             return self.dense @ w
-        if self.idx is not None:
-            # take -> [n, k, L]; ELL values broadcast over the lane axis
-            return jnp.sum(
-                self.val[:, :, None] * jnp.take(w, self.idx, axis=0), axis=1
-            )
-        wv = jnp.take(w, self.coo_cols, axis=0) * self.coo_vals[:, None]
-        return jnp.zeros(
-            (self.coo_n_rows, w.shape[1]), dtype=wv.dtype
-        ).at[self.coo_rows].add(wv)
+        # take -> [n, k, L]; ELL values broadcast over the lane axis
+        return jnp.sum(self.val[:, :, None] * jnp.take(w, self.idx, axis=0), axis=1)
 
     def rmatvec(self, c: Array) -> Array:
         """x^T @ c -> [d]: the gradient-accumulation kernel."""
         if self.dense is not None:
             return self.dense.T @ c
-        if self.idx is not None:
-            contrib = c[None, :] * self.val.T
-            return jnp.zeros(self.dim, dtype=contrib.dtype).at[
-                self.idx.T.reshape(-1)
-            ].add(contrib.reshape(-1))
-        contrib = jnp.take(c, self.coo_rows) * self.coo_vals
-        return jnp.zeros(self.dim, dtype=contrib.dtype).at[self.coo_cols].add(
-            contrib, indices_are_sorted=True
-        )
+        contrib = c[None, :] * self.val.T
+        return jnp.zeros(self.dim, dtype=contrib.dtype).at[
+            self.idx.T.reshape(-1)
+        ].add(contrib.reshape(-1))
 
     def matvec_gathered(self, w: Array, sharding) -> Array:
         """``matvec`` of an ELL matrix whose rows are sharded over the axis
@@ -284,71 +267,29 @@ class FeatureMatrix:
         gradient-accumulation kernel of the lambda-lane sweep path."""
         if self.dense is not None:
             return self.dense.T @ c
-        if self.idx is not None:
-            contrib = c[:, None, :] * self.val[:, :, None]  # [n, k, L]
-            L = c.shape[1]
-            return jnp.zeros((self.dim, L), dtype=contrib.dtype).at[
-                self.idx.reshape(-1)
-            ].add(contrib.reshape(-1, L))
-        contrib = jnp.take(c, self.coo_rows, axis=0) * self.coo_vals[:, None]
-        return jnp.zeros((self.dim, c.shape[1]), dtype=contrib.dtype).at[
-            self.coo_cols
-        ].add(contrib, indices_are_sorted=True)
+        contrib = c[:, None, :] * self.val[:, :, None]  # [n, k, L]
+        L = c.shape[1]
+        return jnp.zeros((self.dim, L), dtype=contrib.dtype).at[
+            self.idx.reshape(-1)
+        ].add(contrib.reshape(-1, L))
 
     def sq_rmatvec(self, c: Array) -> Array:
         """(x*x)^T @ c -> [d]: Hessian-diagonal accumulation."""
         if self.dense is not None:
             return (self.dense * self.dense).T @ c
-        if self.idx is not None:
-            contrib = c[None, :] * self.val.T * self.val.T
-            return jnp.zeros(self.dim, dtype=contrib.dtype).at[
-                self.idx.T.reshape(-1)
-            ].add(contrib.reshape(-1))
-        contrib = jnp.take(c, self.coo_rows) * self.coo_vals * self.coo_vals
-        return jnp.zeros(self.dim, dtype=contrib.dtype).at[self.coo_cols].add(
-            contrib, indices_are_sorted=True
-        )
+        contrib = c[None, :] * self.val.T * self.val.T
+        return jnp.zeros(self.dim, dtype=contrib.dtype).at[
+            self.idx.T.reshape(-1)
+        ].add(contrib.reshape(-1))
 
     def to_dense(self) -> Array:
         if self.dense is not None:
             return self.dense
-        if self.idx is not None:
-            n = self.idx.shape[0]
-            out = jnp.zeros((n, self.dim), dtype=self.val.dtype)
-            rows = jnp.broadcast_to(jnp.arange(n)[:, None], self.idx.shape)
-            return out.at[rows.reshape(-1), self.idx.reshape(-1)].add(
-                self.val.reshape(-1)
-            )
-        out = jnp.zeros((self.coo_n_rows, self.dim), dtype=self.coo_vals.dtype)
-        return out.at[self.coo_rows, self.coo_cols].add(self.coo_vals)
-
-    def slice_rows(self, start: int, size: int) -> "FeatureMatrix":
-        if self.dense is not None:
-            return FeatureMatrix(dim=self.dim, dense=jax.lax.dynamic_slice_in_dim(self.dense, start, size))
-        if self.idx is None:
-            # COO row window with static shapes: the nnz arrays keep their
-            # length (so this jits with a traced ``start``); entries outside
-            # [start, start+size) are zeroed and rows rebased. Columns are
-            # untouched, so the sorted-scatter contract of rmatvec holds.
-            # Start is clamped to match dynamic_slice semantics of the other
-            # layouts.
-            start = jnp.clip(start, 0, max(self.coo_n_rows - size, 0))
-            in_range = (self.coo_rows >= start) & (self.coo_rows < start + size)
-            return FeatureMatrix(
-                dim=self.dim,
-                coo_cols=self.coo_cols,
-                coo_rows=jnp.where(in_range, self.coo_rows - start, 0).astype(
-                    self.coo_rows.dtype
-                ),
-                coo_vals=jnp.where(in_range, self.coo_vals, 0),
-                coo_n_rows=size,
-            )
-        return FeatureMatrix(
-            dim=self.dim,
-            idx=jax.lax.dynamic_slice_in_dim(self.idx, start, size),
-            val=jax.lax.dynamic_slice_in_dim(self.val, start, size),
-            cols=self.cols,
-            idx_local=None if self.cols is None else jax.lax.dynamic_slice_in_dim(self.idx_local, start, size),
+        n = self.idx.shape[0]
+        out = jnp.zeros((n, self.dim), dtype=self.val.dtype)
+        rows = jnp.broadcast_to(jnp.arange(n)[:, None], self.idx.shape)
+        return out.at[rows.reshape(-1), self.idx.reshape(-1)].add(
+            self.val.reshape(-1)
         )
 
 
@@ -403,39 +344,6 @@ def batch_from_dense(
     )
 
 
-def sorted_coo_matrix(
-    rows: np.ndarray,
-    cols: np.ndarray,
-    vals: np.ndarray,
-    n_rows: int,
-    dim: int,
-    dtype=jnp.float32,
-    pad_to_multiple: int = 1,
-) -> FeatureMatrix:
-    """Host-side build of the column-sorted COO layout (huge-d path).
-
-    Sorts triplets by column; padding entries (val=0) carry col=dim-1 so the
-    ``indices_are_sorted`` contract of rmatvec holds.
-    """
-    order = np.argsort(cols, kind="stable")
-    m = len(order)
-    m_pad = ((m + pad_to_multiple - 1) // pad_to_multiple) * pad_to_multiple
-    m_pad = max(m_pad, 1)
-    sc = np.full(m_pad, dim - 1, dtype=np.int32)
-    sr = np.zeros(m_pad, dtype=np.int32)
-    sv = np.zeros(m_pad, dtype=np.float64)
-    sc[:m] = cols[order]
-    sr[:m] = rows[order]
-    sv[:m] = vals[order]
-    return FeatureMatrix(
-        dim=dim,
-        coo_cols=jnp.asarray(sc, np.int32),
-        coo_rows=jnp.asarray(sr, np.int32),
-        coo_vals=jnp.asarray(sv, dtype),
-        coo_n_rows=n_rows,
-    )
-
-
 def _local_column_map(idx: np.ndarray, dim: int):
     """The sorted columns ``idx`` holds and each slot's position among them,
     by a mask over the columns: at ``fit-sparse``'s 14.16M slots 0.63 s on a
@@ -460,14 +368,11 @@ def batch_from_coo(
     weights: Optional[np.ndarray] = None,
     max_nnz: Optional[int] = None,
     dtype=jnp.float32,
-    layout: str = "ell",
     feature_dtype=None,
     one_device: bool = False,
 ) -> LabeledBatch:
-    """Build a sparse batch from COO triplets (host-side, numpy).
+    """Build an ELL batch from COO triplets (host-side, numpy).
 
-    layout='ell' gives the row-major padded layout (moderate d);
-    layout='coo' gives column-sorted COO (huge d; see module docstring).
     ``one_device``: the batch stays on one device, so an ELL batch at least
     ``LOCAL_MAP_MIN_DIM`` wide also gets its local column map.
     ``feature_dtype`` (e.g. bfloat16) stores ONLY the feature VALUES in a
@@ -475,33 +380,29 @@ def batch_from_coo(
     stay wide; elementwise products promote back to ``dtype`` on the fly.
     """
     n = len(y)
-    vdt = feature_dtype or dtype
-    if layout == "coo":
-        feats = sorted_coo_matrix(rows, cols, vals, n_rows=n, dim=dim, dtype=vdt)
-    else:
-        counts = np.bincount(rows, minlength=n)
-        k = int(max_nnz if max_nnz is not None else (counts.max() if n else 0))
-        k = max(k, 1)
-        idx = np.zeros((n, k), dtype=np.int32)
-        val = np.zeros((n, k), dtype=np.float64)
-        # stable row sort preserves input order within each row, so max_nnz
-        # truncation keeps the FIRST entries in input order (matching the
-        # documented contract; a column sort here would silently keep the
-        # lowest-column entries instead)
-        order = np.argsort(rows, kind="stable")
-        r_s, c_s, v_s = rows[order], cols[order], vals[order]
-        starts = np.cumsum(np.concatenate([[0], np.bincount(r_s, minlength=n)[:-1]]))
-        within = np.arange(len(r_s)) - starts[r_s]
-        keep = within < k
-        idx[r_s[keep], within[keep]] = c_s[keep]
-        val[r_s[keep], within[keep]] = v_s[keep]
-        local = {}
-        if one_device and dim >= LOCAL_MAP_MIN_DIM:
-            held, idx_local = _local_column_map(idx, dim)
-            local = dict(cols=jnp.asarray(held, np.int32), idx_local=jnp.asarray(idx_local, np.int32))
-        feats = FeatureMatrix(
-            dim=dim, idx=jnp.asarray(idx, np.int32), val=jnp.asarray(val, vdt), **local
-        )
+    counts = np.bincount(rows, minlength=n)
+    k = int(max_nnz if max_nnz is not None else (counts.max() if n else 0))
+    k = max(k, 1)
+    idx = np.zeros((n, k), dtype=np.int32)
+    val = np.zeros((n, k), dtype=np.float64)
+    # stable row sort preserves input order within each row, so max_nnz
+    # truncation keeps the FIRST entries in input order (matching the
+    # documented contract; a column sort here would silently keep the
+    # lowest-column entries instead)
+    order = np.argsort(rows, kind="stable")
+    r_s, c_s, v_s = rows[order], cols[order], vals[order]
+    starts = np.cumsum(np.concatenate([[0], np.bincount(r_s, minlength=n)[:-1]]))
+    within = np.arange(len(r_s)) - starts[r_s]
+    keep = within < k
+    idx[r_s[keep], within[keep]] = c_s[keep]
+    val[r_s[keep], within[keep]] = v_s[keep]
+    local = {}
+    if carries_local_map(dim, one_device):
+        held, idx_local = _local_column_map(idx, dim)
+        local = dict(cols=jnp.asarray(held, np.int32), idx_local=jnp.asarray(idx_local, np.int32))
+    feats = FeatureMatrix(
+        dim=dim, idx=jnp.asarray(idx, np.int32), val=jnp.asarray(val, feature_dtype or dtype), **local
+    )
     return LabeledBatch(
         features=feats,
         labels=jnp.asarray(y, dtype),
@@ -526,15 +427,12 @@ def pad_batch(batch: LabeledBatch, target_rows: int) -> LabeledBatch:
             dim=f.dim,
             dense=jnp.concatenate([f.dense, jnp.zeros((extra, f.dim), f.dense.dtype)]),
         )
-    elif f.idx is not None:
+    else:
         pad2 = lambda a: jnp.concatenate([a, jnp.zeros((extra, a.shape[1]), a.dtype)])
         feats = FeatureMatrix(
             dim=f.dim, idx=pad2(f.idx), val=pad2(f.val), cols=f.cols,
             idx_local=None if f.cols is None else pad2(f.idx_local),
         )
-    else:
-        # COO: padded rows have no nnz; only the static row count grows
-        feats = dataclasses.replace(f, coo_n_rows=target_rows)
     return LabeledBatch(
         features=feats,
         labels=pad1(batch.labels),

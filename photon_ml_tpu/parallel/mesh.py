@@ -76,13 +76,6 @@ def shard_batch(
 ) -> LabeledBatch:
     """Place a batch on the mesh: rows sharded over the data axis; feature
     columns optionally sharded over the model axis (dense layout only)."""
-    if getattr(batch.features, "layout", None) == "coo":
-        raise NotImplementedError(
-            "shard_batch does not support the column-sorted COO layout (its "
-            "nnz axis is column-major, not row-partitionable); for a "
-            "mesh-sharded huge-d batch build layout='tiled' "
-            "(parallel.sparse.tiled_sparse_batch)"
-        )
     batch = pad_rows_for_mesh(batch, mesh)
     row_spec = P(DATA_AXIS)
     put1 = lambda a: put_global(a, mesh, row_spec)

@@ -153,7 +153,7 @@ def _check_coordinate(cc, axes, n_processes) -> Tuple[str, ...]:
     """Per-coordinate legality; returns planner notes for the legal cases."""
     notes = []
     if cc.feature_dtype is not None and cc.layout == "tiled":
-        # dense/ell/coo fixed effects and RE entity blocks all accept narrow
+        # dense/ell fixed effects and RE entity blocks all accept narrow
         # feature storage (solver state stays wide); the tiled shard_map path
         # keeps its value arrays in the solve dtype
         raise PlanError(
@@ -189,22 +189,9 @@ def _check_coordinate(cc, axes, n_processes) -> Tuple[str, ...]:
             "estimator to be built with a device mesh"
         )
     if (
-        axes is not None
-        and not cc.is_random_effect
-        and cc.layout in ("coo", "sparse")
-        and cc.hbm_budget_mb is None
-    ):
-        # pre-empt parallel.mesh.shard_batch's runtime refusal at plan time
-        raise PlanError(
-            "shard_batch does not support the column-sorted COO layout (its "
-            "nnz axis is column-major, not row-partitionable); for a "
-            "mesh-sharded huge-d batch build layout='tiled' "
-            "(parallel.sparse.tiled_sparse_batch)"
-        )
-    if (
         n_processes > 1
         and not cc.is_random_effect
-        and cc.layout == "ell"
+        and cc.layout in ("ell", "sparse")
         and cc.hbm_budget_mb is None
     ):
         # pre-empt parallel.mesh.shard_batch's runtime refusal at plan time;

@@ -66,6 +66,30 @@ def test_parse_coordinate():
         parse_coordinate("name=x,shard=s,unknown.key=3")
 
 
+@pytest.mark.parametrize("layout", ["auto", "dense", "ell", "sparse", "tiled", "coo"])
+def test_a_coordinate_names_one_of_the_batch_layouts(layout):
+    """The two layouts a batch holds (``sparse`` is ELL), the mesh's tiles and
+    ``auto``; a configuration that names the sorted COO layout fails at
+    parse."""
+    spec = f"name=global,shard=g,layout={layout}"
+    if layout == "coo":
+        with pytest.raises(ValueError, match="unknown layout 'coo' in coordinate 'global'"):
+            parse_coordinate(spec)
+    else:
+        assert parse_coordinate(spec).layout == layout
+
+
+def test_a_batch_of_the_sorted_coo_layout_is_refused():
+    from photon_ml_tpu.io.data import RawDataset
+
+    raw = RawDataset(n_rows=2, labels=np.zeros(2), offsets=np.zeros(2), weights=np.ones(2),
+                     shard_coo={"g": (np.array([0, 1]), np.array([1, 0]), np.ones(2))},
+                     shard_dims={"g": 3}, id_tags={})
+    assert raw.to_batch("g", layout="sparse").features.layout == "ell"
+    with pytest.raises(ValueError, match="unknown batch layout 'coo'"):
+        raw.to_batch("g", layout="coo")
+
+
 def test_train_and_score_round_trip(avro_paths, tmp_path):
     train_p, val_p = avro_paths
     out = str(tmp_path / "out")

@@ -107,7 +107,7 @@ def test_budget_decision(raw):
     assert streamed.hbm_budget_bytes == 16 << 10
     with pytest.raises(ValueError, match="row-sliceable layout"):
         build_fixed_effect_dataset(
-            raw, "global", "global", layout="coo", hbm_budget_bytes=0
+            raw, "global", "global", layout="tiled", hbm_budget_bytes=0
         )
 
 
@@ -551,5 +551,5 @@ def test_disk_to_slice_refuses_non_row_sliceable_layout(tmp_path):
     shards = {"global": FeatureShardConfig(feature_bags=("features",))}
     with pytest.raises(ValueError, match="requires a row-sliceable layout"):
         build_fixed_effect_dataset_from_disk(
-            str(train_dir), shards, "global", "global", 1 << 20, layout="coo"
+            str(train_dir), shards, "global", "global", 1 << 20, layout="tiled"
         )

@@ -52,7 +52,7 @@ _matvec = jax.jit(lambda f, w: f.matvec(w))
 # -- the margins, bit for bit ---------------------------------------------------------------
 
 
-@pytest.mark.parametrize("case", ["random", "padded-slots", "slice_rows", "pad_batch", "validation"])
+@pytest.mark.parametrize("case", ["random", "padded-slots", "pad_batch", "validation"])
 def test_margins_from_the_local_table_are_the_whole_vectors_bit_for_bit(case):
     n = 8192 if case == "validation" else 1000
     batch = _raw(n, WIDE, seed=len(case), held=3000).to_batch(SHARD, dtype=jnp.float32)
@@ -64,9 +64,6 @@ def test_margins_from_the_local_table_are_the_whole_vectors_bit_for_bit(case):
     assert np.array_equal(np.asarray(f.cols)[np.asarray(f.idx_local)], idx)
     if case == "padded-slots":
         assert (np.asarray(f.val) == 0).any()
-    if case == "slice_rows":
-        f = f.slice_rows(100, 512)
-        assert f.gather == "local" and f.idx_local.shape == (512, idx.shape[1])
     if case == "pad_batch":
         f = pad_batch(batch, 1536).features
         assert f.idx_local.shape[0] == 1536
@@ -196,7 +193,7 @@ def test_a_wide_lbfgs_solve_is_the_same_with_and_without_the_map():
 # -- which batches carry one ------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("case", ["wide-ell", "narrow-ell", "dense", "coo", "tiled", "sharded", "streamed"])
+@pytest.mark.parametrize("case", ["wide-ell", "narrow-ell", "dense", "tiled", "sharded", "streamed"])
 def test_only_a_wide_ell_batch_for_one_device_carries_a_map(case):
     from photon_ml_tpu.parallel.mesh import make_mesh, shard_batch
 
@@ -232,7 +229,7 @@ def test_only_a_wide_ell_batch_for_one_device_carries_a_map(case):
             assert raw.to_batch(SHARD, dtype=jnp.float32, mesh=mesh).features.gather == "global"
         assert getattr(f, "cols", None) is None and getattr(f, "gather", "global") == "global"
         return
-    layout = {"dense": "dense", "coo": "coo"}.get(case, "ell")
+    layout = "dense" if case == "dense" else "ell"
     f = raw.to_batch(SHARD, dtype=jnp.float32, layout=layout).features
     assert f.layout == layout
     assert (f.gather, f.gather_columns) == (("local", f.cols.shape[0]) if case == "wide-ell" else ("global", d))

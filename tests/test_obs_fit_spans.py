@@ -79,13 +79,18 @@ def _estimator():
     )
 
 
+def _data(n, users, d_re):
+    full = mixed_data_to_raw_dataset(
+        generate_mixed_effect_data(n=n, d_fixed=5, re_specs={"userId": (users, d_re)}, seed=11)
+    )
+    n_train = n * 7 // 9
+    train, val = full.subset(np.arange(n_train)), full.subset(np.arange(n_train, n))
+    return train, val, _estimator().prepare_datasets(train)
+
+
 @pytest.fixture(scope="module")
 def data():
-    full = mixed_data_to_raw_dataset(
-        generate_mixed_effect_data(n=900, d_fixed=5, re_specs={"userId": (40, 4)}, seed=11)
-    )
-    train, val = full.subset(np.arange(700)), full.subset(np.arange(700, 900))
-    return train, val, _estimator().prepare_datasets(train)
+    return _data(900, 40, 4)
 
 
 def _fit(data, run, n_fits=1):
@@ -96,6 +101,20 @@ def _fit(data, run, n_fits=1):
 
 @pytest.fixture(scope="module")
 def traced(data):
+    return _traced(data)
+
+
+@pytest.fixture(scope="module")
+def traced_at_size():
+    """``traced`` where a per-user train call takes tens of milliseconds on
+    the CPU (160 users x 32 features), not the few of ``data``: there the
+    loop's own ~1 ms was a tenth of a call, so one preemption of the host
+    thread between two children (3.5 ms, seen under six loaded workers)
+    decided the share."""
+    return _traced(_data(16000, 160, 32))
+
+
+def _traced(data):
     """Two fits with a collecting listener and the timeline recorder. (The
     INFO optimization summary fetches on its own account, under
     cd.coordinate, and an earlier test of the process may have left INFO on.)"""
@@ -262,11 +281,11 @@ def test_a_fetch_outside_any_span_is_counted_and_makes_no_span():
     assert _counter(snapshot, "photon_device_fetch_seconds_total", site="somewhere") > 0.0
 
 
-def test_a_random_effects_train_call_is_covered_by_its_children(traced):
-    """``re.exchange``, ``re.warm_start``, the buckets and their sink-only
-    fetches, ``re.collect``: what is left of ``cd.train`` is the loop's own
-    Python (most of a CPU fit at this size; the chip's figure is PERF.md's)."""
-    _, spans, _, _ = traced
+def test_a_random_effects_train_call_is_covered_by_its_children(traced_at_size):
+    """``re.exchange``, ``re.warm_start``, the buckets, their sink-only fetch
+    and ``re.collect``: what is left of ``cd.train`` is the loop's own
+    Python (the chip's figure is PERF.md's)."""
+    _, spans, _, _ = traced_at_size
     trains = [s for s in spans if s.name == "cd.train" and s.attrs["coordinate"] == "per-user"]
     assert len(trains) == 2 * N_SWEEPS
     for train in trains:

@@ -2,6 +2,8 @@
 mirroring the reference's OptimizerIntegTest / IntegTestObjective strategy
 (SURVEY.md §4): L-BFGS, OWL-QN, TRON on analytic objectives and real GLM fits."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -552,9 +554,21 @@ def test_batched_lanes_of_1_2_and_4_trials_cost_the_slowest_lanes_trials(rng):
 # -- the margins search against the points search (PR 37) ----------------------------
 
 
+def _with_local_map(batch):
+    """The batch with the local column map ``batch_from_coo`` gives a one-device
+    ELL batch at least ``LOCAL_MAP_MIN_DIM`` wide, built at this batch's width."""
+    from photon_ml_tpu.ops.features import _local_column_map
+
+    f = batch.features
+    cols, idx_local = _local_column_map(np.asarray(f.idx), f.dim)
+    features = dataclasses.replace(f, cols=jnp.asarray(cols), idx_local=jnp.asarray(idx_local))
+    return dataclasses.replace(batch, features=features)
+
+
 def _margin_case(layout, loss_name, variant, dtype=jnp.float64, l2=None):
     """A small GLM whose first full steps overshoot (so searches try several
-    lengths), as (two-pass objective, warm start)."""
+    lengths), as (two-pass objective, warm start). ``ell_local`` is an ELL
+    batch with the local column map a wide one-device batch carries."""
     from photon_ml_tpu.ops import NormalizationContext, batch_from_coo, get_loss
 
     rng = np.random.default_rng(37)
@@ -579,7 +593,9 @@ def _margin_case(layout, loss_name, variant, dtype=jnp.float64, l2=None):
     if layout == "dense":
         batch = batch_from_dense(dense, y, offsets=offsets, weights=weights, dtype=dtype)
     else:
-        batch = batch_from_coo(r, c, v, y, d, offsets=offsets, weights=weights, dtype=dtype, layout=layout)
+        batch = batch_from_coo(r, c, v, y, d, offsets=offsets, weights=weights, dtype=dtype)
+    if layout == "ell_local":
+        batch = _with_local_map(batch)
     extra = {}
     if variant == "normalized":
         factors = np.append(rng.uniform(0.5, 2.0, size=d - 1), 1.0)
@@ -597,7 +613,7 @@ def _margin_case(layout, loss_name, variant, dtype=jnp.float64, l2=None):
 
 @pytest.mark.parametrize("variant", ["plain", "offsets_padded", "normalized", "prior", "warm"])
 @pytest.mark.parametrize("loss_name", ["logistic", "poisson", "squared", "smoothed_hinge"])
-@pytest.mark.parametrize("layout", ["ell", "coo", "dense"])
+@pytest.mark.parametrize("layout", ["ell", "ell_local", "dense"])
 def test_the_margins_search_takes_the_points_searchs_steps(layout, loss_name, variant):
     """Same verdicts on the same two numbers: step lengths, iterations, trials
     and coefficients of a solve that walks margins are those of one that
@@ -658,7 +674,7 @@ def test_the_margins_search_takes_the_points_searchs_steps(layout, loss_name, va
 @pytest.mark.parametrize("layout, loss_name, variant, l2", [
     ("ell", "logistic", "plain", None),
     ("ell", "logistic", "plain", 1e-3),
-    ("coo", "poisson", "offsets_padded", None),
+    ("ell_local", "poisson", "offsets_padded", None),
     ("dense", "smoothed_hinge", "normalized", None),
     ("dense", "squared", "prior", None),
     ("ell", "squared", "warm", 1e-4),
@@ -732,7 +748,7 @@ def _same_solve(a, b):
 
 @pytest.mark.parametrize("variant", ["offsets_padded", "normalized", "prior"])
 @pytest.mark.parametrize("loss_name", ["logistic", "poisson", "squared", "smoothed_hinge"])
-@pytest.mark.parametrize("layout", ["ell", "coo", "dense"])
+@pytest.mark.parametrize("layout", ["ell", "ell_local", "dense"])
 def test_a_walking_solve_from_zero_starts_from_the_state_its_tolerances_read(layout, loss_name, variant):
     """The tolerance pass of a solve that walks margins takes the margins at
     zero as the offsets (X 0 = 0, and at zero normalization's factors and
@@ -815,7 +831,7 @@ def _wide_case(mode):
         c = np.concatenate([rng.choice(columns, size=(n, k - 1)), np.full((n, 1), d - 1)], axis=1).reshape(-1)
         v = np.concatenate([3.0 * rng.normal(size=(n, k - 1)), np.ones((n, 1))], axis=1).reshape(-1)
         y = (rng.uniform(size=n) < 0.4).astype(float)
-        batch = batch_from_coo(r, c, v, y, d, dtype=jnp.float64, layout="ell")
+        batch = batch_from_coo(r, c, v, y, d, dtype=jnp.float64)
         obj = GLMObjective(loss=get_loss("logistic"), batch=batch, l2=0.3)
         return dict(value_and_grad=vg_fn(obj), w0=jnp.zeros(d), margins=MarginFns(*margin_fns(obj)), **common)
     if mode == "owlqn":

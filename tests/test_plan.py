@@ -67,7 +67,7 @@ class _CC:
 
 
 KINDS = (False, True)  # is_random_effect
-LAYOUTS = ("auto", "dense", "ell", "coo", "tiled")
+LAYOUTS = ("auto", "dense", "ell", "sparse", "tiled")
 DTYPES = (None, "bfloat16")
 MESHES = (None, {"data": 8, "model": 1}, {"data": 4, "model": 2})
 PROCESSES = (1, 2)
@@ -147,12 +147,9 @@ def test_planner_preempts_deep_stack_runtime_refusals():
     NotImplementedError deep inside shard_batch/shard_coefficients) must be
     refused by the planner up front, with the same ledger message."""
     mesh = {"data": 8, "model": 1}
-    with pytest.raises(
-        PlanError, match="shard_batch does not support the column-sorted COO"
-    ):
-        resolve([_CC(layout="coo")], mesh=mesh, n_processes=1)
-    with pytest.raises(PlanError, match="multi-process ELL sharding"):
-        resolve([_CC(layout="ell")], mesh=mesh, n_processes=2, distributed=True)
+    for layout in ("ell", "sparse"):
+        with pytest.raises(PlanError, match="multi-process ELL sharding"):
+            resolve([_CC(layout=layout)], mesh=mesh, n_processes=2, distributed=True)
     with pytest.raises(
         PlanError, match="model-axis sharding across processes"
     ):
@@ -164,6 +161,15 @@ def test_planner_preempts_deep_stack_runtime_refusals():
         PlanError, match="multi-process training requires a device mesh"
     ):
         check_multiprocess_mesh(2, None)
+
+
+@pytest.mark.parametrize("mesh", [{"data": 8, "model": 1}, {"data": 4, "model": 2}])
+def test_sparse_on_a_mesh_plans_as_ell_does(mesh):
+    """``sparse`` is the ELL layout by another name: on a mesh it plans, with
+    the routing ``ell`` gets."""
+    ell, sparse = (resolve([_CC(layout=layout)], mesh=mesh, n_processes=1) for layout in ("ell", "sparse"))
+    (want,), (got,) = ell.coordinates, sparse.coordinates
+    assert got.layout == "sparse" and dataclasses.replace(got, layout="ell") == want
 
 
 def test_newly_legal_compositions_resolve():

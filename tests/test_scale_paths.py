@@ -1,5 +1,5 @@
-"""Round-3 scale-path regressions: COO row slicing, vectorized model
-projection at non-trivial sizes, and the dealt entity layout's chunk-local
+"""Round-3 scale-path regressions: vectorized model projection at
+non-trivial sizes, and the dealt entity layout's chunk-local
 size buckets."""
 
 import dataclasses
@@ -11,7 +11,6 @@ import pytest
 
 from photon_ml_tpu.game.data import build_random_effect_dataset
 from photon_ml_tpu.models.game import RandomEffectModel
-from photon_ml_tpu.ops.features import FeatureMatrix, sorted_coo_matrix
 from photon_ml_tpu.testing import generate_mixed_effect_data
 from photon_ml_tpu.testing.generators import mixed_data_to_raw_dataset
 
@@ -19,61 +18,6 @@ from photon_ml_tpu.testing.generators import mixed_data_to_raw_dataset
 @pytest.fixture
 def rng():
     return np.random.default_rng(7)
-
-
-def _random_coo(rng, n=40, d=25, nnz=160):
-    rows = rng.integers(0, n, size=nnz)
-    cols = rng.integers(0, d, size=nnz)
-    vals = rng.normal(size=nnz)
-    # collapse duplicate (row, col) pairs — scatter-add would double-count
-    keys = rows * d + cols
-    _, first = np.unique(keys, return_index=True)
-    return rows[first], cols[first], vals[first], n, d
-
-
-def test_coo_slice_rows_matches_dense(rng):
-    rows, cols, vals, n, d = _random_coo(rng)
-    fm = sorted_coo_matrix(rows, cols, vals, n_rows=n, dim=d, dtype=jnp.float32)
-    dense = np.asarray(fm.to_dense())
-    w = rng.normal(size=d).astype(np.float32)
-    c = rng.normal(size=12).astype(np.float32)
-    for start in (0, 5, n - 12):
-        sl = fm.slice_rows(start, 12)
-        assert sl.layout == "coo" and sl.n_rows == 12
-        np.testing.assert_allclose(
-            np.asarray(sl.to_dense()), dense[start : start + 12], rtol=1e-6
-        )
-        np.testing.assert_allclose(
-            np.asarray(sl.matvec(jnp.asarray(w))),
-            dense[start : start + 12] @ w,
-            rtol=1e-4,
-            atol=1e-5,
-        )
-        np.testing.assert_allclose(
-            np.asarray(sl.rmatvec(jnp.asarray(c))),
-            dense[start : start + 12].T @ c,
-            rtol=1e-4,
-            atol=1e-5,
-        )
-
-
-def test_coo_slice_rows_under_jit(rng):
-    rows, cols, vals, n, d = _random_coo(rng)
-    fm = sorted_coo_matrix(rows, cols, vals, n_rows=n, dim=d, dtype=jnp.float32)
-    dense = np.asarray(fm.to_dense())
-    w = jnp.asarray(rng.normal(size=d).astype(np.float32))
-
-    @jax.jit
-    def windowed_margins(fm, start):
-        return fm.slice_rows(start, 8).matvec(w)
-
-    for start in (0, 3, 17):
-        np.testing.assert_allclose(
-            np.asarray(windowed_margins(fm, start)),
-            dense[start : start + 8] @ np.asarray(w),
-            rtol=1e-4,
-            atol=1e-5,
-        )
 
 
 def test_project_model_values_general_path_large(rng):
@@ -319,9 +263,9 @@ def test_estimator_tiled_fixed_effect_matches_dense():
         )
 
 
-def test_cli_trains_coo_layout(tmp_path):
-    """A CLI run trains a sorted-COO fixed effect end-to-end (VERDICT r2
-    item 1: the huge-d layouts must be reachable from the driver)."""
+def test_cli_trains_ell_layout(tmp_path):
+    """A CLI run trains an ELL fixed effect end-to-end: the sparse layout
+    is reachable from the command line, not only from the estimator."""
     from photon_ml_tpu.cli.train import run as train_run
     from photon_ml_tpu.io import write_avro_file
     from photon_ml_tpu.io.schemas import TRAINING_EXAMPLE_AVRO
@@ -353,7 +297,7 @@ def test_cli_trains_coo_layout(tmp_path):
             "--feature-shard", "name=global,bags=features",
             "--feature-shard", "name=userShard,bags=userFeatures",
             "--coordinate",
-            "name=global,shard=global,optimizer=LBFGS,reg.type=L2,reg.weights=1,layout=coo",
+            "name=global,shard=global,optimizer=LBFGS,reg.type=L2,reg.weights=1,layout=ell",
             "--coordinate",
             "name=per-user,shard=userShard,re.type=userId,reg.type=L2,reg.weights=1",
             "--evaluators", "AUC",

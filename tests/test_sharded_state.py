@@ -70,7 +70,7 @@ def _solve(batch, config=None):
 @pytest.fixture(scope="module")
 def wide():
     rows, cols, vals, y = _rows(WIDE)
-    one = batch_from_coo(rows, cols, vals, y, WIDE, dtype=jnp.float64, layout="ell")
+    one = batch_from_coo(rows, cols, vals, y, WIDE, dtype=jnp.float64)
     model, result, span, _ = _solve(one)
     return (rows, cols, vals, y), one, model, result, span
 
@@ -102,7 +102,7 @@ def test_the_rule_fires_only_for_a_wide_row_sharded_ell_solve_that_keeps_a_histo
     assert fired == NamedSharding(mesh, PartitionSpec("data"))
     # one device, a narrow width, TRON, a dense batch: the parent's programs
     assert state_sharding(one, lbfgs_config) is None
-    narrow = batch_from_coo(rows, cols % 4000, vals, y, 4000, dtype=jnp.float64, layout="ell")
+    narrow = batch_from_coo(rows, cols % 4000, vals, y, 4000, dtype=jnp.float64)
     assert state_sharding(shard_batch(narrow, mesh), lbfgs_config) is None
     assert state_sharding(shard_batch(one, mesh), _config(OptimizerType.TRON).solver_config()) is None
     one_device = make_mesh(n_data=1, n_model=1, devices=jax.devices()[:1])

@@ -1,10 +1,11 @@
-"""The sparse fixed effect (PR 34): the program's ELL and sorted-COO passes held
-to the plain triplet reference (benchmark/reference/glm_sparse.py), the whole
-fit under ``layout="auto"`` on the benchmark's one-hot law held to the float64
-optimum, and what a sink sees of such a solve: the passes a plain L-BFGS
-counted, the slots its layout pads, and a final gradient NORM instead of the
-gradient."""
+"""The sparse fixed effect: the program's ELL passes, with and without the
+local column map, held to the plain triplet reference
+(benchmark/reference/glm_sparse.py), the whole fit under ``layout="auto"``
+on the benchmark's one-hot law held to the float64 optimum, and what a sink
+sees of such a solve: the passes a plain L-BFGS counted, the slots its
+layout pads, and a final gradient NORM instead of the gradient."""
 
+import dataclasses
 import hashlib
 
 import jax
@@ -18,7 +19,7 @@ from photon_ml_tpu import obs
 from photon_ml_tpu.estimators.game_estimator import CoordinateConfig, GameEstimator
 from photon_ml_tpu.game.problem import GLMOptimizationConfig, GLMProblem
 from photon_ml_tpu.io.data import RawDataset
-from photon_ml_tpu.ops.features import FeatureMatrix, batch_from_coo
+from photon_ml_tpu.ops.features import FeatureMatrix, _local_column_map, batch_from_coo
 from photon_ml_tpu.ops.glm import GLMObjective
 from photon_ml_tpu.ops.losses import get_loss
 from photon_ml_tpu.ops.regularization import RegularizationContext
@@ -46,6 +47,15 @@ def _raw(rows, signs, n=None):
                       shard_coo={SHARD: gen.triplets(cols, signs)}, shard_dims={SHARD: D}, id_tags={})
 
 
+def _with_local_map(batch):
+    """The batch with the local column map ``batch_from_coo`` gives a one-device
+    ELL batch at least ``LOCAL_MAP_MIN_DIM`` wide, built at this batch's width."""
+    f = batch.features
+    cols, idx_local = _local_column_map(np.asarray(f.idx), f.dim)
+    features = dataclasses.replace(f, cols=jnp.asarray(cols), idx_local=jnp.asarray(idx_local))
+    return dataclasses.replace(batch, features=features)
+
+
 def _config(reg_weight, optimizer=OptimizerType.LBFGS, tolerance=1e-6, max_iterations=100):
     return GLMOptimizationConfig(
         optimizer=OptimizerConfig(optimizer_type=optimizer, tolerance=tolerance, max_iterations=max_iterations),
@@ -56,14 +66,16 @@ def _config(reg_weight, optimizer=OptimizerType.LBFGS, tolerance=1e-6, max_itera
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64], ids=["f32", "f64"])
-@pytest.mark.parametrize("layout", ["ell", "coo"])
+@pytest.mark.parametrize("layout", ["ell", "ell_local"])
 def test_value_gradient_and_hv_match_the_triplet_reference(law_rows, layout, dtype):
     law, rows = law_rows
     rng = np.random.default_rng(3)
     signs = gen.draw_signs(2**31 + 9, D)
     r, c, v = gen.triplets(rows.cols, signs)
-    batch = batch_from_coo(r, c, v, rows.labels, D, layout=layout, dtype=dtype)
-    assert batch.features.layout == layout
+    batch = batch_from_coo(r, c, v, rows.labels, D, dtype=dtype)
+    if layout == "ell_local":
+        batch = _with_local_map(batch)
+    assert batch.features.gather == ("local" if layout == "ell_local" else "global")
     w = jnp.asarray(rng.standard_normal(D) / np.sqrt(12), dtype)
     u = jnp.asarray(rng.standard_normal(D) / np.sqrt(12), dtype)
     objective = GLMObjective(loss=get_loss("logistic_regression"), batch=batch, l2=0.75)
@@ -312,14 +324,16 @@ def test_a_dataset_without_a_build_counts_no_slots(ragged_batch):
     assert ds.nnz is None and not _series(run.registry, "photon_fe_slots_total")
 
 
-@pytest.mark.parametrize("layout,slots", [("dense", 7 * 5), ("ell", 7 * 2), ("coo", 9)])
+@pytest.mark.parametrize("layout,slots", [("dense", 7 * 5), ("ell", 7 * 2), ("ell_local", 7 * 2)])
 def test_slots_are_what_a_pass_touches(layout, slots):
+    """A local column map adds no slots: a pass still touches n*k."""
     r = np.array([0, 0, 1, 2, 3, 3, 4, 5, 6])
     c = np.array([0, 4, 1, 2, 3, 0, 4, 4, 1])
     raw = RawDataset(n_rows=7, labels=np.zeros(7), offsets=np.zeros(7), weights=np.ones(7),
                      shard_coo={SHARD: (r, c, np.ones(9))}, shard_dims={SHARD: 5}, id_tags={})
-    f = raw.to_batch(SHARD, layout=layout).features
-    assert isinstance(f, FeatureMatrix) and f.layout == layout and f.slots == slots
+    batch = raw.to_batch(SHARD, layout=layout.removesuffix("_local"))
+    f = (_with_local_map(batch) if layout == "ell_local" else batch).features
+    assert isinstance(f, FeatureMatrix) and f.layout == layout.removesuffix("_local") and f.slots == slots
 
 
 # -- the search that walks margins (PR 37): who gets it, what it counts -----------------------

@@ -1,8 +1,8 @@
-"""Huge-d sparse path: sorted-COO layout and (data x model)-tiled sharding.
+"""Huge-d sparse path: (data x model)-tiled sharding.
 
 Round-1 verdict item 1: an 8-device virtual-mesh test asserting that the
 model-axis-sharded fixed-effect solve is exactly the replicated solve, plus
-kernel-level parity of every layout against dense.
+kernel-level parity of the tiled layout against dense.
 """
 
 import jax
@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from photon_ml_tpu.ops import GLMObjective, LOGISTIC, batch_from_coo, batch_from_dense
-from photon_ml_tpu.ops.features import sorted_coo_matrix
 from photon_ml_tpu.optimize import OptimizerConfig, optimize
 from photon_ml_tpu.parallel import make_mesh
 from photon_ml_tpu.parallel.sparse import (
@@ -38,21 +37,6 @@ def _dense_of(rows, cols, vals, n, d):
     x = np.zeros((n, d))
     np.add.at(x, (rows, cols), vals)
     return x
-
-
-def test_sorted_coo_matches_dense(rng):
-    n, d, k = 64, 300, 5
-    rows, cols, vals = _random_coo(rng, n, d, k)
-    x = _dense_of(rows, cols, vals, n, d)
-    fm = sorted_coo_matrix(rows, cols, vals, n_rows=n, dim=d, dtype=jnp.float64)
-    w = rng.normal(size=d)
-    c = rng.normal(size=n)
-    np.testing.assert_allclose(np.asarray(fm.matvec(jnp.asarray(w))), x @ w, rtol=1e-10)
-    np.testing.assert_allclose(np.asarray(fm.rmatvec(jnp.asarray(c))), x.T @ c, rtol=1e-10)
-    np.testing.assert_allclose(
-        np.asarray(fm.sq_rmatvec(jnp.asarray(c))), (x * x).T @ c, rtol=1e-10
-    )
-    np.testing.assert_allclose(np.asarray(fm.to_dense()), x, rtol=1e-12)
 
 
 @pytest.mark.parametrize("shape", [(1, 1), (8, 1), (1, 8), (4, 2), (2, 4)])
@@ -105,12 +89,12 @@ def test_sharded_solve_equals_replicated_solve(rng):
     np.testing.assert_allclose(w_sharded[:d], np.asarray(res_ref.coefficients), atol=1e-8)
     assert np.all(w_sharded[d:] == 0)
 
-    # and the COO single-device layout agrees too
-    coo_batch = batch_from_coo(rows, cols, vals, y, d, dtype=jnp.float64, layout="coo")
-    obj_c = GLMObjective(loss=LOGISTIC, batch=coo_batch, l2=lam)
-    res_c = optimize(obj_c.value_and_grad, jnp.zeros(d, jnp.float64), cfg)
+    # and the ELL single-device layout agrees too
+    ell_batch = batch_from_coo(rows, cols, vals, y, d, dtype=jnp.float64)
+    obj_e = GLMObjective(loss=LOGISTIC, batch=ell_batch, l2=lam)
+    res_e = optimize(obj_e.value_and_grad, jnp.zeros(d, jnp.float64), cfg)
     np.testing.assert_allclose(
-        np.asarray(res_c.coefficients), np.asarray(res_ref.coefficients), atol=1e-8
+        np.asarray(res_e.coefficients), np.asarray(res_ref.coefficients), atol=1e-8
     )
 
 
@@ -132,7 +116,7 @@ def test_a_sharded_solve_wide_enough_for_a_history_by_rows_equals_the_one_device
     y = (rng.uniform(size=n) < 0.4).astype(np.float64)
     cfg = OptimizerConfig(tolerance=1e-10, max_iterations=200)
 
-    one = batch_from_coo(rows, cols, vals, y, d, dtype=jnp.float64, layout="coo")
+    one = batch_from_coo(rows, cols, vals, y, d, dtype=jnp.float64)
     reference = optimize(GLMObjective(loss=LOGISTIC, batch=one, l2=0.5).value_and_grad, jnp.zeros(d, jnp.float64), cfg)
 
     mesh = make_mesh(n_data=data, n_model=model)

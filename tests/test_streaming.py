@@ -134,7 +134,7 @@ def test_estimator_streamed_fixed_policy_and_mesh():
             coordinate_configs=[
                 CoordinateConfig(
                     name="global", feature_shard="g", config=cfg,
-                    hbm_budget_mb=64, layout="coo",
+                    hbm_budget_mb=64, layout="tiled",
                 )
             ],
         )

@@ -89,7 +89,7 @@ def _trigger_tiled_batch_no_mesh(raw):
 
 
 def _trigger_streamed_fe_bad_layout(raw):
-    _estimator([_fe(layout="coo", hbm_budget_mb=1)])
+    _estimator([_fe(layout="tiled", hbm_budget_mb=1)])
 
 
 def _trigger_streamed_fe_variance(raw):
@@ -116,11 +116,6 @@ def _trigger_standardization_no_intercept(raw):
     build_normalization(
         "STANDARDIZATION", np.ones(d), np.ones(d), np.ones(d), intercept_index=None
     )
-
-
-def _trigger_coo_on_mesh(raw):
-    batch = raw.to_batch("global", layout="coo")
-    mesh_mod.shard_batch(batch, mesh_mod.make_mesh(n_data=len(jax.devices())))
 
 
 def _trigger_multiprocess_ell(raw, monkeypatch):
@@ -166,7 +161,7 @@ def _trigger_disk_slice_bad_layout(raw, tmp_path):
         "global",
         "global",
         1 << 20,
-        layout="coo",
+        layout="tiled",
     )
 
 
@@ -531,12 +526,6 @@ CASES = [
         "STANDARDIZATION requires an intercept term",
         ValueError,
         _trigger_standardization_no_intercept,
-    ),
-    (
-        "coo-on-mesh",
-        "shard_batch does not support the column-sorted COO layout",
-        NotImplementedError,
-        _trigger_coo_on_mesh,
     ),
     (
         "multiprocess-ell",

@@ -190,8 +190,7 @@ def history_row_copies(text, m, d):
     return found
 
 
-@pytest.mark.parametrize("layout,limit_gb", [("ell", 8.2), ("coo", 8.3)])
-def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_gb, monkeypatch):
+def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, monkeypatch):
     """``jit__solve`` at one chip's whole share of the sparse deployment
     (2,359,296 rows x 12 slots into 54,686,453 columns, plain L-BFGS, m = 10;
     the cell runs half those rows). The TPU tiles a ``[10, d]`` history as
@@ -205,23 +204,23 @@ def test_the_sparse_solve_fits_one_chip_beside_its_history(topo, layout, limit_g
     rest: with the ELL sums written over ``[n, k]`` intermediates the compiler
     padded each to 128 lanes (1.2 GB, three alive at once: 14.2 GB of
     temporaries, no room on a 16 GB chip); over ``[k, n]`` (the row axis minor,
-    as the arrays lie on the device) nothing is padded, as over sorted COO.
+    as the arrays lie on the device) nothing is padded.
     Since PR 37 the solve walks margins (what ``GLMProblem.run`` hands a two-pass
     objective's L-BFGS), the search's loop carrying scalars; the objective comes
     as an argument once for every margin step that reads it (the same buffers,
-    counted each time: 7.77 / 7.88 GB as counted here, 10.39 / 10.50 over the
-    tiled history). It is handed its start (f, g, z at w0) as operands. The ELL
+    counted each time: 7.77 GB as counted here, 10.39 over the tiled
+    history). It is handed its start (f, g, z at w0) as operands. The ELL
     batch brings its local column map (2,899,743 held columns at these rows)
     and its margins gather through the Pallas table gather: 7.69 GB, the
     table's 11.6 MB more arguments than with the global gather."""
     from photon_ml_tpu.optimize import lbfgs
 
     n, k, d = 2_359_296, SPARSE_SLOTS, SPARSE_DIM
-    compiled = _sparse_solve(topo, n, layout, monkeypatch)
+    compiled = _sparse_solve(topo, n, monkeypatch)
     memory = compiled.memory_analysis()
     total = memory.temp_size_in_bytes + memory.argument_size_in_bytes + memory.output_size_in_bytes
     assert memory.temp_size_in_bytes < 6.5e9, memory.temp_size_in_bytes
-    assert total < limit_gb * 1e9, total
+    assert total < 8.2e9, total
     text = compiled.as_text()
     # no [n, k] temporary padded to 128 lanes: n * 128 * 4 bytes each
     assert f"[{n},{k}]{{1,0:T(8,128)}}" not in text
@@ -237,7 +236,7 @@ SPARSE_SLOTS, SPARSE_DIM = 12, 54_686_453
 SPARSE_HELD = {1_179_648: 1_712_040, 2_359_296: 2_899_743}
 
 
-def _sparse_solve(topo, n, layout="ell", monkeypatch=None):
+def _sparse_solve(topo, n, monkeypatch):
     """The walking ``jit__solve`` of the sparse configuration over ``n`` rows on
     one v5e, compiled, handed its start as ``GLMProblem.run`` hands it. An ELL
     batch carries its local column map, as a one-device build gives it, and
@@ -254,13 +253,9 @@ def _sparse_solve(topo, n, layout="ell", monkeypatch=None):
     k, d = SPARSE_SLOTS, SPARSE_DIM
     one = SingleDeviceSharding(topo.devices[0])
     s = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
-    if layout == "ell":
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        features = FeatureMatrix(dim=d, idx=s((n, k), jnp.int32), val=s((n, k)),
-                                 cols=s((SPARSE_HELD[n],), jnp.int32), idx_local=s((n, k), jnp.int32))
-    else:
-        features = FeatureMatrix(dim=d, coo_cols=s((n * k,), jnp.int32), coo_rows=s((n * k,), jnp.int32),
-                                 coo_vals=s((n * k,)), coo_n_rows=n)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    features = FeatureMatrix(dim=d, idx=s((n, k), jnp.int32), val=s((n, k)),
+                             cols=s((SPARSE_HELD[n],), jnp.int32), idx_local=s((n, k), jnp.int32))
     batch = LabeledBatch(features=features, labels=s((n,)), offsets=s((n,)), weights=s((n,)))
     objective = GLMObjective(loss=get_loss("logistic_regression"), batch=batch, l2=1000.0)
     return lbfgs._solve.lower(
@@ -297,7 +292,7 @@ def test_the_sparse_cells_solve_is_handed_its_start_for_no_more_memory(topo, mon
     global gather)."""
     n, k, d = 1_179_648, SPARSE_SLOTS, SPARSE_DIM
     held = SPARSE_HELD[n]
-    compiled = _sparse_solve(topo, n, monkeypatch=monkeypatch)
+    compiled = _sparse_solve(topo, n, monkeypatch)
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= SPARSE_CELL_SOLVE_TEMP, memory.temp_size_in_bytes
     # the start's g0 and z0 and the map's table come in; the first margins' copy of the batch no longer does
